@@ -7,7 +7,7 @@ counts), and this records single-chip training throughput at 224 px on
 synthetic data (real ImageNet bytes are not available in this
 environment). Run: python benchmarks/bench_imagenet.py
 
-Measured numbers live in benchmarks/README.md.
+Not measured on this installation.
 """
 
 from __future__ import annotations

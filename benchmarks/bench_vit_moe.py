@@ -58,7 +58,7 @@ best point: 2.9x over einsum at iso-drop, no grouping/drop trade.
 The 1.44x residual vs dense is bandwidth, not FLOPs: cf 1.25 -> 1.0
 deletes the whole 1.25x slot-padding FLOPs term but buys only 3.5%,
 and the device profile shows the time spread across per-layer
-movement/router fusions with no hot op (see benchmarks/README.md).
+movement/router fusions with no hot op.
 """
 
 from __future__ import annotations

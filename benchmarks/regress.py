@@ -1,10 +1,11 @@
 """Perf-regression gate: compare bench output against a baseline window.
 
-The repo's throughput history lives in checked-in bench envelopes
-(``BENCH_r01.json`` .. at the repo root, each holding the run's parsed
-headline record) and in ``kind="bench"`` records on telemetry JSONL
-streams (``bench.py --metrics-dir``). This gate reads EITHER format on
-either side, takes the **median of the last ``--window`` baseline
+Throughput history comes as bench envelopes (``BENCH_r*.json``, each
+holding a run's parsed headline record) or as ``kind="bench"`` records
+on telemetry JSONL streams (``bench.py --metrics-dir``). No envelope is
+checked in on this installation, so ``--baseline`` must name the
+history to compare against; without one the gate exits 2. This gate
+reads EITHER format on either side, takes the **median of the last ``--window`` baseline
 values** (median, not mean: one noisy CI run must not move the bar),
 and fails when the current value drops more than ``--tolerance`` below
 it. When BOTH sides carry graftscope ``phase_summary`` records, the
@@ -246,8 +247,8 @@ def main(argv: list[str] | None = None) -> int:
     )
     p.add_argument(
         "--baseline", nargs="*", default=None,
-        help="baseline file(s); default: the checked-in BENCH_r*.json "
-        "envelopes at the repo root",
+        help="baseline file(s); default: BENCH_r*.json envelopes at the "
+        "repo root, if any",
     )
     p.add_argument("--metric", default=DEFAULT_METRIC)
     p.add_argument("--tolerance", type=float, default=DEFAULT_TOLERANCE)
@@ -267,7 +268,11 @@ def main(argv: list[str] | None = None) -> int:
         args.baseline if args.baseline else _default_baselines()
     )
     if not baseline_paths:
-        print("regress: no baseline files found", file=sys.stderr)
+        print(
+            "regress: no --baseline given and no BENCH_r*.json at the repo "
+            "root; nothing to compare against",
+            file=sys.stderr,
+        )
         return MISSING
     baseline_records: list[dict[str, Any]] = []
     for path in baseline_paths:

@@ -22,9 +22,6 @@ strategies running over a `jax.sharding.Mesh`:
 
 __version__ = "0.1.0"
 
-# Side-effect import: backfills jax.shard_map / lax.pcast / jax.typeof
-# on older jax releases so one source tree runs across API versions.
-from cs744_pytorch_distributed_tutorial_tpu import compat as _compat  # noqa: F401
 from cs744_pytorch_distributed_tutorial_tpu.config import TrainConfig
 
 __all__ = ["TrainConfig", "__version__"]

@@ -230,6 +230,11 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
     cfg = config_from_args(args)
+    from cs744_pytorch_distributed_tutorial_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
 
     # Rendezvous before touching devices (multi-host no-op otherwise).
     # Under the graftelastic supervisor (launch.py) the coordinates

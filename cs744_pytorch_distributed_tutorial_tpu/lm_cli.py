@@ -447,6 +447,11 @@ def _run_pipeline(args, tokens, vocab: int) -> int:
 
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
+    from cs744_pytorch_distributed_tutorial_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
 
     if (
         args.int8_decode == "head"

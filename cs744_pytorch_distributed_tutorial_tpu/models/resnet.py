@@ -32,6 +32,7 @@ class FastConv3x3(nn.Module):
     features: int
     strides: int = 1
     dtype: Any = jnp.float32
+    interpret: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -43,7 +44,9 @@ class FastConv3x3(nn.Module):
             (3, 3, x.shape[-1], self.features),
             jnp.float32,
         ).astype(self.dtype)
-        return conv3x3(x.astype(self.dtype), kernel, self.strides)
+        return conv3x3(
+            x.astype(self.dtype), kernel, self.strides, self.interpret
+        )
 
 
 class BasicBlock(nn.Module):
@@ -54,6 +57,7 @@ class BasicBlock(nn.Module):
     dtype: Any = jnp.float32
     bn_axis: str | None = None
     fast_conv: bool = False
+    kernel_interpret: bool = False
 
     def _conv3(self, feats: int, strides: int, x, name: str,
                min_ch: int = 128, max_ch: int = 256):
@@ -68,7 +72,8 @@ class BasicBlock(nn.Module):
         if (self.fast_conv and strides == 1
                 and min_ch <= x.shape[-1] <= max_ch
                 and min_ch <= feats <= max_ch):
-            return FastConv3x3(feats, strides, dtype=self.dtype, name=name)(x)
+            return FastConv3x3(feats, strides, dtype=self.dtype,
+                               interpret=self.kernel_interpret, name=name)(x)
         return nn.Conv(feats, (3, 3), strides=(strides, strides),
                        padding="SAME", use_bias=False, dtype=self.dtype,
                        name=name)(x)
@@ -106,6 +111,7 @@ class BottleneckBlock(nn.Module):
     fast_conv: bool = False  # accepted for block-interface parity; the
     # bottleneck's 3x3 sits between 1x1s whose layouts XLA reshuffles
     # freely, so the Pallas wgrad routing currently targets BasicBlock.
+    kernel_interpret: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, *, train: bool = False) -> jnp.ndarray:
@@ -141,6 +147,9 @@ class ResNet(nn.Module):
     dtype: Any = jnp.float32
     bn_axis: str | None = None  # SyncBN mesh axis; None = per-replica BN
     fast_conv: bool = False  # Pallas wgrad backward for wide 3x3 convs
+    # Run that kernel through the Pallas interpreter (a mesh that is not
+    # TPU — parallel/mesh.py::interpret_kernels decides, as for flash).
+    kernel_interpret: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, *, train: bool = False) -> jnp.ndarray:
@@ -166,7 +175,9 @@ class ResNet(nn.Module):
                 strides = 2 if stage > 0 and b == 0 else 1
                 x = self.block(features=64 * 2 ** stage, strides=strides,
                                dtype=self.dtype, bn_axis=self.bn_axis,
-                               fast_conv=self.fast_conv)(x, train=train)
+                               fast_conv=self.fast_conv,
+                               kernel_interpret=self.kernel_interpret,
+                               )(x, train=train)
 
         x = jnp.mean(x, axis=(1, 2))  # global average pool
         x = nn.Dense(self.num_classes, dtype=self.dtype)(x)

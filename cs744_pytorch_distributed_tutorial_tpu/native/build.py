@@ -3,7 +3,7 @@
 g++ is baked into the image but pip installs are not allowed, so the
 shared library is built directly (``g++ -O3 -shared -fPIC``) into a
 version-keyed cache next to this package the first time it's needed.
-Failures degrade gracefully: consumers check ``native_available()`` and
+A failed build warns and consumers, which check ``native_available()``,
 fall back to NumPy.
 """
 
@@ -13,6 +13,7 @@ import hashlib
 import os
 import subprocess
 import threading
+import warnings
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LOCK = threading.Lock()
@@ -50,7 +51,16 @@ def _build(name: str) -> str | None:
         )
         os.replace(tmp, lib)
         return lib
-    except (subprocess.SubprocessError, OSError):
+    except (subprocess.SubprocessError, OSError) as e:
+        # Consumers fall back to NumPy; say why, once, so a slow input
+        # pipeline is not a mystery (load_library caches the None).
+        stderr = (getattr(e, "stderr", None) or "")[-2000:]
+        warnings.warn(
+            f"native {name!r} did not build, using the NumPy fallback: "
+            f"{e}\n{stderr}",
+            RuntimeWarning,
+            stacklevel=3,
+        )
         return None
     finally:
         if os.path.exists(tmp):

@@ -1,7 +1,6 @@
 """Analytic FLOPs models and MFU accounting.
 
-Moved here from ``bench.py`` (which re-exports for backward compat) so
-training telemetry and the benchmark share ONE definition of model
+Training telemetry and ``bench.py`` share this ONE definition of model
 FLOPs and peak throughput.
 
 Conventions (the standard MFU accounting):

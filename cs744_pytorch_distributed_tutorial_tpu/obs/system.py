@@ -44,13 +44,13 @@ def _ensure_listener() -> None:
     with _lock:
         if _listener_installed:
             return
-        _listener_installed = True  # even on failure: never retry-spam
-    try:
-        from jax._src import monitoring
+        _listener_installed = True
+    # A private API: if a JAX upgrade moves it this raises, where a
+    # swallowed failure would leave every compile count at zero and the
+    # "no retrace after warm-up" checks vacuous.
+    from jax._src import monitoring
 
-        monitoring.register_event_duration_secs_listener(_on_duration_event)
-    except Exception:
-        pass  # private API moved/absent: compile counts stay at zero
+    monitoring.register_event_duration_secs_listener(_on_duration_event)
 
 
 def _compile_totals() -> tuple[int, float]:

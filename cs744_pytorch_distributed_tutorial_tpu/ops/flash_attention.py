@@ -34,13 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu only imports on TPU-enabled builds; interpret mode needs pl only
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
-
 _NEG = -1e30
 
 
@@ -149,12 +142,11 @@ def _forward(q, k, v, causal, block_q, block_k, interpret, with_lse=False):
     scale = d**-0.5
 
     qb, kb, vb = (_to_bh(x, b, t, h, d) for x in (q, k, v))
-    spec_kw = {"memory_space": _VMEM} if (_VMEM is not None and not interpret) else {}
-    q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0), **spec_kw)
-    kv_spec = pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0), **spec_kw)
+    q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0))
+    kv_spec = pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0))
     # Row statistics ride as [BH, T, 1]: a trailing singleton keeps the
     # last-two-dims (8, 128)-divisibility rule satisfiable at any block.
-    row_spec = pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0), **spec_kw)
+    row_spec = pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0))
 
     out_shapes = [jax.ShapeDtypeStruct(qb.shape, v.dtype)]
     out_specs = [q_spec]
@@ -311,10 +303,9 @@ def flash_dq(
     qb, kb, vb, gb = (
         _to_bh(x, b, x.shape[1], h, d) for x in (q, k, v, do)
     )
-    spec_kw = {"memory_space": _VMEM} if (_VMEM is not None and not interpret) else {}
-    q_tile = pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0), **spec_kw)
-    kv_full = pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0), **spec_kw)
-    row_tile = pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0), **spec_kw)
+    q_tile = pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0))
+    kv_full = pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0))
+    row_tile = pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0))
     dq = pl.pallas_call(
         partial(_dq_kernel, causal, block_k, scale),
         out_shape=jax.ShapeDtypeStruct(qb.shape, q.dtype),
@@ -339,10 +330,9 @@ def flash_dkv(
     qb, kb, vb, gb = (
         _to_bh(x, b, x.shape[1], h, d) for x in (q, k, v, do)
     )
-    spec_kw = {"memory_space": _VMEM} if (_VMEM is not None and not interpret) else {}
-    q_full = pl.BlockSpec((1, tq, d), lambda i, j: (i, 0, 0), **spec_kw)
-    k_tile = pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0), **spec_kw)
-    row_full = pl.BlockSpec((1, tq, 1), lambda i, j: (i, 0, 0), **spec_kw)
+    q_full = pl.BlockSpec((1, tq, d), lambda i, j: (i, 0, 0))
+    k_tile = pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0))
+    row_full = pl.BlockSpec((1, tq, 1), lambda i, j: (i, 0, 0))
     dk, dv = pl.pallas_call(
         partial(_dkv_kernel, causal, block_q, scale),
         out_shape=[

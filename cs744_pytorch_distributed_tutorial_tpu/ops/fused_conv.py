@@ -37,13 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # pltpu imports only on TPU-enabled builds; interpret mode needs pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["conv3x3_wgrad", "conv3x3"]
 
@@ -176,17 +170,13 @@ def conv3x3_wgrad(
         )
     if stride == 2 and (h % 2 or w % 2):
         raise ValueError("stride-2 wgrad needs even H, W")
-    if _VMEM is None or (not interpret and jax.default_backend() != "tpu"):
-        # CPU/virtual-mesh runs (tests, dryruns) execute the same kernel
-        # through the interpreter — one code path, two backends.
-        interpret = True
-    if interpret and getattr(jax.typeof(x), "vma", None):
+    if interpret and jax.typeof(x).vma:
         # Inside a check_vma=True shard_map, interpret-mode pallas
         # inlines the kernel into the vma-checked trace, where its
         # replicated constants (scratch init, boundary zeros) cannot
         # meet the device-varying operands. Use the reference
         # formulation there — the kernel's numerics are pinned by the
-        # direct tests, and real TPU runs never take this branch. The
+        # direct tests, and compiled runs never take this branch. The
         # vjp point is pcast varying so the result keeps the LOCAL-grad
         # contract (no implicit psum).
         def f(wk):
@@ -196,10 +186,7 @@ def conv3x3_wgrad(
             )
 
         w0 = jnp.zeros((3, 3, c, k), x.dtype)
-        vma = frozenset(getattr(jax.typeof(x), "vma", frozenset())) | frozenset(
-            getattr(jax.typeof(g), "vma", frozenset())
-        )
-        for name in sorted(vma):
+        for name in sorted(jax.typeof(x).vma | jax.typeof(g).vma):
             w0 = lax.pcast(w0, name, to="varying")
         return jax.vjp(f, w0)[1](g)[0].astype(jnp.float32)
 
@@ -215,13 +202,7 @@ def conv3x3_wgrad(
     while kb > 128 and kb % 2 == 0 and kb * 9 * c * 4 > 3 * 1024 * 1024:
         kb //= 2
     assert k % kb == 0, (k, kb)
-    # Interpret mode (CPU tests) has no pltpu; a plain ShapeDtypeStruct
-    # scratch runs the same kernel through the interpreter.
-    scratch = (
-        _VMEM((kb, 9 * c), jnp.float32)
-        if _VMEM is not None
-        else jax.ShapeDtypeStruct((kb, 9 * c), jnp.float32)
-    )
+    scratch = pltpu.VMEM((kb, 9 * c), jnp.float32)
     # Under a check_vma=True shard_map (the CIFAR engine), pallas
     # outputs must declare their device-varying axes; the wgrad
     # inherits the union of its operands' (activations vary over
@@ -229,8 +210,7 @@ def conv3x3_wgrad(
     out_shape = jax.ShapeDtypeStruct(
         (k, 9 * c),
         jnp.float32,
-        vma=frozenset(getattr(jax.typeof(x), "vma", None) or frozenset())
-        | frozenset(getattr(jax.typeof(g), "vma", None) or frozenset()),
+        vma=jax.typeof(x).vma | jax.typeof(g).vma,
     )
     g_spec = pl.BlockSpec((bb, ho, wo, kb), lambda j, i: (i, 0, 0, j))
     out_spec = pl.BlockSpec((kb, 9 * c), lambda j, i: (j, 0))
@@ -297,13 +277,9 @@ def _match_vma(val, like):
     primal under a check_vma shard_map (the engine's 'auto' strategy);
     a no-op when the primal is itself device-varying (manual
     strategies, which pcast params before differentiating)."""
-    v_val = frozenset(getattr(jax.typeof(val), "vma", frozenset()) or ())
-    v_like = frozenset(getattr(jax.typeof(like), "vma", frozenset()) or ())
-    extra = tuple(sorted(v_val - v_like))
+    extra = tuple(sorted(jax.typeof(val).vma - jax.typeof(like).vma))
     if extra:
-        from jax import lax as _lax
-
-        val = _lax.psum(val, extra)
+        val = lax.psum(val, extra)
     return val
 
 

@@ -31,13 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu only imports on TPU-enabled builds; interpret mode needs pl only
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
-
 LANES = 128
 SUBLANES = 8
 _BLOCK_ROWS = 512  # rows of 128 lanes per grid step (256 KiB fp32 per operand)
@@ -74,8 +67,7 @@ def _update_leaf(
     p2, m2, g2 = prep(p), prep(m), prep(g)
     block_rows = min(_BLOCK_ROWS, rows)
     grid = (pl.cdiv(rows, block_rows),)
-    spec_kw = {"memory_space": _VMEM} if (_VMEM is not None and not interpret) else {}
-    block = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0), **spec_kw)
+    block = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
 
     new_p, new_m = pl.pallas_call(
         partial(_kernel, lr, mu, wd),

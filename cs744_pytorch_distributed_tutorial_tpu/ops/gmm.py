@@ -43,16 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # pltpu ships with standard JAX builds (interpret mode uses its
-    # grid spec too); a build without it gets a loud error in
-    # _require_pltpu instead of Mosaic-compiling anything.
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -160,21 +151,7 @@ def _prep(lhs, group_sizes, block_m: int, num_experts: int):
     return lhs, gs, m_padded
 
 
-def _require_pltpu():
-    """The kernels' grid spec (scalar prefetch) lives in
-    ``jax.experimental.pallas.tpu`` even in interpret mode; builds
-    without that module get a loud redirect instead of an
-    AttributeError on ``None``."""
-    if pltpu is None:
-        raise ValueError(
-            "grouped_matmul(impl='pallas') needs "
-            "jax.experimental.pallas.tpu (unavailable on this JAX "
-            "build); use impl='ragged'"
-        )
-
-
 def _gmm_fwd_impl(lhs, rhs, group_sizes, block_m, block_n, interpret):
-    _require_pltpu()
     m, k = lhs.shape
     e, _, n = rhs.shape
     lhs_p, gs, m_padded = _prep(lhs, group_sizes, block_m, e)
@@ -187,16 +164,15 @@ def _gmm_fwd_impl(lhs, rhs, group_sizes, block_m, block_n, interpret):
     n_padded = _ceil_to(n, bn)
     if n_padded != n:
         rhs = jnp.pad(rhs, ((0, 0), (0, 0), (0, n_padded - n)))
-    kw = {"memory_space": _VMEM} if (_VMEM is not None and not interpret) else {}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_m, k), lambda j, s, sg, sm, *_: (sm[s], 0), **kw),
-            pl.BlockSpec((1, k, bn), lambda j, s, sg, sm, *_: (sg[s], 0, j), **kw),
+            pl.BlockSpec((block_m, k), lambda j, s, sg, sm, *_: (sm[s], 0)),
+            pl.BlockSpec((1, k, bn), lambda j, s, sg, sm, *_: (sg[s], 0, j)),
         ],
         out_specs=pl.BlockSpec(
-            (block_m, bn), lambda j, s, sg, sm, *_: (sm[s], j), **kw
+            (block_m, bn), lambda j, s, sg, sm, *_: (sm[s], j)
         ),
     )
     out = pl.pallas_call(
@@ -211,7 +187,6 @@ def _gmm_fwd_impl(lhs, rhs, group_sizes, block_m, block_n, interpret):
 def _tgmm_impl(lhs, dout, group_sizes, num_experts, block_m, block_n,
                interpret):
     """Per-group ``lhsᵀ @ dout`` → ``[E, K, N]`` (the dW of gmm)."""
-    _require_pltpu()
     m, k = lhs.shape
     n = dout.shape[1]
     e = num_experts
@@ -230,16 +205,15 @@ def _tgmm_impl(lhs, dout, group_sizes, num_experts, block_m, block_n,
     prev_g = jnp.concatenate([jnp.full((1,), -1, jnp.int32), sg[:-1]])
     first_g = ((sg != prev_g) & (valid == 1)).astype(jnp.int32)
     grid = (-(-n // bn), num_steps)
-    kw = {"memory_space": _VMEM} if (_VMEM is not None and not interpret) else {}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_m, k), lambda j, s, sg, sm, *_: (sm[s], 0), **kw),
-            pl.BlockSpec((block_m, bn), lambda j, s, sg, sm, *_: (sm[s], j), **kw),
+            pl.BlockSpec((block_m, k), lambda j, s, sg, sm, *_: (sm[s], 0)),
+            pl.BlockSpec((block_m, bn), lambda j, s, sg, sm, *_: (sm[s], j)),
         ],
         out_specs=pl.BlockSpec(
-            (1, k, bn), lambda j, s, sg, sm, *_: (sg[s], 0, j), **kw
+            (1, k, bn), lambda j, s, sg, sm, *_: (sg[s], 0, j)
         ),
     )
     dw = pl.pallas_call(
@@ -307,7 +281,6 @@ def _gmm_fused_kernel(block_m: int, act: str, h_dtype,
 
 def _gmm_fused_fwd_impl(lhs, rhs, bias, group_sizes, act, h_dtype,
                         block_m, block_n, interpret, with_z=False):
-    _require_pltpu()
     m, k = lhs.shape
     e, _, n = rhs.shape
     lhs_p, gs, m_padded = _prep(lhs, group_sizes, block_m, e)
@@ -325,10 +298,9 @@ def _gmm_fused_fwd_impl(lhs, rhs, bias, group_sizes, act, h_dtype,
         gs, m_padded, block_m, num_steps
     )
     grid = (-(-n // bn), num_steps)
-    kw = {"memory_space": _VMEM} if (_VMEM is not None and not interpret) else {}
     out_shape = [jax.ShapeDtypeStruct((m_padded, n_padded), h_dtype)]
     out_specs = [
-        pl.BlockSpec((block_m, bn), lambda j, s, sg, sm, *_: (sm[s], j), **kw)
+        pl.BlockSpec((block_m, bn), lambda j, s, sg, sm, *_: (sm[s], j))
     ]
     if with_z:
         # Pre-activation residual for the backward's gelu', stored at
@@ -339,16 +311,16 @@ def _gmm_fused_fwd_impl(lhs, rhs, bias, group_sizes, act, h_dtype,
         )
         out_specs.append(
             pl.BlockSpec(
-                (block_m, bn), lambda j, s, sg, sm, *_: (sm[s], j), **kw
+                (block_m, bn), lambda j, s, sg, sm, *_: (sm[s], j)
             )
         )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_m, k), lambda j, s, sg, sm, *_: (sm[s], 0), **kw),
-            pl.BlockSpec((1, k, bn), lambda j, s, sg, sm, *_: (sg[s], 0, j), **kw),
-            pl.BlockSpec((1, 1, bn), lambda j, s, sg, sm, *_: (sg[s], 0, j), **kw),
+            pl.BlockSpec((block_m, k), lambda j, s, sg, sm, *_: (sm[s], 0)),
+            pl.BlockSpec((1, k, bn), lambda j, s, sg, sm, *_: (sg[s], 0, j)),
+            pl.BlockSpec((1, 1, bn), lambda j, s, sg, sm, *_: (sg[s], 0, j)),
         ],
         out_specs=tuple(out_specs),
     )

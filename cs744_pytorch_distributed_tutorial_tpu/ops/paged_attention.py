@@ -12,11 +12,16 @@ memory-bound, so that is exactly the wrong scaling.
 
 This kernel reads **only live pages**, straight out of the pool:
 
-- Grid ``(slot, kv_head, page_block)`` with the page dimension fastest.
-  The page table and per-slot depths ride as **scalar-prefetched**
-  operands (``PrefetchScalarGridSpec``), so each grid step's BlockSpec
-  index_map picks its page from ``page_table[slot, i]`` — data-dependent
-  DMA, no gather, no dense intermediate.
+- Grid ``(slot, page_block)`` with the page dimension fastest. The page
+  table and per-slot depths ride as **scalar-prefetched** operands
+  (``PrefetchScalarGridSpec``), so each grid step's BlockSpec index_map
+  picks its page from ``page_table[slot, i]`` — data-dependent DMA, no
+  gather, no dense intermediate.
+- One block is a whole page, all KV heads: ``(1, page_size, Hkv, D)``.
+  Mosaic requires a block's last two dims to be (8, 128)-divisible or
+  the array's own, which a one-head ``(1, page_size, 1, D)`` block of
+  this pool layout is not; the page-wide block moves the same bytes in
+  one DMA and the kernel loops the heads.
 - Dead iterations (``i >= ceil((pos+1)/page_size)``) CLAMP their
   index_map to the slot's last live page. Pallas skips the re-fetch when
   a block index repeats, so capacity-sized grids cost live-sized HBM
@@ -26,20 +31,20 @@ This kernel reads **only live pages**, straight out of the pool:
   f32 VMEM scratch, ``ops/flash_attention.py`` discipline); the last
   live page masks its tail rows by position, dead iterations are skipped
   by ``pl.when``, and the output block flushes once at the end of each
-  (slot, head) pass.
+  slot's pass.
 
 Three variants share this one entry point:
 
 - float (f32/bf16 pools): numerics follow ``decode_attention`` — f32
   scores/softmax, PV matmul in the pool dtype.
 - int8-KV (``key/value_scale_pages`` given): dequant happens INSIDE the
-  kernel with the same algebra as ``ops/quant.py::decode_attention_quant``
-  (per-key ``k_scale`` on scores after the QK dot, ``v_scale`` folded
-  into the probabilities before PV) — the scale pools ride the same
+  kernel (each K/V row times its scale before the dots — the same
+  algebra as ``ops/quant.py::decode_attention_quant``, which scales the
+  scores and probabilities after them) — the scale pools ride the same
   clamped index_map, replacing ``paged_decode_attention_quant``'s
   four-pool gather.
 - tensor-parallel: under ``shard_map`` the pools arrive sliced over KV
-  heads and ``q`` over query heads; the grid derives from the LOCAL
+  heads and ``q`` over query heads; the blocks derive from the LOCAL
   shapes, so the kernel partitions over the head axis with no changes.
 
 Online softmax reassociates the reduction, so kernel-vs-reference parity
@@ -50,7 +55,7 @@ bitwise dense-parity story stays on it.
 ``pages_per_slot`` statically prunes the page-table width and grid — the
 compiled ``cost_analysis`` bytes-read then scales with
 ``ceil(live/page_size) * page_size`` instead of capacity, which is how
-CPU CI gates the win analytically (no TPU in the loop).
+CPU CI checks the byte count analytically.
 
 ``interpret=True`` runs the same kernel on any backend for tests.
 """
@@ -62,11 +67,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu only imports on TPU-enabled builds; interpret mode needs it
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
 
@@ -88,7 +89,7 @@ def _decode_kernel(
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
     pos = lens_ref[b]
     # Page i holds positions [i*page_size, (i+1)*page_size); the slot's
     # current token sits at ``pos``, so pages 0..pos//page_size are live.
@@ -102,41 +103,41 @@ def _decode_kernel(
 
     @pl.when(i < live)
     def _update():
-        q = q_ref[0, 0]  # [group, D]
-        k = k_ref[0, :, 0, :]  # [page_size, D]
-        v = v_ref[0, :, 0, :]
-        if quant:
-            q, k = q.astype(jnp.float32), k.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [group, page_size] f32
-        if quant:
-            # Per-key dequant AFTER the dot — algebraically identical to
-            # scaling K first (decode_attention_quant's layout).
-            s = s * ks_ref[0, :, 0][None, :]
-        k_pos = i * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos <= pos, s, _NEG)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        correction = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = correction * l_prev + p.sum(axis=-1, keepdims=True)
-        if quant:
-            pv = p * vs_ref[0, :, 0][None, :]
-            v = v.astype(jnp.float32)
-        else:
-            pv = p.astype(v.dtype)
-        acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
-            pv, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        for h in range(k_ref.shape[2]):  # static: the page's KV heads
+            q = q_ref[0, h]  # [group, D]
+            k = k_ref[0, :, h, :]  # [page_size, D]
+            v = v_ref[0, :, h, :]
+            if quant:
+                # Per-row dequant ahead of the dots: the [page_size, 1]
+                # scale column broadcasts along lanes, where scaling the
+                # [group, page_size] scores would need it transposed.
+                q = q.astype(jnp.float32)
+                k = k.astype(jnp.float32) * ks_ref[0, :, h:h + 1]
+                v = v.astype(jnp.float32) * vs_ref[0, :, h:h + 1]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [group, page_size] f32
+            k_pos = i * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1
+            )
+            s = jnp.where(k_pos <= pos, s, _NEG)
+            m_prev, l_prev = m_ref[h], l_ref[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            correction = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            m_ref[h] = m_new
+            l_ref[h] = correction * l_prev + p.sum(axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * correction + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
 
     # Position 0 is always visible (pos >= 0), so l > 0 — no NaN rows
     # even for freshly-admitted or parked slots.
     @pl.when(i == num_blocks - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 def paged_attention(
@@ -180,11 +181,6 @@ def paged_attention(
         )
 
         interpret = default_interpret()
-    if pltpu is None:  # pragma: no cover - TPU-less builds without pltpu
-        return _reference(
-            q, key_pages, value_pages, page_table, pos,
-            key_scale_pages, value_scale_pages,
-        )
 
     group = hq // hkv
     pt = page_table
@@ -193,39 +189,40 @@ def paged_attention(
     num_blocks = pt.shape[1]
     qg = q[:, 0].reshape(b, hkv, group, d)
 
-    def q_map(bi, h, i, lens, table):
-        return bi, h, 0, 0
+    def q_map(bi, i, lens, table):
+        return bi, 0, 0, 0
 
-    def kv_map(bi, h, i, lens, table):
+    def live_page(bi, i, lens, table):
         # Dead iterations re-point at the last live page: an unchanged
         # block index skips the DMA, so capacity-wide grids read
         # live-sized bytes (and never the trash page past block 0).
-        live_last = lens[bi] // page_size
-        return table[bi, jnp.minimum(i, live_last)], 0, h, 0
+        return table[bi, jnp.minimum(i, lens[bi] // page_size)]
 
-    def scale_map(bi, h, i, lens, table):
-        live_last = lens[bi] // page_size
-        return table[bi, jnp.minimum(i, live_last)], 0, h
+    def kv_map(bi, i, lens, table):
+        return live_page(bi, i, lens, table), 0, 0, 0
 
-    q_spec = pl.BlockSpec((1, 1, group, d), q_map)
-    kv_spec = pl.BlockSpec((1, page_size, 1, d), kv_map)
+    def scale_map(bi, i, lens, table):
+        return live_page(bi, i, lens, table), 0, 0
+
+    q_spec = pl.BlockSpec((1, hkv, group, d), q_map)
+    kv_spec = pl.BlockSpec((1, page_size, hkv, d), kv_map)
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qg, key_pages, value_pages]
     if quant:
-        sc_spec = pl.BlockSpec((1, page_size, 1), scale_map)
+        sc_spec = pl.BlockSpec((1, page_size, hkv), scale_map)
         in_specs += [sc_spec, sc_spec]
         operands += [key_scale_pages, value_scale_pages]
     out_dtype = q.dtype if quant else value_pages.dtype
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, num_blocks),
+        grid=(b, num_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, group, d), q_map),
+        out_specs=pl.BlockSpec((1, hkv, group, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
+            pltpu.VMEM((hkv, group, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -235,24 +232,3 @@ def paged_attention(
         interpret=interpret,
     )(pos.astype(jnp.int32), pt.astype(jnp.int32), *operands)
     return out.reshape(b, 1, hq, d)
-
-
-def _reference(
-    q, key_pages, value_pages, page_table, pos, key_scale_pages,
-    value_scale_pages,
-):  # pragma: no cover - TPU-less builds without pltpu
-    """Gather+einsum fallback for builds where pltpu itself is absent."""
-    if key_scale_pages is not None:
-        from cs744_pytorch_distributed_tutorial_tpu.ops.quant import (
-            paged_decode_attention_quant,
-        )
-
-        return paged_decode_attention_quant(
-            q, key_pages, value_pages, key_scale_pages, value_scale_pages,
-            page_table, pos,
-        )
-    from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
-        paged_decode_attention,
-    )
-
-    return paged_decode_attention(q, key_pages, value_pages, page_table, pos)

@@ -38,13 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu only imports on TPU-enabled builds; interpret mode needs pl only
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
-
 
 def quantize_int8(w: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Symmetric per-output-channel int8 quantization of a ``[K, N]``
@@ -177,17 +170,16 @@ def int8_matmul(
     qp = _pad_to(q, 1, bn)
     sp = _pad_to(scale.astype(jnp.float32)[None, :], 1, bn)
     mp, np_ = xp.shape[0], qp.shape[1]
-    spec_kw = {"memory_space": _VMEM} if (_VMEM is not None and not interpret) else {}
     out = pl.pallas_call(
         _kernel,
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         grid=(mp // bm, np_ // bn),
         in_specs=[
-            pl.BlockSpec((bm, k), lambda i, j: (i, 0), **spec_kw),
-            pl.BlockSpec((k, bn), lambda i, j: (0, j), **spec_kw),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j), **spec_kw),
+            pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((k, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j), **spec_kw),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         interpret=interpret,
     )(xp, qp, sp)
     return out[:m, :n].reshape(*lead, n)

@@ -257,13 +257,10 @@ class ServingEngine:
         impl = cfg.paged_attention_impl
         if impl == "auto":
             from cs744_pytorch_distributed_tutorial_tpu.ops._backend import (
-                TPU_PLATFORMS,
+                default_interpret,
             )
 
-            impl = (
-                "kernel" if jax.default_backend() in TPU_PLATFORMS
-                else "gather"
-            )
+            impl = "gather" if default_interpret() else "kernel"
         self.paged_attention_impl = impl
         self.cfg = cfg
         self.params = params

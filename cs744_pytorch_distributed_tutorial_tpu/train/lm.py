@@ -262,7 +262,7 @@ class LMConfig:
     label_smoothing: float = 0.0
 
     # Residual dropout on each block's attention/MLP sublayer outputs —
-    # the round-1 deferred rng migration (docs/roadmap.md). The step
+    # the round-1 deferred rng migration. The step
     # index keys the mask stream: ``train_step(..., step=k)`` draws the
     # same masks for the same k on every run, different masks per step.
     # 0.0 reproduces the dropout-free path exactly (golden traces pin

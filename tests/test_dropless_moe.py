@@ -198,7 +198,7 @@ def test_dropless_lm_trains():
 @pytest.mark.parametrize("act", ["none", "gelu"])
 def test_grouped_matmul_fused_matches_unfused(act):
     """The fused-epilogue kernels (bias(+gelu) inside the gmm — the
-    in-model Pallas win, benchmarks/README.md) compute exactly the
+    in-model Pallas path) compute exactly the
     unfused chain, forward and gradients (custom_vjp: dx/dw via the
     plain kernels, db via a K=1 tgmm segment-sum)."""
     from cs744_pytorch_distributed_tutorial_tpu.ops.gmm import (

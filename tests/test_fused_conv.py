@@ -88,7 +88,7 @@ def test_fast_conv_resnet_grads_match():
     y = jnp.asarray(rng.integers(0, 10, 2), jnp.int32)
 
     ref = resnet18(num_classes=10)
-    fast = resnet18(num_classes=10, fast_conv=True)
+    fast = resnet18(num_classes=10, fast_conv=True, kernel_interpret=True)
     vs = ref.init(jax.random.key(0), x, train=False)
 
     def loss(model, p):

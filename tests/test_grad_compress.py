@@ -36,21 +36,6 @@ from cs744_pytorch_distributed_tutorial_tpu.parallel.sync import (
 from conftest import run_tiny_dp4_steps
 
 
-def _smap(f, mesh, in_specs, out_specs):
-    """shard_map across the jax.shard_map / experimental API versions."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
 def _tree(seed=0):
     """Mixed shapes/dtypes: oversized leaf, odd sizes, scalar, bf16."""
     rng = np.random.RandomState(seed)
@@ -79,7 +64,12 @@ def _run_sync(mesh, strategy, bucket_bytes, tree):
         gl = jax.tree.map(lambda a: a[0], gs)
         return sync_grads(gl, strategy, "data", 4, bucket_bytes=bucket_bytes)
 
-    out = jax.jit(_smap(f, mesh, (P("data"),), P()))(g)
+    out = jax.jit(
+        jax.shard_map(
+            f, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
+            check_vma=False,
+        )
+    )(g)
     return jax.tree.map(np.asarray, jax.device_get(out))
 
 
@@ -163,7 +153,10 @@ def test_compressed_sync_returns_transmission_residual(mesh4):
         return mean, jax.tree.map(lambda a: a[None], ef)
 
     mean, ef = jax.jit(
-        _smap(f, mesh4, (P("data"), P("data")), (P(), P("data")))
+        jax.shard_map(
+            f, mesh=mesh4, in_specs=(P("data"), P("data")),
+            out_specs=(P(), P("data")), check_vma=False,
+        )
     )(g, ef0)
     # Residuals are nonzero (quantization is lossy) but small relative
     # to the gradient scale.
@@ -243,7 +236,10 @@ def test_zero1_bucketed_update_bitwise(mesh4):
             return opt.apply(p, m, gl)
 
         return jax.jit(
-            _smap(f, mesh4, (P(), P("data"), P("data")), (P(), P("data")))
+            jax.shard_map(
+                f, mesh=mesh4, in_specs=(P(), P("data"), P("data")),
+                out_specs=(P(), P("data")), check_vma=False,
+            )
         )(tree, mom, g)
 
     p0, m0 = run(0)

@@ -1,5 +1,5 @@
-"""LM dropout rng plumbing — the round-1 deferred migration
-(docs/roadmap.md): ``LMTrainer.train_step`` takes a step index that keys
+"""LM dropout rng plumbing — the round-1 deferred migration:
+``LMTrainer.train_step`` takes a step index that keys
 the dropout mask stream.
 
 Pinned properties:

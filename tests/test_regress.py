@@ -21,6 +21,7 @@ from regress import (  # noqa: E402
     REGRESSION,
     evaluate,
     load_records,
+    main,
     metric_values,
 )
 import metrics_summary  # noqa: E402
@@ -68,9 +69,18 @@ def test_baseline_is_window_median():
     assert code == PASS
 
 
+def test_no_default_baseline_exits_2(tmp_path, capsys):
+    """No BENCH_r*.json is checked in: without --baseline the gate has
+    nothing to compare against and must say so, not pass."""
+    current = tmp_path / "metrics.jsonl"
+    current.write_text(json.dumps(_bench(100.0)) + "\n")
+    assert main(["--current", str(current)]) == MISSING
+    assert "nothing to compare against" in capsys.readouterr().err
+
+
 def test_bench_envelope_parsing(tmp_path):
-    """The checked-in BENCH_rNN.json driver envelopes (headline record
-    under "parsed") read the same as JSONL streams."""
+    """BENCH_rNN.json driver envelopes (headline record under "parsed")
+    read the same as JSONL streams."""
     envelope = {
         "n": 5, "cmd": "python bench.py", "rc": 0, "tail": "...",
         "parsed": {"metric": METRIC, "value": 35330.5, "unit": "s/s/chip"},

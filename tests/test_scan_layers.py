@@ -4,8 +4,8 @@ unrolled one (models/transformer.py::TransformerLM.scan_layers).
 No counterpart in the reference (conv VGG-11 only,
 ``master/part1/model.py:30-46``) — this is compile-scalability
 infrastructure: the scanned program is one block body + a loop instead
-of L inlined bodies, which is what lets deep/big-batch GPT-2 configs
-compile (the round-3 b32 remote-compile wall, benchmarks/README.md).
+of L inlined bodies, the option for when a deep unrolled program stops
+compiling.
 These tests pin that the layout change is EXACTLY a layout change:
 logits, grads, the training step, remat, dropout keying, decode with a
 KV cache, and tensor-parallel sharding all agree with the unrolled

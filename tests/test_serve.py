@@ -341,16 +341,20 @@ def test_engine_eos_stops_early(tiny_lm):
     budget = 12
     prompt = np.asarray([1, 2, 3, 4], np.int32)
     ref = _reference_tokens(model, params, prompt, budget)
-    eos = ref[3]  # force a stop 4 tokens in
+    # Stop mid-stream: at the first position past 0 whose token has not
+    # occurred before it (an eos that already occurred would, rightly,
+    # stop the engine at that earlier position).
+    stop = next(i for i in range(1, budget - 1) if ref[i] not in ref[:i])
+    eos = ref[stop]
     sink = _ListSink()
     cfg = ServeConfig(num_slots=2, page_size=4, num_pages=17,
                       max_pages_per_slot=8, eos_id=eos)
     eng = ServingEngine(model, params, cfg, sink=sink)
     req = eng.submit(Request(prompt=prompt, max_new_tokens=budget))
     eng.run()
-    assert req.generated == ref[:4]
+    assert req.generated == ref[: stop + 1]
     recs = [r for r in sink.records if r.get("kind") == "serve"]
-    assert len(recs) == 1 and recs[0]["output_tokens"] == 4
+    assert len(recs) == 1 and recs[0]["output_tokens"] == stop + 1
 
 
 def test_engine_submit_validation(tiny_lm):

@@ -322,8 +322,7 @@ def test_overlap_modes_zero_retrace(mesh4, batch, cfg_kw):
     key = jax.random.key(cfg.seed)
     warm = CompileCounter()
     state, _ = tr.train_step(state, gx, gy, key)
-    if warm.count == 0:
-        pytest.skip("jax monitoring compile events unavailable")
+    assert warm.count >= 1, "the first step's compile was not counted"
     steady = CompileCounter()
     for _ in range(3):
         state, m = tr.train_step(state, gx, gy, key)

@@ -1,8 +1,8 @@
 """Tensor-parallel decode: generation/beam on tensor-SHARDED params.
 
 Round 1's generation required gathered full params
-(``LMTrainer.decode_model``) — the one strategy-family composition hole
-(docs/roadmap.md). The ``mesh=`` path added to ``make_generator`` /
+(``LMTrainer.decode_model``) — the one strategy-family composition
+hole. The ``mesh=`` path added to ``make_generator`` /
 ``make_beam_searcher`` runs the whole sampling loop inside shard_map:
 each device projects and caches its local heads, and the per-sublayer
 psums keep the logits replicated. These tests pin exact token parity

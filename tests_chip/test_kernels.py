@@ -1,0 +1,281 @@
+"""Every Pallas kernel in the tree, Mosaic-compiled (``interpret=False``)
+at a production shape and compared with its ``jax.numpy`` reference.
+
+The CPU suite runs the same kernels through the Pallas interpreter,
+which checks the arithmetic but none of what Mosaic checks on the
+device: block tiling, VMEM limits, layout inference. One case per
+kernel here; small-shape edge cases (empty groups, ragged tiles) stay
+in ``test_gmm.py`` and the CPU suite.
+
+References run at ``Precision.HIGHEST``: on TPU a default-precision f32
+matmul is a single bf16 pass, so tolerances are bf16-sized wherever the
+kernel feeds the MXU bf16 (or f32 at default precision).
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from parity import assert_close
+
+from cs744_pytorch_distributed_tutorial_tpu.ops.flash_attention import (
+    flash_attention,
+)
+from cs744_pytorch_distributed_tutorial_tpu.ops.fused_conv import conv3x3_wgrad
+from cs744_pytorch_distributed_tutorial_tpu.ops.fused_sgd import FusedSGD
+from cs744_pytorch_distributed_tutorial_tpu.ops.fused_xent import (
+    fused_cross_entropy,
+)
+from cs744_pytorch_distributed_tutorial_tpu.ops.gmm import (
+    grouped_matmul,
+    grouped_matmul_fused,
+)
+from cs744_pytorch_distributed_tutorial_tpu.ops.paged_attention import (
+    paged_attention,
+)
+from cs744_pytorch_distributed_tutorial_tpu.ops.quant import (
+    int8_matmul,
+    int8_matmul_ref,
+    paged_decode_attention_quant,
+    quantize_int8,
+    quantize_kv,
+)
+from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
+    paged_decode_attention,
+)
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _normal(seed, shape, dtype=jnp.float32, scale=1.0):
+    x = jax.random.normal(jax.random.key(seed), shape, jnp.float32) * scale
+    return x.astype(dtype)
+
+
+# ------------------------------------------------------------ flash attention
+def _dense_causal(q, k, v):
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HIGHEST)
+    s = s * q.shape[-1] ** -0.5
+    t = q.shape[1]
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v, precision=HIGHEST)
+
+
+@pytest.mark.parametrize("heads,head_dim", [(12, 64), (8, 128)])
+def test_flash_attention_fwd_bwd(heads, head_dim):
+    """Causal, T=1024, bf16 — GPT-2-small's and a llama-style head."""
+    shape = (2, 1024, heads, head_dim)
+    q, k, v = (_normal(i, shape, jnp.bfloat16) for i in range(3))
+    w = _normal(3, shape)  # fixed cotangent: a weighted sum as the loss
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+
+    flash = lambda q, k, v: flash_attention(q, k, v, True)  # noqa: E731
+    out = jax.jit(flash)(q, k, v)
+    assert out.dtype == jnp.bfloat16
+    assert_close(out, _dense_causal(q, k, v), 5e-2)
+    got = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(loss(_dense_causal), argnums=(0, 1, 2)))(q, k, v)
+    # four bf16 roundings deep (g, p, ds, the result): measured 4e-2 of
+    # this bound through the interpreter, where the dots are exact f32
+    for g, r in zip(got, want):
+        assert_close(g, r, 1.5e-1)
+
+
+# ------------------------------------------------------------- grouped matmul
+E, D_MODEL, D_FF, ROWS = 8, 512, 2048, 4096
+GROUP_SIZES = [700, 0, 512, 300, 1000, 84, 988, 512]  # sums to ROWS
+
+
+def _gmm_inputs(dtype):
+    x = _normal(0, (ROWS, D_MODEL), dtype)
+    w = _normal(1, (E, D_MODEL, D_FF), dtype, scale=D_MODEL**-0.5)
+    b = _normal(2, (E, D_FF))
+    return x, w, b, jnp.asarray(GROUP_SIZES, jnp.int32)
+
+
+def _gmm_ref(x, w, gs, b=None, gelu=False):
+    ids = jnp.repeat(jnp.arange(E), gs, total_repeat_length=ROWS)
+    y = jax.lax.ragged_dot(
+        x.astype(jnp.float32), w.astype(jnp.float32), gs, precision=HIGHEST
+    )
+    if b is not None:
+        y = y + b[ids]
+    return jax.nn.gelu(y) if gelu else y
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_gmm_fwd_bwd(dtype):
+    """The MoE expert matmul at E=8, 512 -> 2048, default blocks."""
+    x, w, _, gs = _gmm_inputs(dtype)
+    pallas = lambda x, w: grouped_matmul(x, w, gs, impl="pallas")  # noqa: E731
+    assert_close(jax.jit(pallas)(x, w), _gmm_ref(x, w, gs), 5e-2)
+    ct = _normal(5, (ROWS, D_FF))
+    got = jax.jit(jax.grad(
+        lambda x, w: jnp.sum(pallas(x, w).astype(jnp.float32) * ct),
+        argnums=(0, 1),
+    ))(x, w)
+    want = jax.jit(jax.grad(
+        lambda x, w: jnp.sum(_gmm_ref(x, w, gs) * ct), argnums=(0, 1)
+    ))(x, w)
+    assert_close(got[0], want[0], 5e-2)
+    assert_close(got[1], want[1], 5e-2)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_gmm_fused_gelu_fwd_bwd(dtype):
+    """The bias+gelu epilogue kernel and its backward (dbias rides a K=1
+    tgmm) at the same shape."""
+    x, w, b, gs = _gmm_inputs(dtype)
+    fused = lambda x, w, b: grouped_matmul_fused(  # noqa: E731
+        x, w, b, gs, activation="gelu"
+    )
+    assert_close(jax.jit(fused)(x, w, b), _gmm_ref(x, w, gs, b, gelu=True), 5e-2)
+    ct = _normal(6, (ROWS, D_FF))
+    got = jax.jit(jax.grad(
+        lambda x, w, b: jnp.sum(fused(x, w, b).astype(jnp.float32) * ct),
+        argnums=(0, 1, 2),
+    ))(x, w, b)
+    want = jax.jit(jax.grad(
+        lambda x, w, b: jnp.sum(_gmm_ref(x, w, gs, b, gelu=True) * ct),
+        argnums=(0, 1, 2),
+    ))(x, w, b)
+    for g, r in zip(got, want):
+        assert_close(g, r, 5e-2)
+
+
+# ------------------------------------------------------------ paged attention
+SLOTS, PAGE, NUM_PAGES, PAGES_PER_SLOT = 8, 16, 256, 24
+
+
+def _paged_inputs(hq, hkv, d, dtype):
+    q = _normal(0, (SLOTS, 1, hq, d), dtype)
+    k = _normal(1, (NUM_PAGES, PAGE, hkv, d), dtype)
+    v = _normal(2, (NUM_PAGES, PAGE, hkv, d), dtype)
+    rng = np.random.default_rng(0)
+    # distinct live pages per slot; page 0 is the engine's trash page
+    table = rng.permutation(np.arange(1, NUM_PAGES))[: SLOTS * PAGES_PER_SLOT]
+    table = jnp.asarray(table.reshape(SLOTS, PAGES_PER_SLOT), jnp.int32)
+    # depths from a fresh slot (0) to a full table, page edges included
+    pos = jnp.asarray([0, 15, 16, 100, 255, 256, 300, 383], jnp.int32)
+    return q, k, v, table, pos
+
+
+@pytest.mark.parametrize("hq,hkv,d", [(12, 12, 64), (32, 4, 128)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_float(hq, hkv, d, dtype):
+    """GPT-2-small serving heads (12 x 64) and a GQA shape (32/4 x 128),
+    page 16, slots at mixed depths."""
+    q, k, v, table, pos = _paged_inputs(hq, hkv, d, dtype)
+    got = jax.jit(
+        lambda *a: paged_attention(*a, interpret=False)
+    )(q, k, v, table, pos)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(paged_decode_attention)(
+            *(x.astype(jnp.float32) for x in (q, k, v)), table, pos
+        )
+    assert got.shape == q.shape and got.dtype == dtype
+    assert_close(got, want, 3e-2)
+
+
+@pytest.mark.parametrize("hq,hkv,d", [(12, 12, 64), (32, 4, 128)])
+@pytest.mark.parametrize("qdtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_int8_kv(hq, hkv, d, qdtype):
+    q, k, v, table, pos = _paged_inputs(hq, hkv, d, jnp.float32)
+    q = q.astype(qdtype)
+    kq, ks = quantize_kv(k)
+    vq, vs = quantize_kv(v)
+    got = jax.jit(
+        lambda q, kq, vq, ks, vs, table, pos: paged_attention(
+            q, kq, vq, table, pos, key_scale_pages=ks, value_scale_pages=vs,
+            interpret=False,
+        )
+    )(q, kq, vq, ks, vs, table, pos)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(paged_decode_attention_quant)(
+            q.astype(jnp.float32), kq, vq, ks, vs, table, pos
+        )
+    assert got.dtype == qdtype
+    assert_close(got, want, 3e-2)
+
+
+# --------------------------------------------------------------- int8 matmul
+@pytest.mark.parametrize("rows", [8, 1024])
+def test_int8_matmul_lm_head(rows):
+    """GPT-2's 768 x 50304 head: the decode gemv and a prefill matmul."""
+    x = _normal(0, (rows, 768), jnp.bfloat16)
+    q, scale = quantize_int8(_normal(1, (768, 50304), scale=768**-0.5))
+    got = jax.jit(lambda *a: int8_matmul(*a, interpret=False))(x, q, scale)
+    want = jax.jit(int8_matmul_ref)(x, q, scale)
+    assert got.shape == (rows, 50304)
+    assert_close(got, want, 2e-2)
+
+
+# ---------------------------------------------------------------- fused xent
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_fused_xent_gpt2_vocab(dtype):
+    """V=50257 (not a tile multiple: the padded columns must carry no
+    mass), forward and the one-pass backward."""
+    n, v = 2048, 50257
+    logits = _normal(0, (n, v), dtype, scale=2.0)
+    labels = jax.random.randint(jax.random.key(1), (n,), 0, v)
+
+    def ref(lg):
+        logp = jax.nn.log_softmax(lg.astype(jnp.float32), axis=-1)
+        return -jnp.take_along_axis(logp, labels[:, None], axis=1)[:, 0]
+
+    assert_close(jax.jit(fused_cross_entropy)(logits, labels), ref(logits), 1e-3)
+    got = jax.jit(jax.grad(
+        lambda lg: fused_cross_entropy(lg, labels).mean()
+    ))(logits)
+    want = jax.jit(jax.grad(lambda lg: ref(lg).mean()))(logits)
+    assert got.dtype == dtype
+    assert_close(got * n, want * n, 1e-2 if dtype == jnp.bfloat16 else 1e-4)
+
+
+# ----------------------------------------------------------------- fused sgd
+def test_fused_sgd_resnet_leaves():
+    """Aligned and ragged leaves (a 3x3x512x512 conv, a stem conv, a
+    bias, the 10-way head bias) against the torch-SGD formula."""
+    lr, mu, wd = 0.1, 0.9, 1e-4
+    shapes = {"conv": (3, 3, 512, 512), "stem": (3, 3, 3, 64),
+              "bn": (64,), "head": (10,)}
+    p = {k: _normal(i, s) for i, (k, s) in enumerate(shapes.items())}
+    m = {k: _normal(10 + i, s) for i, (k, s) in enumerate(shapes.items())}
+    g = {k: _normal(20 + i, s) for i, (k, s) in enumerate(shapes.items())}
+    opt = FusedSGD(lr, mu, wd, interpret=False)
+    new_p, new_m = jax.jit(opt.apply)(p, m, g)
+    for k in shapes:
+        want_m = mu * m[k] + (g[k] + wd * p[k])
+        assert_close(new_m[k], want_m, 1e-5)
+        assert_close(new_p[k], p[k] - lr * want_m, 1e-5)
+
+
+# ---------------------------------------------------------------- conv wgrad
+@pytest.mark.parametrize(
+    "hw,cin,cout,stride",
+    [(16, 128, 128, 1), (8, 256, 256, 1), (32, 64, 128, 2), (16, 128, 256, 2)],
+)
+def test_conv3x3_wgrad(hw, cin, cout, stride):
+    """ResNet-18 stage-2/3 convs and the two stage-entry stride-2 convs
+    at the scored per-chip batch, bf16."""
+    batch = 4096
+    x = _normal(0, (batch, hw, hw, cin), jnp.bfloat16)
+    g = _normal(1, (batch, hw // stride, hw // stride, cout), jnp.bfloat16)
+    got = jax.jit(
+        lambda x, g: conv3x3_wgrad(x, g, stride=stride, interpret=False)
+    )(x, g)
+
+    def conv(w):
+        return jax.lax.conv_general_dilated(
+            x.astype(jnp.float32), w, (stride, stride), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=HIGHEST,
+        )
+
+    w0 = jnp.zeros((3, 3, cin, cout), jnp.float32)
+    want = jax.jit(lambda g: jax.vjp(conv, w0)[1](g)[0])(g.astype(jnp.float32))
+    assert got.shape == (3, 3, cin, cout) and got.dtype == jnp.float32
+    assert_close(got, want, 1e-2)
