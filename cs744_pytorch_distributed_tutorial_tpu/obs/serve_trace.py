@@ -763,10 +763,12 @@ def profile_serve_programs(
 
     # A ServeChaosMonkey wraps _decode_step in a plain function; unwrap
     # to the jitted original — for .lower(), and so profiling re-runs
-    # never advance the monkey's fault counter.
-    decode_step = getattr(
-        engine._decode_step, "__wrapped__", engine._decode_step
-    )
+    # never advance the monkey's fault counter. (A jitted function has a
+    # ``__wrapped__`` of its own, the plain Python function: stop at the
+    # first that can lower.)
+    decode_step = engine._decode_step
+    while not hasattr(decode_step, "lower"):
+        decode_step = decode_step.__wrapped__
 
     key = engine._sample_root
     dec_args = (

@@ -69,6 +69,7 @@ from cs744_pytorch_distributed_tutorial_tpu.infer.generate import (
     sample_tokens,
 )
 from cs744_pytorch_distributed_tutorial_tpu.serve.pool import PagePool
+from cs744_pytorch_distributed_tutorial_tpu.utils import profiling
 from cs744_pytorch_distributed_tutorial_tpu.utils.failure import (
     DecodeNanError,
 )
@@ -313,6 +314,13 @@ class ServingEngine:
         self._completed: list[Request] = []
         self._timed_out = 0  # requests retired at deadline expiry
         self._shed = 0  # requests rejected at admission control
+        # Phase counters at the span boundaries of step() (the table in
+        # docs/observability.md): kept whether or not a profiler runs,
+        # so stats() gives admissions per step without a trace.
+        self._admissions = 0
+        self._admit_steps = 0  # steps that admitted at least one request
+        self._max_admits_in_step = 0
+        self._pages_grown = 0  # pages the grow loop allocated
         self._base_key = jax.random.key(cfg.seed)
         # One PRNG stream PER REQUEST, indexed by absolute output-token
         # position: token t of request r always samples from
@@ -791,57 +799,66 @@ class ServingEngine:
             admit_kind = "prefill"
         req.replay_pending = False
         plen = int(req.prompt.size)
-        replayed = max(0, plen - req.orig_prompt_len)
-        need = max(1, self.pool.pages_for(plen))
-        pages = self.pool.alloc(need)
-        row = np.zeros((self.cfg.max_pages_per_slot,), np.int32)
-        row[: len(pages)] = pages
         bucket = self._bucket_for(plen)
-        prompt = np.zeros((1, bucket), np.int32)
-        prompt[0, :plen] = req.prompt
-        # The (request, token-index) stream — a recompute-preempted
-        # request's re-prefill samples token index ``output_tokens``
-        # (the first NOT-yet-produced one) from the same key a decode
-        # step would have used, so replay reproduces the original
-        # tokens at any temperature.
-        key = jax.random.fold_in(
-            jax.random.fold_in(self._sample_root, req.req_id),
-            req.output_tokens,
-        )
-        self._pages, first_tok = self._prefill_fn(bucket)(
-            self.params,
-            self._pages,
-            jnp.asarray(prompt),
-            jnp.int32(plen),
-            jnp.asarray(row),
-            key,
-        )
-        tok = int(first_tok)  # blocks — the request's first token
-        now = self.clock()
-        first = req.first_token_time is None
-        if first:
-            req.first_token_time = now
-        # Rows [plen, bucket) of the padded prompt scattered to trash.
-        self._trash_rows += bucket - plen
-        if self.tracer is not None:
-            self.tracer.on_admit(
-                req, slot=slot_idx, bucket=bucket, t0=t_admit, t1=now,
-                kind=admit_kind, replayed=replayed,
-            )
-            if first:
-                self.tracer.sample_ttft(
-                    (now - req.arrival_time) * 1e3, now
+        # The profiler's spans (docs/observability.md) open at the stamps
+        # the tracer's hooks get, so both describe the same intervals.
+        with profiling.annotate(
+            "serve/admit", step=self._step_count, req=req.req_id,
+            bucket=bucket, kind=admit_kind, prompt_len=plen,
+        ):
+            with profiling.annotate("serve/admit_prep", req=req.req_id):
+                replayed = max(0, plen - req.orig_prompt_len)
+                need = max(1, self.pool.pages_for(plen))
+                pages = self.pool.alloc(need)
+                row = np.zeros((self.cfg.max_pages_per_slot,), np.int32)
+                row[: len(pages)] = pages
+                prompt = np.zeros((1, bucket), np.int32)
+                prompt[0, :plen] = req.prompt
+                # The (request, token-index) stream — a recompute-preempted
+                # request's re-prefill samples token index
+                # ``output_tokens`` (the first NOT-yet-produced one) from
+                # the same key a decode step would have used, so replay
+                # reproduces the original tokens at any temperature.
+                key = jax.random.fold_in(
+                    jax.random.fold_in(self._sample_root, req.req_id),
+                    req.output_tokens,
                 )
-        req.generated.append(tok)
-        self._surface(req, tok, now)
-        self._admit_seq += 1
-        self._slots[slot_idx] = _Slot(
-            req=req, length=plen, pages=pages, last_tok=tok,
-            admit_seq=self._admit_seq,
-        )
-        self._page_table[slot_idx, :] = row
-        if self._slot_done(self._slots[slot_idx]):
-            self._retire(slot_idx)
+                prefill = self._prefill_fn(bucket)
+                args = (
+                    jnp.asarray(prompt), jnp.int32(plen), jnp.asarray(row),
+                )
+            with profiling.annotate(
+                "serve/prefill", req=req.req_id, bucket=bucket
+            ):
+                self._pages, first_tok = prefill(
+                    self.params, self._pages, *args, key
+                )
+                tok = int(first_tok)  # blocks — the request's first token
+            now = self.clock()
+            first = req.first_token_time is None
+            if first:
+                req.first_token_time = now
+            # Rows [plen, bucket) of the padded prompt scattered to trash.
+            self._trash_rows += bucket - plen
+            if self.tracer is not None:
+                self.tracer.on_admit(
+                    req, slot=slot_idx, bucket=bucket, t0=t_admit, t1=now,
+                    kind=admit_kind, replayed=replayed,
+                )
+                if first:
+                    self.tracer.sample_ttft(
+                        (now - req.arrival_time) * 1e3, now
+                    )
+            req.generated.append(tok)
+            self._surface(req, tok, now)
+            self._admit_seq += 1
+            self._slots[slot_idx] = _Slot(
+                req=req, length=plen, pages=pages, last_tok=tok,
+                admit_seq=self._admit_seq,
+            )
+            self._page_table[slot_idx, :] = row
+            if self._slot_done(self._slots[slot_idx]):
+                self._retire(slot_idx)
 
     def _slot_done(self, slot: _Slot) -> bool:
         if len(slot.req.generated) >= slot.req.max_new_tokens:
@@ -947,8 +964,22 @@ class ServingEngine:
         (prefill+commit each), grow page tables for slots crossing a
         page boundary (preempting LIFO if the pool is dry), then run ONE
         fixed-shape decode step over all slots and retire the finished.
-        Returns the requests completed during this iteration."""
+        Returns the requests completed during this iteration.
+
+        Each phase is a span on the profiler's timeline (``serve/step``
+        and its children; docs/observability.md has the table). Every
+        phase is synchronous, so the spans partition the step's wall time
+        and the device's busy and idle time alike; with no capture
+        running they record nothing."""
+        with profiling.annotate(
+            "serve/step", step=self._step_count, queued=len(self._queue),
+            active=sum(s is not None for s in self._slots),
+        ):
+            return self._step()
+
+    def _step(self) -> list[Request]:
         done_before = len(self._completed)
+        step = self._step_count
 
         # Deadline sweep BEFORE refill: an expired queue head must not
         # be admitted, and an expired active slot's pages must be free
@@ -956,13 +987,15 @@ class ServingEngine:
         # decode step below never sees a deadline, so the zero-retrace
         # contract is untouched.
         if self.guard is not None:
-            self.guard.expire(self)
+            with profiling.annotate("serve/expire", step=step):
+                self.guard.expire(self)
 
         # refill — FCFS with head-of-line blocking: a new request only
         # admits when its prompt's pages are FREE. Never preempt to
         # admit (the queue head is by definition younger than every
         # active request — killing running work for it would invert
         # priority and can livelock with re-queued victims).
+        admits = 0
         for i in range(self.cfg.num_slots):
             if not self._queue:
                 break
@@ -972,26 +1005,36 @@ class ServingEngine:
             if not self.pool.can_alloc(max(1, self.pool.pages_for(plen))):
                 break
             self._admit(i, self._queue.popleft())
+            admits += 1
+        if admits:
+            self._admissions += admits
+            self._admit_steps += 1
+            self._max_admits_in_step = max(self._max_admits_in_step, admits)
 
         # grow: every active slot needs a page for the KV row its next
         # fed token writes (position slot.length)
-        for i in range(self.cfg.num_slots):
-            slot = self._slots[i]
-            if slot is None or self._slot_done(slot):
-                continue
-            page_idx = slot.length // self.cfg.page_size
-            if page_idx < len(slot.pages):
-                continue
-            if not self._ensure_pages(1):
-                raise RuntimeError("page pool dry with no active slots")
-            slot = self._slots[i]  # _ensure_pages may have preempted i
-            if slot is None or slot.length // self.cfg.page_size < len(
-                slot.pages
-            ):
-                continue
-            new_page = self.pool.alloc(1)[0]
-            self._page_table[i, len(slot.pages)] = new_page
-            slot.pages.append(new_page)
+        with profiling.annotate("serve/grow", step=step) as grow_span:
+            grown = 0
+            for i in range(self.cfg.num_slots):
+                slot = self._slots[i]
+                if slot is None or self._slot_done(slot):
+                    continue
+                page_idx = slot.length // self.cfg.page_size
+                if page_idx < len(slot.pages):
+                    continue
+                if not self._ensure_pages(1):
+                    raise RuntimeError("page pool dry with no active slots")
+                slot = self._slots[i]  # _ensure_pages may have preempted i
+                if slot is None or slot.length // self.cfg.page_size < len(
+                    slot.pages
+                ):
+                    continue
+                new_page = self.pool.alloc(1)[0]
+                self._page_table[i, len(slot.pages)] = new_page
+                slot.pages.append(new_page)
+                grown += 1
+            self._pages_grown += grown
+            grow_span.set_metadata(pages=grown)
 
         if not any(s is not None for s in self._slots):
             return self._completed[done_before:]
@@ -999,79 +1042,88 @@ class ServingEngine:
         # decode one token for every active slot
         cfg = self.cfg
         t_d0 = self.clock()
-        tokens = np.full((cfg.num_slots,), cfg.pad_id, np.int32)
-        lengths = np.zeros((cfg.num_slots,), np.int32)
-        active = np.zeros((cfg.num_slots,), bool)
-        req_ids = np.zeros((cfg.num_slots,), np.int32)
-        tok_idx = np.zeros((cfg.num_slots,), np.int32)
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            tokens[i] = slot.last_tok
-            lengths[i] = slot.length
-            active[i] = True
-            req_ids[i] = slot.req.req_id
-            # Absolute output-token index this step produces for the
-            # request — the per-request PRNG stream position (see
-            # _sample_root; replay-exact across preemptions).
-            tok_idx[i] = slot.req.output_tokens
-        self._pages, toks = self._decode_step(
-            self.params,
-            self._pages,
-            jnp.asarray(tokens),
-            jnp.asarray(lengths),
-            jnp.asarray(self._page_table),
-            jnp.asarray(active),
-            jnp.asarray(req_ids),
-            jnp.asarray(tok_idx),
-            self._sample_root,
-        )
-        toks = np.asarray(toks)  # graftlint: disable=GL001 -- the scheduler NEEDS this sync: retire/refill decisions read the sampled tokens; one fetch per engine step, outside any jit
-        # NaN detection on the already-fetched tokens (zero extra
-        # transfers): poisoned logits sample out-of-vocab. Raised BEFORE
-        # any per-step bookkeeping mutates, so the host state a
-        # post-crash snapshot() captures is exactly the pre-step world —
-        # run_serve_with_recovery replays this step on a fresh engine.
-        bad = active & ((toks < 0) | (toks >= self.model.vocab_size))
-        if bad.any():
-            raise DecodeNanError(
-                step=self._step_count, slots=np.nonzero(bad)[0]
+        with profiling.annotate("serve/decode_prep", step=step):
+            tokens = np.full((cfg.num_slots,), cfg.pad_id, np.int32)
+            lengths = np.zeros((cfg.num_slots,), np.int32)
+            active = np.zeros((cfg.num_slots,), bool)
+            req_ids = np.zeros((cfg.num_slots,), np.int32)
+            tok_idx = np.zeros((cfg.num_slots,), np.int32)
+            for i, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                tokens[i] = slot.last_tok
+                lengths[i] = slot.length
+                active[i] = True
+                req_ids[i] = slot.req.req_id
+                # Absolute output-token index this step produces for the
+                # request — the per-request PRNG stream position (see
+                # _sample_root; replay-exact across preemptions).
+                tok_idx[i] = slot.req.output_tokens
+            n_active = int(active.sum())
+            args = (
+                jnp.asarray(tokens),
+                jnp.asarray(lengths),
+                jnp.asarray(self._page_table),
+                jnp.asarray(active),
+                jnp.asarray(req_ids),
+                jnp.asarray(tok_idx),
             )
-        self._step_count += 1
-        n_active = int(active.sum())
-        self._active_slot_steps += n_active
-        # Inactive slots still write one KV row per step — to the trash
-        # page (fixed-shape contract).
-        self._trash_rows += cfg.num_slots - n_active
-        now = self.clock()
-        self._decode_walls.append(now - t_d0)
-        if self._straggler is not None:
-            self._straggler.record(self._step_count, now - t_d0)
-        window = None
-        if self.tracer is not None:
-            # Snapshot slot residency BEFORE retiring — the hook extends
-            # each live slot's coalesced decode_run span to ``now``, the
-            # same stamp the tokens below surface with.
-            slot_reqs = {
-                i: s.req.req_id
-                for i, s in enumerate(self._slots)
-                if s is not None
-            }
-            window = self.tracer.on_decode_step(
-                t_d0, now, slot_reqs, self._pool_stats(), len(self._queue)
+        with profiling.annotate("serve/decode", step=step, active=n_active):
+            self._pages, toks = self._decode_step(
+                self.params, self._pages, *args, self._sample_root
             )
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            slot.length += 1
-            slot.last_tok = int(toks[i])
-            slot.req.generated.append(slot.last_tok)
-            self._surface(slot.req, slot.last_tok, now)
-            if self._slot_done(slot):
-                self._retire(i)
-        if window is not None:
-            self._emit(window)
-        return self._completed[done_before:]
+            toks = np.asarray(toks)  # graftlint: disable=GL001 -- the scheduler NEEDS this sync: retire/refill decisions read the sampled tokens; one fetch per engine step, outside any jit
+        with profiling.annotate("serve/retire", step=step) as retire_span:
+            # NaN detection on the already-fetched tokens (zero extra
+            # transfers): poisoned logits sample out-of-vocab. Raised
+            # BEFORE any per-step bookkeeping mutates, so the host state a
+            # post-crash snapshot() captures is exactly the pre-step world
+            # — run_serve_with_recovery replays this step on a fresh
+            # engine.
+            bad = active & ((toks < 0) | (toks >= self.model.vocab_size))
+            if bad.any():
+                raise DecodeNanError(
+                    step=self._step_count, slots=np.nonzero(bad)[0]
+                )
+            self._step_count += 1
+            self._active_slot_steps += n_active
+            # Inactive slots still write one KV row per step — to the
+            # trash page (fixed-shape contract).
+            self._trash_rows += cfg.num_slots - n_active
+            now = self.clock()
+            self._decode_walls.append(now - t_d0)
+            if self._straggler is not None:
+                self._straggler.record(self._step_count, now - t_d0)
+            window = None
+            if self.tracer is not None:
+                # Snapshot slot residency BEFORE retiring — the hook
+                # extends each live slot's coalesced decode_run span to
+                # ``now``, the same stamp the tokens below surface with.
+                slot_reqs = {
+                    i: s.req.req_id
+                    for i, s in enumerate(self._slots)
+                    if s is not None
+                }
+                window = self.tracer.on_decode_step(
+                    t_d0, now, slot_reqs, self._pool_stats(),
+                    len(self._queue),
+                )
+            done_at_fetch = len(self._completed)
+            for i, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                slot.length += 1
+                slot.last_tok = int(toks[i])
+                slot.req.generated.append(slot.last_tok)
+                self._surface(slot.req, slot.last_tok, now)
+                if self._slot_done(slot):
+                    self._retire(i)
+            if window is not None:
+                self._emit(window)
+            retire_span.set_metadata(
+                retired=len(self._completed) - done_at_fetch
+            )
+            return self._completed[done_before:]
 
     def run(self) -> list[Request]:
         """Drain: step until the queue and every slot are empty."""
@@ -1255,6 +1307,10 @@ class ServingEngine:
             "shed_requests": self._shed,
             "page_churn": self.pool.total_allocs + self.pool.total_frees,
             "trash_rows_written": self._trash_rows,
+            "admissions": self._admissions,
+            "admit_steps": self._admit_steps,
+            "max_admits_in_step": self._max_admits_in_step,
+            "pages_grown": self._pages_grown,
         }
 
 

@@ -233,7 +233,7 @@ def _merge_stats(total: dict[str, Any], part: dict[str, Any]) -> None:
     """Fold one engine generation's ``stats()`` into the running totals
     (sums for counters, max for high-water marks)."""
     for k, v in part.items():
-        if k in ("page_high_water",):
+        if k in ("page_high_water", "max_admits_in_step"):
             total[k] = max(total.get(k, 0), v)
         elif k in ("slot_occupancy", "pages_allocatable"):
             total[k] = v  # latest generation's view
@@ -329,6 +329,8 @@ def run_serve_with_recovery(
         engine._step_count = 0
         engine._active_slot_steps = 0
         engine._trash_rows = 0
+        engine._admissions = engine._admit_steps = 0
+        engine._max_admits_in_step = engine._pages_grown = 0
         engine._decode_walls.clear()
         engine._event_ring.clear()
         engine.pool.high_water = 0

@@ -250,6 +250,8 @@ def run_poisson(
         engine._step_count = 0
         engine._active_slot_steps = 0
         engine._trash_rows = 0
+        engine._admissions = engine._admit_steps = 0
+        engine._max_admits_in_step = engine._pages_grown = 0
         engine._decode_walls.clear()
         engine._event_ring.clear()
         engine.pool.high_water = engine.pool.allocated_pages

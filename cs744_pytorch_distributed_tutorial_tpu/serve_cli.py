@@ -34,6 +34,7 @@ parity contract are weight-independent, so the CLI does not train.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 
@@ -109,6 +110,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "p50/p99, queue depth, preemption rate, pool "
                         "counters); defaults to 0.25 when --trace-dir "
                         "is set")
+    p.add_argument("--profile-dir", default=None,
+                   help="capture the JAX profiler's trace of the serving "
+                        "run here (view in TensorBoard profile / "
+                        "ui.perfetto.dev): the engine's serve/* phase "
+                        "spans beside the device's lines, on one clock")
     # graftguard: deadlines + admission control (serve/guard.py);
     # setting any of these attaches a ServeGuard to the engine
     p.add_argument("--deadline-s", type=float, default=None,
@@ -203,6 +209,7 @@ def main(argv: list[str] | None = None) -> None:
         run_poisson,
         run_serve_with_recovery,
     )
+    from cs744_pytorch_distributed_tutorial_tpu.utils import profiling
 
     model = TransformerLM(
         vocab_size=args.vocab_size,
@@ -310,65 +317,72 @@ def main(argv: list[str] | None = None) -> None:
                 degrade_floor=args.degrade_floor,
             ))
 
-        if args.chaos or args.step_timeout_s is not None:
-            # Supervised recovery loop: the supervisor owns the flight
-            # recorder (one per engine generation, armed by its step
-            # watchdog) and restarts the engine from its snapshot on
-            # any ServeFailure.
-            from cs744_pytorch_distributed_tutorial_tpu.utils.chaos import (
-                SERVE_FAULT_KINDS,
-                FaultSchedule,
-                ServeChaosMonkey,
-            )
-
-            monkey = None
-            if args.chaos:
-                faults = _parse_chaos(args.chaos)
-                bad = sorted(
-                    set(faults.values()) - set(SERVE_FAULT_KINDS)
+        # The profiler's capture spans the serving run, its warm-up
+        # included (the warm-up's serve/step spans and the compiles come
+        # first on the timeline).
+        with (
+            profiling.trace(args.profile_dir)
+            if args.profile_dir else contextlib.nullcontext()
+        ):
+            if args.chaos or args.step_timeout_s is not None:
+                # Supervised recovery loop: the supervisor owns the flight
+                # recorder (one per engine generation, armed by its step
+                # watchdog) and restarts the engine from its snapshot on
+                # any ServeFailure.
+                from cs744_pytorch_distributed_tutorial_tpu.utils.chaos import (
+                    SERVE_FAULT_KINDS,
+                    FaultSchedule,
+                    ServeChaosMonkey,
                 )
-                if bad:
-                    raise SystemExit(
-                        f"--chaos kinds {bad} not in {SERVE_FAULT_KINDS}"
+
+                monkey = None
+                if args.chaos:
+                    faults = _parse_chaos(args.chaos)
+                    bad = sorted(
+                        set(faults.values()) - set(SERVE_FAULT_KINDS)
                     )
-                monkey = ServeChaosMonkey(
-                    FaultSchedule(faults), telemetry=sink
+                    if bad:
+                        raise SystemExit(
+                            f"--chaos kinds {bad} not in {SERVE_FAULT_KINDS}"
+                        )
+                    monkey = ServeChaosMonkey(
+                        FaultSchedule(faults), telemetry=sink
+                    )
+
+                engines: list = []
+
+                def make_engine():
+                    eng = ServingEngine(
+                        model, params, cfg,
+                        sink=sink, tracer=tracer, guard=guard,
+                    )
+                    engines.append(eng)
+                    return eng
+
+                serve_rec = run_serve_with_recovery(
+                    make_engine, workload,
+                    monkey=monkey,
+                    max_restarts=args.max_restarts,
+                    backoff_s=args.restart_backoff_s,
+                    step_timeout_s=args.step_timeout_s,
+                    telemetry=sink,
+                    sink=sink,
                 )
-
-            engines: list = []
-
-            def make_engine():
-                eng = ServingEngine(
-                    model, params, cfg,
-                    sink=sink, tracer=tracer, guard=guard,
+                engine = engines[-1]
+            else:
+                engine = ServingEngine(
+                    model, params, cfg, sink=sink, tracer=tracer, guard=guard,
                 )
-                engines.append(eng)
-                return eng
-
-            serve_rec = run_serve_with_recovery(
-                make_engine, workload,
-                monkey=monkey,
-                max_restarts=args.max_restarts,
-                backoff_s=args.restart_backoff_s,
-                step_timeout_s=args.step_timeout_s,
-                telemetry=sink,
-                sink=sink,
-            )
-            engine = engines[-1]
-        else:
-            engine = ServingEngine(
-                model, params, cfg, sink=sink, tracer=tracer, guard=guard,
-            )
-            # Flight recorder over the serving loop: SIGTERM/uncaught-
-            # crash dumps the serve event ring tail + pool high-water
-            # through the sink — same discipline the training engines
-            # get.
-            flight = engine.make_flight_recorder()
-            flight.install()
-            try:
-                serve_rec = run_poisson(engine, workload, sink=sink)
-            finally:
-                flight.uninstall()
+                # Flight recorder over the serving loop: SIGTERM/uncaught-
+                # crash dumps the serve event ring tail + pool high-water
+                # through the sink — same discipline the training engines
+                # get.
+                flight = engine.make_flight_recorder()
+                flight.install()
+                try:
+                    serve_rec = run_poisson(engine, workload, sink=sink)
+                finally:
+                    flight.uninstall()
 
         if args.trace_dir:
             import os

@@ -1,0 +1,308 @@
+"""The profiler's phase spans inside ``ServingEngine.step()``.
+
+One tiny engine is driven under ``jax.profiler.start_trace`` (Python
+tracer off) and the capture is read back with ``ProfileData``: the spans
+are the contract the benchmark's per-layer metrics read
+(docs/observability.md has the table), so their names, nesting, order
+and fields are pinned here, beside the counters ``stats()`` keeps at the
+same boundaries and the promise that nothing changes with no capture
+running.
+"""
+
+from __future__ import annotations
+
+from collections import Counter
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cs744_pytorch_distributed_tutorial_tpu.models import TransformerLM
+from cs744_pytorch_distributed_tutorial_tpu.obs.system import CompileCounter
+from cs744_pytorch_distributed_tutorial_tpu.serve import (
+    GuardConfig,
+    Request,
+    ServeConfig,
+    ServeGuard,
+    ServingEngine,
+)
+from cs744_pytorch_distributed_tutorial_tpu.utils import profiling
+from cs744_pytorch_distributed_tutorial_tpu.utils.failure import (
+    DecodeNanError,
+)
+
+VOCAB = 61
+# 8 allocatable pages and slots that want up to 7 each: the pool runs
+# dry, so the grow loop pre-empts and re-admissions are recomputes.
+CASES = [(6, 18), (10, 14), (8, 16), (5, 20), (12, 12)]
+
+
+def _submit_all(eng, rng):
+    return [
+        eng.submit(Request(
+            prompt=rng.integers(1, VOCAB, size=plen).astype(np.int32),
+            max_new_tokens=budget,
+        ))
+        for plen, budget in CASES
+    ]
+
+
+def _drive(eng):
+    """step() until drained; returns the number of calls."""
+    calls = 0
+    while eng.busy:
+        eng.step()
+        calls += 1
+    return calls
+
+
+def _outputs(reqs):
+    # Pre-emption moves produced tokens into the prompt.
+    return [
+        list(r.prompt[r.orig_prompt_len:]) + list(r.generated) for r in reqs
+    ]
+
+
+def _read_spans(trace_dir):
+    from jax.profiler import ProfileData
+
+    path = max(trace_dir.rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
+    spans = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve/"):
+                    spans.append({
+                        "name": ev.name, "lo": ev.start_ns,
+                        "hi": ev.start_ns + ev.duration_ns,
+                        **dict(ev.stats),
+                    })
+    return sorted(spans, key=lambda s: (s["lo"], -s["hi"]))
+
+
+@pytest.fixture(scope="module")
+def capture(tmp_path_factory):
+    model = TransformerLM(
+        vocab_size=VOCAB, num_layers=2, num_heads=2, d_model=32, d_ff=64,
+        max_seq_len=64, attention_impl="dense", use_rope=True,
+    )
+    params = model.init(
+        jax.random.key(0), jnp.zeros((1, 4), jnp.int32)
+    )["params"]
+    cfg = ServeConfig(
+        num_slots=3, page_size=4, num_pages=9, max_pages_per_slot=7
+    )
+    eng = ServingEngine(model, params, cfg)
+    # Warm-up off the capture: the same workload compiles every prefill
+    # bucket (re-admissions' too) and the decode step.
+    _submit_all(eng, np.random.default_rng(13))
+    _drive(eng)
+    compiles = CompileCounter()
+    stats0 = eng.stats()
+
+    trace_dir = tmp_path_factory.mktemp("serve_spans")
+    with profiling.trace(str(trace_dir)):
+        traced = _submit_all(eng, np.random.default_rng(13))
+        calls = _drive(eng)
+        stats1 = eng.stats()
+        # a few steps with a guard set: the deadline sweep gets its span
+        eng.guard = ServeGuard(cfg=GuardConfig(deadline_s=3600.0))
+        eng.submit(Request(
+            prompt=np.arange(1, 6, dtype=np.int32), max_new_tokens=3
+        ))
+        guarded_calls = _drive(eng)
+        eng.guard = None
+        # a step that raises on its way out of the decode
+        decode = eng._decode_step
+        eng._decode_step = lambda params, pages, *a: (
+            pages, np.full((cfg.num_slots,), VOCAB + 7, np.int32)
+        )
+        eng.submit(Request(
+            prompt=np.arange(1, 6, dtype=np.int32), max_new_tokens=3
+        ))
+        with pytest.raises(DecodeNanError):
+            eng.step()
+        eng._decode_step = decode
+        _drive(eng)
+    spans = _read_spans(trace_dir)
+    n_traced_compiles = compiles.count
+
+    # the same workload again with no capture running
+    untraced = _submit_all(eng, np.random.default_rng(13))
+    _drive(eng)
+    return {
+        "spans": spans,
+        # the spans of the first, unguarded run: its steps are numbered
+        # from the warm-up's last
+        "run": [
+            s for s in spans
+            if s["lo"] < _named(spans, "serve/step")[calls]["lo"]
+        ],
+        "calls": calls, "guarded_calls": guarded_calls,
+        "stats": {k: stats1[k] - stats0[k] for k in (
+            "admissions", "admit_steps", "pages_grown", "requests_done",
+            "preemptions", "decode_steps",
+        )},
+        "max_admits_in_step": stats1["max_admits_in_step"],
+        "traced": traced, "untraced": untraced,
+        "compiles_traced": n_traced_compiles,
+        "compiles_after": compiles.count,
+    }
+
+
+def _named(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+def _inside(child, parent):
+    return parent["lo"] <= child["lo"] and child["hi"] <= parent["hi"]
+
+
+def _children(spans, parent, name):
+    return [s for s in _named(spans, name) if _inside(s, parent)]
+
+
+def check_one_step_span_per_call(c):
+    steps = _named(c["run"], "serve/step")
+    assert len(steps) == c["calls"]
+    assert all({"step", "queued", "active"} <= set(s) for s in steps)
+    assert steps[0]["queued"] == len(CASES) and steps[0]["active"] == 0
+    # the engine's counter: it does not fall, and rises by the decodes
+    numbers = [s["step"] for s in steps]
+    assert numbers == sorted(numbers)
+    assert numbers[-1] - numbers[0] == c["stats"]["decode_steps"] - 1
+
+
+def check_one_admit_span_per_admission(c):
+    admits = _named(c["run"], "serve/admit")
+    assert len(admits) == c["stats"]["admissions"]
+    assert len(admits) == len(CASES) + c["stats"]["preemptions"]
+    steps = _named(c["run"], "serve/step")
+    for a in admits:
+        assert {"step", "req", "bucket", "kind", "prompt_len"} <= set(a)
+        assert a["bucket"] >= a["prompt_len"] >= 1
+        parents = [s for s in steps if _inside(a, s)]
+        assert len(parents) == 1 and parents[0]["step"] == a["step"]
+    assert {a["req"] for a in admits} == {r.req_id for r in c["traced"]}
+
+
+def check_prep_and_prefill_nest_in_their_admit(c):
+    for a in _named(c["run"], "serve/admit"):
+        prep = _children(c["run"], a, "serve/admit_prep")
+        prefill = _children(c["run"], a, "serve/prefill")
+        assert len(prep) == 1 and len(prefill) == 1
+        assert prep[0]["req"] == prefill[0]["req"] == a["req"]
+        assert prefill[0]["bucket"] == a["bucket"]
+        assert prep[0]["hi"] <= prefill[0]["lo"]
+
+
+def check_decode_phases_in_order(c):
+    decoded = 0
+    for s in _named(c["run"], "serve/step"):
+        grow = _children(c["run"], s, "serve/grow")
+        phases = [
+            _children(c["run"], s, f"serve/{n}")
+            for n in ("decode_prep", "decode", "retire")
+        ]
+        assert len(grow) == 1 and grow[0]["step"] == s["step"]
+        assert all(len(p) == 1 for p in phases)
+        prep, decode, retire = (p[0] for p in phases)
+        assert grow[0]["hi"] <= prep["lo"]
+        assert prep["hi"] <= decode["lo"] and decode["hi"] <= retire["lo"]
+        assert retire["hi"] <= s["hi"]
+        assert prep["step"] == decode["step"] == retire["step"] == s["step"]
+        assert 1 <= decode["active"] <= 3
+        for a in _children(c["run"], s, "serve/admit"):
+            assert a["hi"] <= grow[0]["lo"]
+        decoded += 1
+    assert decoded == c["stats"]["decode_steps"]
+    assert not _named(c["run"], "serve/expire")  # no guard, no sweep
+
+
+def check_readmission_is_a_recompute_under_the_same_req(c):
+    assert c["stats"]["preemptions"] > 0, "pool was not tight enough"
+    by_req: dict[int, list[str]] = {}
+    for a in _named(c["run"], "serve/admit"):
+        by_req.setdefault(a["req"], []).append(a["kind"])
+    assert all(kinds[0] == "prefill" for kinds in by_req.values())
+    again = [k for kinds in by_req.values() for k in kinds[1:]]
+    assert len(again) == c["stats"]["preemptions"]
+    assert set(again) == {"recompute"}
+
+
+def check_counters_equal_span_counts(c):
+    steps = _named(c["run"], "serve/step")
+    per_step = [len(_children(c["run"], s, "serve/admit")) for s in steps]
+    assert c["stats"]["admissions"] == sum(per_step)
+    assert c["stats"]["admit_steps"] == sum(1 for n in per_step if n)
+    assert c["max_admits_in_step"] == max(per_step)
+    grown = sum(s["pages"] for s in _named(c["run"], "serve/grow"))
+    assert c["stats"]["pages_grown"] == grown > 0
+    retired = sum(s["retired"] for s in _named(c["run"], "serve/retire"))
+    assert c["stats"]["requests_done"] == retired == len(CASES)
+
+
+def check_expire_span_only_under_a_guard(c):
+    steps = _named(c["spans"], "serve/step")
+    guarded = steps[c["calls"]: c["calls"] + c["guarded_calls"]]
+    assert len(guarded) == c["guarded_calls"] >= 2
+    sweeps = _named(c["spans"], "serve/expire")
+    assert len(sweeps) == len(guarded)
+    for s, sweep in zip(guarded, sweeps):
+        assert _inside(sweep, s) and sweep["step"] == s["step"]
+
+
+def check_an_exception_closes_the_spans_it_passes(c):
+    # A span is recorded when it closes: the raising step's are all there.
+    steps = _named(c["spans"], "serve/step")
+    raised = steps[c["calls"] + c["guarded_calls"]]
+    names = Counter(
+        s["name"] for s in c["spans"] if s is not raised and _inside(s, raised)
+    )
+    assert names == {
+        "serve/admit": 1, "serve/admit_prep": 1, "serve/prefill": 1,
+        "serve/grow": 1, "serve/decode_prep": 1, "serve/decode": 1,
+        "serve/retire": 1,
+    }
+    # it never got to count its retirements
+    retire = next(
+        s for s in _named(c["spans"], "serve/retire") if _inside(s, raised)
+    )
+    assert "retired" not in retire
+
+
+def check_no_capture_same_tokens_no_compile(c):
+    assert _outputs(c["untraced"]) == _outputs(c["traced"])
+    assert all(len(o) == b for o, (_, b) in zip(_outputs(c["traced"]), CASES))
+    assert c["compiles_traced"] == 0 and c["compiles_after"] == 0
+
+
+CHECKS = [
+    check_one_step_span_per_call,
+    check_one_admit_span_per_admission,
+    check_prep_and_prefill_nest_in_their_admit,
+    check_decode_phases_in_order,
+    check_readmission_is_a_recompute_under_the_same_req,
+    check_counters_equal_span_counts,
+    check_expire_span_only_under_a_guard,
+    check_an_exception_closes_the_spans_it_passes,
+    check_no_capture_same_tokens_no_compile,
+]
+
+
+@pytest.mark.parametrize(
+    "check", CHECKS, ids=[f.__name__.removeprefix("check_") for f in CHECKS]
+)
+def test_serve_spans(capture, check):
+    check(capture)
+
+
+def test_annotate_passes_fields_and_late_metadata(tmp_path):
+    with profiling.trace(str(tmp_path)):
+        with profiling.annotate("serve/probe", req=7, kind="prefill") as span:
+            span.set_metadata(pages=2)
+    (probe,) = _read_spans(tmp_path)
+    assert (probe["req"], probe["kind"], probe["pages"]) == (7, "prefill", 2)
