@@ -181,7 +181,7 @@ class Attention(nn.Module):
     # would be psum-summed tensor_axis_size times.
     attn_bias: bool = False
     # Paged KV pool (mode="paged_decode", serve/): per-layer
-    # [num_pages, page_size, Hkv, D] pools in the "pages" collection,
+    # [num_pages, page_size, Hkv*D] pools in the "pages" collection,
     # indexed by a per-slot page table — memory scales with live tokens
     # across the whole engine, not B x max_seq_len. Both must be set to
     # use the paged mode.
@@ -374,11 +374,17 @@ class Attention(nn.Module):
         elif mode == "paged_decode":
             # Continuous-batching serve path (serve/): KV lives in a
             # POOL of fixed-size pages shared by every slot —
-            # [num_pages, page_size, Hkv, D] per layer in the "pages"
-            # collection — and each slot's pages are listed (in sequence
-            # order) by its ``page_table`` row. Pool memory scales with
-            # LIVE tokens across the engine instead of B x max_seq_len,
-            # and a retired slot's pages recycle immediately. The new
+            # [num_pages, page_size, Hkv*D] per layer in the "pages"
+            # collection, head h in lanes [h*D, (h+1)*D) — and each
+            # slot's pages are listed (in sequence order) by its
+            # ``page_table`` row. Folded, because the TPU's default
+            # layout of a 4-D [.., Hkv, D] pool puts num_pages minor-most
+            # and every program then converts the pool to row-major and
+            # back (serve/layout.py; tests/test_serve_layout.py checks
+            # it with the compile-only topology). Pool memory scales
+            # with LIVE tokens across the engine instead of B x
+            # max_seq_len, and a retired slot's pages recycle
+            # immediately. The new
             # token's K/V scatters into (page_table[b, pos//page],
             # pos%page); attention then either gathers the slot's pages
             # into the dense per-slot view and runs the exact
@@ -407,7 +413,8 @@ class Attention(nn.Module):
                 raise ValueError(
                     f"paged decode steps one token at a time, got t={t}"
                 )
-            pool_shape = (self.num_pages, self.page_size, kv_local, head_dim)
+            pool_shape = (self.num_pages, self.page_size, kv_local * head_dim)
+            scale_shape = (self.num_pages, self.page_size, kv_local)
             pool_dtype = jnp.int8 if self.quant_kv_cache else k.dtype
             kp = self.variable(
                 "pages", "key_pages", jnp.zeros, pool_shape, pool_dtype
@@ -417,11 +424,11 @@ class Attention(nn.Module):
             )
             if self.quant_kv_cache:
                 ksp = self.variable(
-                    "pages", "key_scale_pages", jnp.ones, pool_shape[:3],
+                    "pages", "key_scale_pages", jnp.ones, scale_shape,
                     jnp.float32,
                 )
                 vsp = self.variable(
-                    "pages", "value_scale_pages", jnp.ones, pool_shape[:3],
+                    "pages", "value_scale_pages", jnp.ones, scale_shape,
                     jnp.float32,
                 )
             # Scatter the new token's K/V. Inactive slots are parked on
@@ -432,6 +439,10 @@ class Attention(nn.Module):
                 page_table, (decode_pos // self.page_size)[:, None], axis=1
             )[:, 0]
             slot_off = decode_pos % self.page_size
+
+            def fold(x):  # the new token's [B, 1, Hkv, D] -> [B, Hkv*D]
+                return x[:, 0].reshape(b, kv_local * head_dim)
+
             if self.paged_attention_impl not in ("gather", "kernel"):
                 raise ValueError(
                     "paged_attention_impl must be 'gather' or 'kernel', "
@@ -450,8 +461,8 @@ class Attention(nn.Module):
 
                 kq, ks = quantize_kv(k)
                 vq, vs = quantize_kv(v)
-                kp.value = kp.value.at[slot_page, slot_off].set(kq[:, 0])
-                vp.value = vp.value.at[slot_page, slot_off].set(vq[:, 0])
+                kp.value = kp.value.at[slot_page, slot_off].set(fold(kq))
+                vp.value = vp.value.at[slot_page, slot_off].set(fold(vq))
                 ksp.value = ksp.value.at[slot_page, slot_off].set(ks[:, 0])
                 vsp.value = vsp.value.at[slot_page, slot_off].set(vs[:, 0])
                 if use_kernel:
@@ -474,8 +485,8 @@ class Attention(nn.Module):
                     paged_decode_attention,
                 )
 
-                kp.value = kp.value.at[slot_page, slot_off].set(k[:, 0])
-                vp.value = vp.value.at[slot_page, slot_off].set(v[:, 0])
+                kp.value = kp.value.at[slot_page, slot_off].set(fold(k))
+                vp.value = vp.value.at[slot_page, slot_off].set(fold(v))
                 if use_kernel:
                     paged_out = paged_attention(
                         q, kp.value, vp.value, page_table, decode_pos,

@@ -1,8 +1,9 @@
 """Paged-attention decode as a Pallas TPU kernel — serve's HBM-bound path.
 
 The serving engine (``serve/``) keeps every slot's KV in a shared pool of
-fixed-size pages (``[num_pages, page_size, Hkv, D]`` per layer) indexed
-by a per-slot page table. The reference decode path
+fixed-size pages (``[num_pages, page_size, Hkv*D]`` per layer, heads
+folded into the lane dimension: head ``h`` owns lanes ``[h*D, (h+1)*D)``)
+indexed by a per-slot page table. The reference decode path
 (``parallel/ring_attention.py::paged_decode_attention``) gathers each
 slot's pages into the dense ``[B, P*page_size, Hkv, D]`` view and runs
 the standard einsum — correct (and bitwise-parity-testable against the
@@ -17,11 +18,14 @@ This kernel reads **only live pages**, straight out of the pool:
   (``PrefetchScalarGridSpec``), so each grid step's BlockSpec index_map
   picks its page from ``page_table[slot, i]`` — data-dependent DMA, no
   gather, no dense intermediate.
-- One block is a whole page, all KV heads: ``(1, page_size, Hkv, D)``.
-  Mosaic requires a block's last two dims to be (8, 128)-divisible or
-  the array's own, which a one-head ``(1, page_size, 1, D)`` block of
-  this pool layout is not; the page-wide block moves the same bytes in
-  one DMA and the kernel loops the heads.
+- One block is a whole page, all KV heads: ``(1, page_size, Hkv*D)``,
+  one unpadded DMA; the kernel loops the heads over lane slices.
+  Folded, because the TPU's default layout of a 4-D ``[.., Hkv, D]``
+  pool puts ``num_pages`` minor-most, and this kernel and every scatter
+  then had the whole pool converted to row-major and back, two
+  pool-sized copies a pool a program (``serve/layout.py``;
+  ``tests/test_serve_layout.py`` checks it with the compile-only
+  topology).
 - Dead iterations (``i >= ceil((pos+1)/page_size)``) CLAMP their
   index_map to the slot's last live page. Pallas skips the re-fetch when
   a block index repeats, so capacity-sized grids cost live-sized HBM
@@ -44,8 +48,9 @@ Three variants share this one entry point:
   clamped index_map, replacing ``paged_decode_attention_quant``'s
   four-pool gather.
 - tensor-parallel: under ``shard_map`` the pools arrive sliced over KV
-  heads and ``q`` over query heads; the blocks derive from the LOCAL
-  shapes, so the kernel partitions over the head axis with no changes.
+  heads (a contiguous lane range of the folded last dimension) and
+  ``q`` over query heads; the blocks derive from the LOCAL shapes, so
+  the kernel partitions over the head axis with no changes.
 
 Online softmax reassociates the reduction, so kernel-vs-reference parity
 is tolerance-level (tests/test_paged_attention.py), not bitwise — the
@@ -103,10 +108,11 @@ def _decode_kernel(
 
     @pl.when(i < live)
     def _update():
-        for h in range(k_ref.shape[2]):  # static: the page's KV heads
+        d = q_ref.shape[-1]
+        for h in range(q_ref.shape[1]):  # static: the page's KV heads
             q = q_ref[0, h]  # [group, D]
-            k = k_ref[0, :, h, :]  # [page_size, D]
-            v = v_ref[0, :, h, :]
+            k = k_ref[0, :, h * d:(h + 1) * d]  # [page_size, D]
+            v = v_ref[0, :, h * d:(h + 1) * d]
             if quant:
                 # Per-row dequant ahead of the dots: the [page_size, 1]
                 # scale column broadcasts along lanes, where scaling the
@@ -155,11 +161,13 @@ def paged_attention(
     """One decode step of ``q`` [B, 1, Hq, D] against paged KV pools,
     reading only each slot's live pages (module docstring).
 
-    ``key_pages``/``value_pages`` are ``[num_pages, page_size, Hkv, D]``
-    pools, ``page_table`` ``[B, P]`` page indices in sequence order, and
-    ``pos`` ``[B]`` the slots' current depths — the exact signature of
-    ``paged_decode_attention`` (+ scale pools for the int8 variant,
-    matching ``paged_decode_attention_quant``). ``Hq`` may be a multiple
+    ``key_pages``/``value_pages`` are ``[num_pages, page_size, Hkv*D]``
+    pools (``D`` is ``q``'s; the int8 variant's scale pools stay
+    ``[num_pages, page_size, Hkv]``), ``page_table`` ``[B, P]`` page
+    indices in sequence order, and ``pos`` ``[B]`` the slots' current
+    depths — the exact signature of ``paged_decode_attention`` (+ scale
+    pools for the int8 variant, matching
+    ``paged_decode_attention_quant``). ``Hq`` may be a multiple
     of ``Hkv`` (GQA). ``pages_per_slot`` statically narrows the page
     table and grid to the first N pages — the capacity stays a runtime
     fact for the engine's fixed-shape step (live length enters via the
@@ -169,7 +177,13 @@ def paged_attention(
     b, t, hq, d = q.shape
     if t != 1:
         raise ValueError(f"paged decode steps one token at a time, got t={t}")
-    num_pages, page_size, hkv, _ = key_pages.shape
+    if key_pages.ndim != 3 or key_pages.shape[-1] % d:
+        raise ValueError(
+            f"pools are [num_pages, page_size, Hkv*D] with D={d}, "
+            f"got {key_pages.shape}"
+        )
+    _, page_size, folded = key_pages.shape
+    hkv = folded // d
     if hq % hkv:
         raise ValueError(f"query heads {hq} not a multiple of kv heads {hkv}")
     quant = key_scale_pages is not None
@@ -198,18 +212,15 @@ def paged_attention(
         # live-sized bytes (and never the trash page past block 0).
         return table[bi, jnp.minimum(i, lens[bi] // page_size)]
 
-    def kv_map(bi, i, lens, table):
-        return live_page(bi, i, lens, table), 0, 0, 0
-
-    def scale_map(bi, i, lens, table):
+    def page_map(bi, i, lens, table):
         return live_page(bi, i, lens, table), 0, 0
 
     q_spec = pl.BlockSpec((1, hkv, group, d), q_map)
-    kv_spec = pl.BlockSpec((1, page_size, hkv, d), kv_map)
+    kv_spec = pl.BlockSpec((1, page_size, folded), page_map)
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qg, key_pages, value_pages]
     if quant:
-        sc_spec = pl.BlockSpec((1, page_size, hkv), scale_map)
+        sc_spec = pl.BlockSpec((1, page_size, hkv), page_map)
         in_specs += [sc_spec, sc_spec]
         operands += [key_scale_pages, value_scale_pages]
     out_dtype = q.dtype if quant else value_pages.dtype

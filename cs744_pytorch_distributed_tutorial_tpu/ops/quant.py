@@ -308,10 +308,11 @@ def paged_decode_attention_quant(
     pos: jax.Array,
 ) -> jax.Array:
     """``decode_attention_quant`` against paged int8 pools (``serve/``):
-    ``key_pages``/``value_pages`` are ``[num_pages, page_size, Hkv, D]``
-    int8 pools with per-row scale pools ``[num_pages, page_size, Hkv]``;
-    ``page_table`` ``[B, P]`` and per-slot depths ``pos`` ``[B]`` as in
-    the float variant. Gather first, then the exact int8 decode path —
+    ``key_pages``/``value_pages`` are ``[num_pages, page_size, Hkv*D]``
+    int8 pools (heads folded, as the float pools) with per-row scale
+    pools ``[num_pages, page_size, Hkv]``; ``page_table`` ``[B, P]`` and
+    per-slot depths ``pos`` ``[B]`` as in the float variant. Gather
+    first, unfold the slot's view, then the exact int8 decode path —
     parity with the dense int8 cache is structural.
 
     Reference implementation: the four-pool gather reads capacity-many
@@ -320,12 +321,13 @@ def paged_decode_attention_quant(
     ``key/value_scale_pages`` passed — reading only live pages."""
     from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
         gather_pages,
+        unfold_heads,
     )
 
     return decode_attention_quant(
         q,
-        gather_pages(key_pages, page_table),
-        gather_pages(value_pages, page_table),
+        unfold_heads(gather_pages(key_pages, page_table), q.shape[-1]),
+        unfold_heads(gather_pages(value_pages, page_table), q.shape[-1]),
         gather_pages(key_scale_pages, page_table),
         gather_pages(value_scale_pages, page_table),
         pos,

@@ -148,6 +148,12 @@ def gather_pages(pages: jax.Array, page_table: jax.Array) -> jax.Array:
     return g.reshape(b, p * pages.shape[1], *pages.shape[2:])
 
 
+def unfold_heads(rows: jax.Array, head_dim: int) -> jax.Array:
+    """A gathered ``[B, T, Hkv*D]`` view of folded pools as the dense
+    cache's ``[B, T, Hkv, D]``."""
+    return rows.reshape(*rows.shape[:2], -1, head_dim)
+
+
 def paged_decode_attention(
     q: jax.Array,
     key_pages: jax.Array,
@@ -157,12 +163,14 @@ def paged_decode_attention(
 ) -> jax.Array:
     """``decode_attention`` against a paged KV pool (``serve/``).
 
-    ``key_pages``/``value_pages`` are ``[num_pages, page_size, Hkv, D]``
-    pools shared by every slot; ``page_table`` ``[B, P]`` lists each
-    slot's pages in sequence order and ``pos`` ``[B]`` the slots'
-    current depths. The gather produces the dense per-slot view and the
-    masking/softmax/PV path is literally ``decode_attention`` — paged
-    parity is structural, not approximate.
+    ``key_pages``/``value_pages`` are ``[num_pages, page_size, Hkv*D]``
+    pools shared by every slot (heads folded into the last dimension,
+    ``D`` is ``q``'s; ``ops/paged_attention.py`` says why);
+    ``page_table`` ``[B, P]`` lists each slot's pages in sequence order
+    and ``pos`` ``[B]`` the slots' current depths. The gather produces
+    the dense per-slot view, unfolded to ``[B, P*page_size, Hkv, D]``
+    after the gather, and the masking/softmax/PV path is literally
+    ``decode_attention`` — paged parity is structural, not approximate.
 
     This is the REFERENCE implementation: its HBM traffic scales with
     page capacity ``P``, not live length. The serving hot path is
@@ -170,8 +178,8 @@ def paged_decode_attention(
     the same signature that reads only live pages straight from the
     pool (no gather, no dense intermediate) and is tolerance-tested
     against this function."""
-    gk = gather_pages(key_pages, page_table)
-    gv = gather_pages(value_pages, page_table)
+    gk = unfold_heads(gather_pages(key_pages, page_table), q.shape[-1])
+    gv = unfold_heads(gather_pages(value_pages, page_table), q.shape[-1])
     return decode_attention(q, gk, gv, pos)
 
 

@@ -10,11 +10,12 @@ padding. This engine serves at REQUEST granularity instead:
   key) -> (pages, next_tokens[B])`` — so batch membership changes
   (retire, refill, preempt) without retracing (graftlint GL002; the
   0-retrace contract is pinned by tests/test_serve.py);
-- KV lives in per-layer page POOLS (``[num_pages, page_size, Hkv, D]``,
-  the "pages" variable collection of ``mode="paged_decode"``), indexed
-  by each slot's row of the page table. Pool memory scales with LIVE
-  tokens across the engine, not B x max_seq_len, and a retired slot's
-  pages recycle immediately (``pool.PagePool``);
+- KV lives in per-layer page POOLS (``[num_pages, page_size, Hkv*D]``,
+  the "pages" variable collection of ``mode="paged_decode"``; heads
+  folded so that no program copies a pool, ``serve/layout.py``),
+  indexed by each slot's row of the page table. Pool memory scales
+  with LIVE tokens across the engine, not B x max_seq_len, and a
+  retired slot's pages recycle immediately (``pool.PagePool``);
 - prefill is its own jitted program per prompt-length bucket: a dense
   causal pass over the padded prompt, the first sampled token, and a
   scatter of the prompt's KV rows into the slot's pages — all one
@@ -343,30 +344,15 @@ class ServingEngine:
         come from the model, zero params are ever materialized. Scale
         pools init to ones (matching the in-model variable init); data
         pools to zeros."""
-        cfg = self.cfg
-        b, p = cfg.num_slots, cfg.max_pages_per_slot
-        # A mesh-free clone yields GLOBAL kv-head shapes; the TP path
-        # then shards the pools over the tensor axis below.
-        shape_model = self.model.clone(tensor_axis=None, tensor_axis_size=1)
-
-        def init_fn():
-            return shape_model.init(
-                jax.random.key(0),
-                jnp.zeros((b, 1), jnp.int32),
-                mode="paged_decode",
-                decode_pos=jnp.zeros((b,), jnp.int32),
-                page_table=jnp.zeros((b, p), jnp.int32),
-            )["pages"]
-
-        shapes = jax.eval_shape(init_fn)
 
         def materialize(path, s):
-            name = path[-1].key
-            if "scale" in name:
+            if "scale" in path[-1].key:
                 return jnp.ones(s.shape, s.dtype)
             return jnp.zeros(s.shape, s.dtype)
 
-        pages = jax.tree_util.tree_map_with_path(materialize, shapes)
+        pages = jax.tree_util.tree_map_with_path(
+            materialize, self._pages_shape_tree()
+        )
         if self.mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -380,28 +366,30 @@ class ServingEngine:
 
     def _page_specs(self):
         """PartitionSpecs for the pools: KV heads shard over the tensor
-        axis (dim 2 of ``[num_pages, page_size, Hkv, D]`` pools and of
-        the ``[num_pages, page_size, Hkv]`` scale pools), everything
-        else replicated — the paged mirror of the tensor-sharded dense
-        cache in ``tp_decode_model``."""
+        axis, everything else replicated — the paged mirror of the
+        tensor-sharded dense cache in ``tp_decode_model``. Heads are the
+        last dimension of the data pools (``[num_pages, page_size,
+        Hkv*D]``, head ``h`` in lanes ``[h*D, (h+1)*D)``, so a shard is
+        a contiguous slice of whole heads) and of the scale pools
+        (``[num_pages, page_size, Hkv]``) alike: one spec serves both.
+        Folded, because the TPU's default layout of a 4-D ``[.., Hkv,
+        D]`` pool puts ``num_pages`` minor-most and every program then
+        converts the pool to row-major and back (``serve/layout.py``;
+        ``tests/test_serve_layout.py`` checks it with the compile-only
+        topology)."""
         from jax.sharding import PartitionSpec as P
 
-        axis = self.model.tensor_axis
         # scan_layers stacks every pool with a leading [num_layers] axis
-        # (replicated), shifting the kv-head dim right by one.
+        # (replicated), shifting the head dim right by one.
         lead = (None,) if self._scanned else ()
-        data_ndim = 5 if self._scanned else 4
-
-        def spec(leaf):
-            if leaf.ndim == data_ndim:
-                return P(*lead, None, None, axis, None)
-            return P(*lead, None, None, axis)
-
-        return jax.tree.map(spec, self._pages_shape_tree())
+        spec = P(*lead, None, None, self.model.tensor_axis)
+        return jax.tree.map(lambda _: spec, self._pages_shape_tree())
 
     def _pages_shape_tree(self):
         cfg = self.cfg
         b, p = cfg.num_slots, cfg.max_pages_per_slot
+        # A mesh-free clone yields GLOBAL kv-head shapes; the TP path
+        # shards the pools over the tensor axis (_page_specs).
         shape_model = self.model.clone(tensor_axis=None, tensor_axis_size=1)
 
         def init_fn():
@@ -496,13 +484,27 @@ class ServingEngine:
             off = idx % page_size
 
             def put(p, c):
+                # The cache's rows are [bucket, Hkv, D] (scales
+                # [bucket, Hkv]); the pools fold the heads into the last
+                # dimension, so each row reshapes to the pool's own.
                 if scanned:
                     # scan_layers stacks both collections with a leading
-                    # [num_layers] axis (one "blocks" subtree); the
-                    # scatter indices are layer-independent, so one
-                    # batched update commits every layer — no unrolling.
-                    return p.at[:, pidx, off].set(c[:, 0, :bucket])
-                return p.at[pidx, off].set(c[0, :bucket])
+                    # [num_layers] axis (one "blocks" subtree). Layers
+                    # and pages merge into one axis of rows (free: the
+                    # tiled dimensions are the last two), so one scatter
+                    # commits every layer at layer * num_pages + page;
+                    # a batched ``p.at[:, pidx, off]`` makes the TPU
+                    # compiler re-lay the whole stack out for it.
+                    layers, num_pages = p.shape[:2]
+                    flat = p.reshape(layers * num_pages, *p.shape[2:])
+                    lidx = jnp.arange(layers)[:, None] * num_pages + pidx
+                    rows = c[:, 0, :bucket].reshape(
+                        layers, bucket, p.shape[-1]
+                    )
+                    return flat.at[lidx, off].set(rows).reshape(p.shape)
+                return p.at[pidx, off].set(
+                    c[0, :bucket].reshape(bucket, p.shape[-1])
+                )
 
             def walk(p, c):
                 if any(k in p for k in _CACHE_TO_PAGES.values()):

@@ -1,7 +1,7 @@
 """Host-side page accounting for the paged KV pool.
 
 The device side is dumb on purpose: per-layer pools of
-``[num_pages, page_size, Hkv, D]`` plus a ``[B, P]`` page table, all
+``[num_pages, page_size, Hkv*D]`` plus a ``[B, P]`` page table, all
 fixed-shape so the decode step never retraces. Everything that *varies*
 — which pages belong to which request, what is free — lives here as
 plain Python, mutated between steps.

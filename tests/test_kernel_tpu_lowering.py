@@ -72,8 +72,8 @@ def _sgd(p, m, g):
 def _paged_args(hq, hkv, d, pool_dtype, q_dtype):
     args = [
         _s((8, 1, hq, d), q_dtype),
-        _s((64, 16, hkv, d), pool_dtype),
-        _s((64, 16, hkv, d), pool_dtype),
+        _s((64, 16, hkv * d), pool_dtype),  # pools fold the heads
+        _s((64, 16, hkv * d), pool_dtype),
         _s((8, 8), i32),
         _s((8,), i32),
     ]
@@ -105,6 +105,8 @@ CASES = {
     "paged_bf16_12x64": (_paged, _paged_args(12, 12, 64, bf16, bf16)),
     "paged_int8_12x64": (_paged, _paged_args(12, 12, 64, i8, bf16)),
     "paged_f32_gqa_4x128": (_paged, _paged_args(32, 4, 128, f32, f32)),
+    # Hkv*D = 192: not a lane multiple, the block's last dim is the array's
+    "paged_bf16_3x64": (_paged, _paged_args(3, 3, 64, bf16, bf16)),
     "paged_int8_gqa_4x128": (_paged, _paged_args(32, 4, 128, i8, f32)),
     "int8_matmul_lm_head": (
         lambda x, q, s: int8_matmul(x, q, s, interpret=False),

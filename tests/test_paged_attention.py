@@ -49,13 +49,57 @@ from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
 B, HQ, HKV, D = 3, 4, 2, 16
 
 
-def _pools(key, num_pages, page_size, dtype=jnp.float32):
+def _pools(key, num_pages, page_size, dtype=jnp.float32, hkv=HKV, d=D):
+    """Pools as the engine stores them: ``[num_pages, page_size,
+    Hkv*D]``, head ``h`` in lanes ``[h*D, (h+1)*D)``."""
     kk, kv = jax.random.split(key)
-    shape = (num_pages, page_size, HKV, D)
+    shape = (num_pages, page_size, hkv * d)
     return (
         jax.random.normal(kk, shape, jnp.float32).astype(dtype),
         jax.random.normal(kv, shape, jnp.float32).astype(dtype),
     )
+
+
+def _int8_pools(key, num_pages, page_size, hkv=HKV, d=D):
+    """int8 data pools (folded) and their ``[num_pages, page_size,
+    Hkv]`` scale pools."""
+    ks = jax.random.split(key, 4)
+    shape = (num_pages, page_size, hkv * d)
+    kp, vp = (
+        jax.random.randint(k, shape, -127, 128, jnp.int32).astype(jnp.int8)
+        for k in ks[:2]
+    )
+    ksc, vsc = (
+        jax.random.uniform(
+            k, (num_pages, page_size, hkv), jnp.float32, 0.5 / 127, 1.5 / 127
+        )
+        for k in ks[2:]
+    )
+    return kp, vp, ksc, vsc
+
+
+def _dense_reference(q, kp, vp, table, pos, ksc=None, vsc=None):
+    """The slot's view gathered page by page on the host and attended
+    with plain numpy: independent of ``gather_pages`` and of the fold's
+    reshape, so a head landing in the wrong lanes shows."""
+    q, kp, vp = (np.asarray(x, np.float32) for x in (q, kp, vp))
+    table, pos = np.asarray(table), np.asarray(pos)
+    b, _, hq, d = q.shape
+    hkv = kp.shape[-1] // d
+    out = np.zeros((b, 1, hq, d), np.float32)
+    for bi in range(b):
+        n = int(pos[bi]) + 1
+        for h in range(hq):
+            g = h // (hq // hkv)
+            k = kp[table[bi]].reshape(-1, hkv * d)[:n, g * d:(g + 1) * d]
+            v = vp[table[bi]].reshape(-1, hkv * d)[:n, g * d:(g + 1) * d]
+            if ksc is not None:
+                k = k * np.asarray(ksc)[table[bi]].reshape(-1, hkv)[:n, g, None]
+                v = v * np.asarray(vsc)[table[bi]].reshape(-1, hkv)[:n, g, None]
+            s = k @ q[bi, 0, h] * d**-0.5
+            w = np.exp(s - s.max())
+            out[bi, 0, h] = (w / w.sum()) @ v
+    return out
 
 
 def _layout(num_pages, page_size, ppr, seed=0):
@@ -94,20 +138,7 @@ def test_kernel_int8_matches_quant_reference():
     same algebra as decode_attention_quant (k_scale on scores, v_scale
     folded into probs)."""
     page_size, ppr, num_pages = 4, 4, 17
-    ks = jax.random.split(jax.random.key(2), 4)
-    shape = (num_pages, page_size, HKV, D)
-    kp = jax.random.randint(ks[0], shape, -127, 128, jnp.int32).astype(
-        jnp.int8
-    )
-    vp = jax.random.randint(ks[1], shape, -127, 128, jnp.int32).astype(
-        jnp.int8
-    )
-    ksc = jax.random.uniform(
-        ks[2], shape[:3], jnp.float32, 0.5 / 127, 1.5 / 127
-    )
-    vsc = jax.random.uniform(
-        ks[3], shape[:3], jnp.float32, 0.5 / 127, 1.5 / 127
-    )
+    kp, vp, ksc, vsc = _int8_pools(jax.random.key(2), num_pages, page_size)
     table, pos = _layout(num_pages, page_size, ppr, seed=1)
     q = jax.random.normal(jax.random.key(3), (B, 1, HQ, D), jnp.float32)
     expected = np.asarray(
@@ -120,6 +151,85 @@ def test_kernel_int8_matches_quant_reference():
         )
     )
     np.testing.assert_allclose(got, expected, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "hq,hkv,d,quant",
+    [
+        (4, 2, 16, False),  # GQA, Hkv*D = 32
+        (4, 2, 16, True),
+        (3, 3, 24, False),  # Hkv*D = 72: not a multiple of 128, nor of 8
+        (6, 3, 24, True),
+        (2, 2, 64, False),  # Hkv*D = 128: one full lane tile
+        (12, 12, 64, False),  # the serve cell's heads: 768 lanes
+    ],
+    ids=["gqa", "gqa-int8", "odd72", "odd72-gqa-int8", "lanes128", "gpt2s"],
+)
+def test_folded_pools_match_host_reference(hq, hkv, d, quant):
+    """Kernel AND gather reference against a numpy walk of the page
+    table, over head geometries whose folded width is and is not a lane
+    multiple. The gather reference must also be bitwise what
+    ``decode_attention`` gives on the unfolded dense view."""
+    from cs744_pytorch_distributed_tutorial_tpu.ops.quant import (
+        decode_attention_quant,
+    )
+    from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
+        decode_attention,
+    )
+
+    page_size, ppr, num_pages = 4, 4, 17
+    table, pos = _layout(num_pages, page_size, ppr, seed=5)
+    q = jax.random.normal(jax.random.key(20), (B, 1, hq, d), jnp.float32)
+    if quant:
+        kp, vp, ksc, vsc = _int8_pools(
+            jax.random.key(21), num_pages, page_size, hkv, d
+        )
+        sc = dict(key_scale_pages=ksc, value_scale_pages=vsc)
+        gathered = paged_decode_attention_quant(q, kp, vp, ksc, vsc, table, pos)
+    else:
+        kp, vp = _pools(jax.random.key(21), num_pages, page_size, hkv=hkv, d=d)
+        ksc = vsc = None
+        sc = {}
+        gathered = paged_decode_attention(q, kp, vp, table, pos)
+    want = _dense_reference(q, kp, vp, table, pos, ksc, vsc)
+    np.testing.assert_allclose(np.asarray(gathered), want, rtol=2e-5, atol=2e-5)
+    got = paged_attention(q, kp, vp, table, pos, interpret=True, **sc)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+    # bitwise: gather-then-unfold IS the dense cache's layout
+    def dense(pool, width):
+        view = np.asarray(pool)[np.asarray(table)]  # [B, P, page, width*]
+        return jnp.asarray(view.reshape(B, ppr * page_size, hkv, *width))
+
+    if quant:
+        exact = decode_attention_quant(
+            q, dense(kp, (d,)), dense(vp, (d,)), dense(ksc, ()),
+            dense(vsc, ()), pos,
+        )
+    else:
+        exact = decode_attention(q, dense(kp, (d,)), dense(vp, (d,)), pos)
+    np.testing.assert_array_equal(np.asarray(gathered), np.asarray(exact))
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+def test_pages_per_slot_prunes_without_changing_live_slots(quant):
+    """``pages_per_slot`` narrows table and grid; slots whose live pages
+    all lie inside the pruned width read the same answer."""
+    page_size, ppr, num_pages = 4, 4, 17
+    table, _ = _layout(num_pages, page_size, ppr, seed=6)
+    pos = jnp.asarray([0, 5, 2 * page_size - 1], jnp.int32)  # <= 2 pages
+    q = jax.random.normal(jax.random.key(22), (B, 1, HQ, D), jnp.float32)
+    if quant:
+        kp, vp, ksc, vsc = _int8_pools(jax.random.key(23), num_pages, page_size)
+        sc = dict(key_scale_pages=ksc, value_scale_pages=vsc)
+    else:
+        kp, vp = _pools(jax.random.key(23), num_pages, page_size)
+        sc = {}
+    full = paged_attention(q, kp, vp, table, pos, interpret=True, **sc)
+    pruned = paged_attention(
+        q, kp, vp, table, pos, interpret=True, pages_per_slot=2, **sc
+    )
+    np.testing.assert_array_equal(np.asarray(pruned), np.asarray(full))
 
 
 def test_kernel_never_reads_dead_pages():
@@ -155,30 +265,17 @@ def test_kernel_never_reads_dead_pages():
 
 @pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
 def test_kernel_tensor_parallel_matches_unsharded(quant):
-    """Pools sharded over KV heads, q over query heads (the serving TP
-    layout): per-shard grids over the LOCAL Hkv reproduce the unsharded
-    kernel — no head-index plumbing needed."""
+    """Pools sharded over KV heads (a contiguous lane range of the
+    folded last dimension), q over query heads (the serving TP layout):
+    per-shard grids over the LOCAL Hkv reproduce the unsharded kernel —
+    no head-index plumbing needed."""
     from cs744_pytorch_distributed_tutorial_tpu.parallel import make_mesh
 
     page_size, ppr, num_pages = 4, 4, 17
     table, pos = _layout(num_pages, page_size, ppr, seed=3)
     q = jax.random.normal(jax.random.key(6), (B, 1, HQ, D), jnp.float32)
-    shape = (num_pages, page_size, HKV, D)
     if quant:
-        ks = jax.random.split(jax.random.key(7), 4)
-        kp = jax.random.randint(ks[0], shape, -127, 128, jnp.int32).astype(
-            jnp.int8
-        )
-        vp = jax.random.randint(ks[1], shape, -127, 128, jnp.int32).astype(
-            jnp.int8
-        )
-        ksc = jax.random.uniform(
-            ks[2], shape[:3], jnp.float32, 0.5 / 127, 1.5 / 127
-        )
-        vsc = jax.random.uniform(
-            ks[3], shape[:3], jnp.float32, 0.5 / 127, 1.5 / 127
-        )
-        scales = (ksc, vsc)
+        kp, vp, *scales = _int8_pools(jax.random.key(7), num_pages, page_size)
     else:
         kp, vp = _pools(jax.random.key(7), num_pages, page_size)
         scales = ()
@@ -194,7 +291,9 @@ def test_kernel_tensor_parallel_matches_unsharded(quant):
     expected = np.asarray(call(q, kp, vp, *scales))
     mesh = make_mesh({"tensor": 2}, devices=jax.devices()[:2])
     head = P(None, None, "tensor", None)
-    in_specs = (head, head, head) + (P(None, None, "tensor"),) * len(scales)
+    # folded pools and scale pools alike carry the heads last
+    pool = P(None, None, "tensor")
+    in_specs = (head, pool, pool) + (pool,) * len(scales)
     mapped = jax.shard_map(
         call, mesh=mesh, in_specs=in_specs, out_specs=head, check_vma=False
     )
@@ -288,5 +387,9 @@ def test_validation():
     with pytest.raises(ValueError, match="both scale pools"):
         paged_attention(
             q, kp, vp, table, pos,
-            key_scale_pages=jnp.ones(kp.shape[:3]), interpret=True,
+            key_scale_pages=jnp.ones((*kp.shape[:2], HKV)), interpret=True,
         )
+    # the old 4-D pools, and rows that are not whole heads, are refused
+    for bad in (kp.reshape(*kp.shape[:2], HKV, D), kp[..., :-1]):
+        with pytest.raises(ValueError, match=r"Hkv\*D"):
+            paged_attention(q, bad, bad, table, pos, interpret=True)

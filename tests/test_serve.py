@@ -147,8 +147,9 @@ def _commit_cache_to_pages(pages, cache, page_tables, true_len):
                 page_size = pool.shape[1]
                 for b in range(rows.shape[0]):
                     for i in range(true_len):
+                        # pools fold the heads: [Hkv, D] -> [Hkv*D]
                         pool[page_tables[b, i // page_size], i % page_size] = (
-                            rows[b, i]
+                            rows[b, i].reshape(pool.shape[2:])
                         )
                 out[pname] = jnp.asarray(pool)
             return out
@@ -209,6 +210,73 @@ def test_paged_decode_logits_bitwise_match_dense(tiny_lm, quant_kv):
         np.testing.assert_array_equal(
             np.asarray(paged_logits), np.asarray(dense_logits)
         )
+
+
+@pytest.mark.parametrize("scan", [False, True], ids=["unrolled", "scan"])
+@pytest.mark.parametrize("quant_kv", [False, True], ids=["float", "int8"])
+def test_engine_commit_then_decode_bitwise_matches_dense(
+    tiny_lm, quant_kv, scan
+):
+    """The engine's OWN device-side commit (its prefill program's
+    scatter of the dense cache rows into folded pools, one flattened
+    scatter for every layer under ``scan_layers``) and then a decode
+    step over those pools equal the dense cache path to the bit, on the
+    gather path: first token, committed rows, next-step logits."""
+    from cs744_pytorch_distributed_tutorial_tpu.models import (
+        stack_block_params,
+    )
+
+    model, params = tiny_lm
+    dense = model.clone(quant_kv_cache=quant_kv, scan_layers=scan)
+    if scan:
+        params = stack_block_params(params)
+    eng = ServingEngine(
+        dense, params,
+        ServeConfig(num_slots=2, page_size=4, num_pages=17,
+                    max_pages_per_slot=8, paged_attention_impl="gather"),
+    )
+    plen, bucket = 6, 8
+    prompt = np.zeros((1, bucket), np.int32)
+    prompt[0, :plen] = np.random.default_rng(5).integers(1, VOCAB, plen)
+    row = np.zeros((8,), np.int32)
+    row[:2] = [9, 3]  # the slot's pages, out of pool order
+    empty = jax.tree.map(np.asarray, eng._pages)
+    pages, tok = eng._prefill_fn(bucket)(
+        params, eng._pages, jnp.asarray(prompt), jnp.int32(plen),
+        jnp.asarray(row), eng._sample_root,
+    )
+
+    # jitted like the engine's program: op-by-op execution rounds the
+    # int8 scales differently in the last bit
+    logits, variables = jax.jit(
+        lambda p, x: dense.apply(
+            {"params": p}, x, mode="prefill", mutable=["cache"]
+        )
+    )(params, jnp.asarray(prompt))
+    cache = variables["cache"]
+    assert int(tok) == int(jnp.argmax(logits[0, plen - 1]))
+    if not scan:  # the host-side walk knows the unrolled tree only
+        want = _commit_cache_to_pages(empty, cache, row[None], plen)
+        for got_leaf, want_leaf in zip(
+            jax.tree.leaves(pages), jax.tree.leaves(want)
+        ):
+            np.testing.assert_array_equal(
+                np.asarray(got_leaf)[row[:2]], np.asarray(want_leaf)[row[:2]]
+            )
+
+    step = jnp.asarray([[int(tok)]], jnp.int32)
+    dense_logits, _ = dense.apply(
+        {"params": params, "cache": cache}, step, mode="decode",
+        decode_pos=jnp.asarray(plen, jnp.int32), mutable=["cache"],
+    )
+    paged_logits, _ = eng.model.apply(
+        {"params": params, "pages": pages}, step, mode="paged_decode",
+        decode_pos=jnp.asarray([plen], jnp.int32),
+        page_table=jnp.asarray(row[None]), mutable=["pages"],
+    )
+    np.testing.assert_array_equal(
+        np.asarray(paged_logits), np.asarray(dense_logits)
+    )
 
 
 # --------------------------------------------------- engine lifecycle

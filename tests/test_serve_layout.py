@@ -1,0 +1,124 @@
+"""The paged KV pools' device layout, held without a chip.
+
+The engine's decode step and one prefill bucket are compiled for the v5e
+through libtpu's compile-only topology (``serve/layout.py`` has the
+recipe and the reason): a lane-realistic geometry (12 heads x 64, pages
+of 16, bf16) on a small pool. Per program, the compiled module must show
+
+(a) no ``copy`` whose result has a data pool's element count: with the
+    4-D ``[num_pages, page_size, Hkv, D]`` pools every program converted
+    each pool to row-major and back, two such copies a pool (this test
+    at the parent of PR 26: 8 in each program, 2 layers x K and V x 2);
+(b) temporaries under one pool's bytes;
+(c) the pools entering row-major.
+
+One exception, counted and named: under ``scan_layers`` the decode step
+keeps ONE same-layout copy of each stacked pool, ``lax.scan`` reading
+the stack as ``xs`` and writing it as ``ys`` (the buffers cannot alias);
+that is the scan's, not the layout's, and stays an open question in
+``PERF.md``. The int8 variant's small scale pools
+(``[num_pages, page_size, Hkv]`` float32) still convert, an open
+question there too: (a) and (c) hold its data pools, (b) is the float
+pools' alone.
+
+The topology is described inside a fixture and every compile runs in
+the test's own process (one process loads libtpu at a time); where no
+topology can be described the tests skip and say why.
+``tests_chip/test_kernels.py`` makes the same three assertions on the
+device at the serve cell's full geometry.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from cs744_pytorch_distributed_tutorial_tpu.models import TransformerLM
+from cs744_pytorch_distributed_tutorial_tpu.serve import (
+    ServeConfig,
+    ServingEngine,
+)
+from cs744_pytorch_distributed_tutorial_tpu.serve.layout import (
+    audit,
+    compile_programs,
+)
+
+BUCKET = 32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compiled(one_chip):
+    """(quant, scan) -> (engine, its two programs compiled for the
+    v5e), each pair compiled once for its two tests."""
+    cache = {}
+
+    def get(quant, scan):
+        if (quant, scan) not in cache:
+            model = TransformerLM(
+                vocab_size=512, num_layers=2, num_heads=12, d_model=768,
+                d_ff=1024, max_seq_len=256, dtype=jnp.bfloat16,
+                attention_impl="dense", quant_kv_cache=quant,
+                scan_layers=scan,
+                # steer the CPU process onto the chip's path: the Pallas
+                # kernel, Mosaic-compiled
+                flash_interpret=False,
+            )
+            params = jax.eval_shape(
+                lambda: model.init(
+                    jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+                )
+            )["params"]
+            engine = ServingEngine(
+                model, params,
+                ServeConfig(
+                    num_slots=8, page_size=16, num_pages=257,
+                    max_pages_per_slot=16, paged_attention_impl="kernel",
+                ),
+            )
+            cache[quant, scan] = engine, compile_programs(
+                engine, BUCKET, one_chip
+            )
+        return cache[quant, scan]
+
+    return get
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("scan", [False, True], ids=["unrolled", "scan"])
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+def test_no_program_copies_a_pool(compiled, quant, scan, program):
+    engine, programs = compiled(quant, scan)
+    got = audit(programs[program], engine)
+    # (c) row-major as it enters
+    assert got.entry_layouts and got.row_major, got.entry_layouts
+    # (a) no pool-sized copy ...
+    if scan and program == "decode":
+        # ... but the scan's own, one a stacked pool and no layout change
+        assert len(got.pool_copies) <= len(got.entry_layouts), got
+        for shape, minor_to_major in got.pool_copies:
+            assert minor_to_major == tuple(reversed(range(len(shape))))
+    else:
+        assert got.pool_copies == []
+    # (b) nothing pool-sized among the temporaries. Not held for int8:
+    # there they are the scale pools' row-major copies (2.1 MB each at
+    # this size, lanes padded 12 -> 128), which this layout leaves be.
+    if not quant:
+        assert got.temp_bytes < got.pool_bytes, got

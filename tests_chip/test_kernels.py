@@ -153,8 +153,9 @@ SLOTS, PAGE, NUM_PAGES, PAGES_PER_SLOT = 8, 16, 256, 24
 
 def _paged_inputs(hq, hkv, d, dtype):
     q = _normal(0, (SLOTS, 1, hq, d), dtype)
-    k = _normal(1, (NUM_PAGES, PAGE, hkv, d), dtype)
-    v = _normal(2, (NUM_PAGES, PAGE, hkv, d), dtype)
+    # pools as the engine stores them: heads folded into the lanes
+    k = _normal(1, (NUM_PAGES, PAGE, hkv * d), dtype)
+    v = _normal(2, (NUM_PAGES, PAGE, hkv * d), dtype)
     rng = np.random.default_rng(0)
     # distinct live pages per slot; page 0 is the engine's trash page
     table = rng.permutation(np.arange(1, NUM_PAGES))[: SLOTS * PAGES_PER_SLOT]
@@ -186,8 +187,10 @@ def test_paged_attention_float(hq, hkv, d, dtype):
 def test_paged_attention_int8_kv(hq, hkv, d, qdtype):
     q, k, v, table, pos = _paged_inputs(hq, hkv, d, jnp.float32)
     q = q.astype(qdtype)
-    kq, ks = quantize_kv(k)
-    vq, vs = quantize_kv(v)
+    # quantize per (row, head), then fold the int8 rows again
+    kq, ks = quantize_kv(k.reshape(NUM_PAGES, PAGE, hkv, d))
+    vq, vs = quantize_kv(v.reshape(NUM_PAGES, PAGE, hkv, d))
+    kq, vq = kq.reshape(k.shape), vq.reshape(v.shape)
     got = jax.jit(
         lambda q, kq, vq, ks, vs, table, pos: paged_attention(
             q, kq, vq, table, pos, key_scale_pages=ks, value_scale_pages=vs,
@@ -200,6 +203,53 @@ def test_paged_attention_int8_kv(hq, hkv, d, qdtype):
         )
     assert got.dtype == qdtype
     assert_close(got, want, 3e-2)
+
+
+@pytest.fixture(scope="module")
+def serve_cell_programs():
+    from cs744_pytorch_distributed_tutorial_tpu.models import TransformerLM
+    from cs744_pytorch_distributed_tutorial_tpu.serve import (
+        ServeConfig,
+        ServingEngine,
+    )
+    from cs744_pytorch_distributed_tutorial_tpu.serve.layout import (
+        compile_programs,
+    )
+
+    model = TransformerLM(
+        vocab_size=50304, num_layers=12, num_heads=12, d_model=768,
+        d_ff=3072, max_seq_len=1024, dtype=jnp.bfloat16,
+        attention_impl="dense",
+    )
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+    )["params"]
+    engine = ServingEngine(
+        model, params,
+        ServeConfig(
+            num_slots=128, page_size=16, num_pages=8193,
+            max_pages_per_slot=64,
+        ),
+    )
+    assert engine.paged_attention_impl == "kernel"
+    return engine, compile_programs(engine, 512)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_serve_programs_copy_no_pool(program, serve_cell_programs):
+    """The serve cell's own programs, compiled on the device at its full
+    geometry (GPT-2 small, 128 slots, 8193 pages of 16, bucket 512):
+    the pools enter row-major, no program holds a pool-sized ``copy``
+    and the temporaries stay under one pool's bytes. With 4-D pools
+    each program held 48 such copies (two a pool) and 0.58-3.82 GB of
+    temporaries; ``tests/test_serve_layout.py`` is the chipless twin."""
+    from cs744_pytorch_distributed_tutorial_tpu.serve.layout import audit
+
+    engine, programs = serve_cell_programs
+    got = audit(programs[program], engine)
+    assert len(got.entry_layouts) == 24 and got.row_major, got.entry_layouts
+    assert got.pool_copies == []
+    assert got.temp_bytes < got.pool_bytes, (got.temp_bytes, got.pool_bytes)
 
 
 # --------------------------------------------------------------- int8 matmul
