@@ -37,6 +37,7 @@ from cs744_pytorch_distributed_tutorial_tpu.models.vgg import (
 )
 from cs744_pytorch_distributed_tutorial_tpu.models.hf_interop import (
     gpt2_model_config,
+    keye_model_config,
     llama_model_config,
     lm_params_from_hf_gpt2,
     lm_params_from_hf_llama,
@@ -131,6 +132,7 @@ __all__ = [
     "resnet50",
     "tiny_cnn",
     "gpt2_model_config",
+    "keye_model_config",
     "llama_model_config",
     "lm_params_from_hf_gpt2",
     "lm_params_from_hf_llama",

@@ -226,6 +226,61 @@ def llama_model_config(
     )
 
 
+def keye_model_config(hf_config: Mapping[str, Any], max_seq_len: int | None = None) -> dict:
+    """``TransformerLM`` kwargs for the language model of a
+    ``KeyeVL2`` ``config.json`` (Kwai-Keye/Keye-VL-2.0-30B-A3B): RMSNorm,
+    RoPE, GQA with a head width of its own and per-head q/k RMSNorm,
+    gated experts on the dropless path with renormalised top-k weights
+    (``norm_topk_prob``), and the sparse-attention indexer of
+    ``sa_config``. Read from the published keys alone, no tensor: the
+    language model only (no vision tower), text positions (the three
+    position streams of ``mrope_section`` are equal for text, which is
+    plain RoPE). ``max_seq_len`` defaults to
+    ``max_position_embeddings``."""
+    if hf_config.get("mlp_only_layers") or hf_config.get("decoder_sparse_step", 1) != 1:
+        raise ValueError(
+            "dense layers among the routed ones (mlp_only_layers, "
+            "decoder_sparse_step != 1) are not supported: every block "
+            "of TransformerLM is alike"
+        )
+    if not hf_config.get("norm_topk_prob", True):
+        raise ValueError(
+            "norm_topk_prob=false is not supported: MoEFFN renormalises "
+            "the top-k weights"
+        )
+    if hf_config.get("attention_bias") or hf_config.get("use_sliding_window"):
+        raise ValueError("attention_bias / sliding windows are not supported")
+    sa = hf_config.get("sa_config") or {}
+    if sa and sa.get("indexer_num_kv_heads", 1) != 1:
+        raise ValueError("the indexer caches one key head a token")
+    return dict(
+        vocab_size=hf_config["vocab_size"],
+        num_layers=hf_config["num_hidden_layers"],
+        num_heads=hf_config["num_attention_heads"],
+        num_kv_heads=hf_config["num_key_value_heads"],
+        head_dim=hf_config["head_dim"],
+        d_model=hf_config["hidden_size"],
+        d_ff=hf_config["moe_intermediate_size"],
+        max_seq_len=max_seq_len or hf_config["max_position_embeddings"],
+        use_rope=True,
+        rope_base=float(hf_config["rope_theta"]),
+        tie_embeddings=bool(hf_config.get("tie_word_embeddings", False)),
+        norm="rmsnorm",
+        norm_eps=hf_config["rms_norm_eps"],
+        mlp="swiglu",
+        qk_norm=True,
+        num_experts=hf_config["num_experts"],
+        moe_top_k=hf_config["num_experts_per_tok"],
+        moe_dispatch="dropless",
+        moe_bias=False,
+        indexer_heads=sa.get("indexer_num_heads", 0),
+        indexer_head_dim=sa.get("indexer_head_dim", 64),
+        sparse_topk=sa.get("topk", 0),
+        attn_bias=False,
+        attention_impl="dense",
+    )
+
+
 def lm_params_from_hf_llama(state_dict: Mapping[str, Any]) -> dict:
     """Convert a ``LlamaForCausalLM.state_dict()`` into the ``params``
     tree of the matching ``TransformerLM`` (``llama_model_config``).

@@ -112,6 +112,13 @@ class MoEFFN(nn.Module):
     gmm_block_n: int = 512
     # None = interpret Pallas kernels off-TPU (ops/_backend.py).
     gmm_interpret: Any = None
+    # Gated experts: out = w_out(silu(w_gate x) * w_in x), the SwiGLU
+    # form of the llama-family dense MLP, a third [E, D, d_ff] matrix an
+    # expert. On the dropless path only (what serves them: no capacity,
+    # so no token is dropped).
+    gated: bool = False
+    # Expert biases b_in / b_out; False declares neither.
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -146,6 +153,11 @@ class MoEFFN(nn.Module):
                 "defaults (1.25, 1) or use 'scatter'/'einsum' for "
                 "capacity-based routing"
             )
+        if self.gated and not dropless:
+            raise ValueError(
+                "gated experts run on dispatch_impl='dropless' only, got "
+                f"{self.dispatch_impl!r}"
+            )
         if e % (self.expert_axis_size if ep else 1):
             raise ValueError(
                 f"num_experts {e} not divisible by expert axis "
@@ -178,6 +190,9 @@ class MoEFFN(nn.Module):
         # ---- router (float32 end-to-end) --------------------------------
         logits = nn.Dense(
             e, use_bias=False, dtype=jnp.float32, param_dtype=jnp.float32,
+            # the top-k choice is discrete: no bf16 pass over the logits
+            # that make it (a [D, E] matmul; the cost is nothing)
+            precision=lax.Precision.HIGHEST,
             name="router",
         )(tokens.astype(jnp.float32))
         gates = jax.nn.softmax(logits, axis=-1)  # [G, N, E]
@@ -213,11 +228,19 @@ class MoEFFN(nn.Module):
         # ---- expert parameters (shared by every dispatch path) ----------
         init = nn.initializers.lecun_normal()
         w_in = self.param("w_in", init, (e_local, d, self.d_ff))
-        b_in = self.param(
-            "b_in", nn.initializers.zeros_init(), (e_local, self.d_ff)
-        )
         w_out = self.param("w_out", init, (e_local, self.d_ff, d))
-        b_out = self.param("b_out", nn.initializers.zeros_init(), (e_local, d))
+        if self.gated:
+            w_gate = self.param("w_gate", init, (e_local, d, self.d_ff))
+        if self.use_bias:
+            b_in = self.param(
+                "b_in", nn.initializers.zeros_init(), (e_local, self.d_ff)
+            )
+            b_out = self.param(
+                "b_out", nn.initializers.zeros_init(), (e_local, d)
+            )
+        else:
+            b_in = jnp.zeros((e_local, self.d_ff), jnp.float32)
+            b_out = jnp.zeros((e_local, d), jnp.float32)
 
         if dropless:
             # ---- dropless: sort by expert, ragged grouped matmuls -------
@@ -246,7 +269,28 @@ class MoEFFN(nn.Module):
             group_sizes = jnp.bincount(expert_flat, length=e)
             tok_ids = order // k  # pair -> owning token row
             xs = tokens.reshape(n_total, d)[tok_ids].astype(self.dtype)
-            if gmm_impl == "pallas":
+            # The serving engine's counters read the routing (a no-op
+            # unless "serve_stats" is asked for).
+            if not self.is_initializing():
+                self.sow(
+                    "serve_stats", "expert_idx", topk_idx.reshape(n_total, k)
+                )
+            # A row tile no taller than the pairs there are (a decode
+            # step routes a few dozen), and a column tile that divides
+            # the width: the kernel pads what does not fit, and padding
+            # the columns copies every expert's matrix.
+            block_m = min(
+                self.gmm_block_m, max(32, 1 << (p_tot - 1).bit_length())
+            )
+
+            def block_n(n_cols):
+                fits = [
+                    c for c in (self.gmm_block_n, 384, 256, 128)
+                    if c <= self.gmm_block_n and n_cols % c == 0
+                ]
+                return fits[0] if fits else self.gmm_block_n
+
+            if gmm_impl == "pallas" and self.use_bias and not self.gated:
                 # Fused-epilogue kernels: the per-group bias (and gelu)
                 # ride inside the gmm — XLA cannot fuse elementwise
                 # chains into a Pallas custom call, so the unfused
@@ -262,8 +306,8 @@ class MoEFFN(nn.Module):
                     b,
                     group_sizes,
                     activation=act,
-                    block_m=self.gmm_block_m,
-                    block_n=self.gmm_block_n,
+                    block_m=block_m,
+                    block_n=block_n(rhs.shape[-1]),
                     interpret=interpret,
                 )
                 h = fused(xs, w_in.astype(self.dtype), b_in, "gelu")
@@ -277,14 +321,22 @@ class MoEFFN(nn.Module):
                     rhs,
                     group_sizes,
                     impl=gmm_impl,
-                    block_m=self.gmm_block_m,
-                    block_n=self.gmm_block_n,
+                    block_m=block_m,
+                    block_n=block_n(rhs.shape[-1]),
                     interpret=interpret,
                 )
                 h = gmm(xs, w_in.astype(self.dtype))
-                h = nn.gelu(h + b_in[sorted_e].astype(h.dtype))
+                if self.use_bias:
+                    h = h + b_in[sorted_e].astype(h.dtype)
+                if self.gated:
+                    # unfused: the gate's product is an XLA elementwise
+                    # over two [P, d_ff] kernel outputs
+                    h = nn.silu(gmm(xs, w_gate.astype(self.dtype))) * h
+                else:
+                    h = nn.gelu(h)
                 out = gmm(h.astype(self.dtype), w_out.astype(self.dtype))
-                out = out + b_out[sorted_e].astype(out.dtype)
+                if self.use_bias:
+                    out = out + b_out[sorted_e].astype(out.dtype)
             self.sow("metrics", "moe_drop", jnp.float32(0.0))
             gate_flat = topk_gate.reshape(p_tot)[order].astype(out.dtype)
             y = (

@@ -194,6 +194,21 @@ class Attention(nn.Module):
     # only live pages — tolerance-level parity (online softmax), HBM
     # traffic scaling with live tokens instead of page capacity.
     paged_attention_impl: str = "gather"
+    # Width of one head where it is not d_model / num_heads (q, k, v
+    # project to heads * head_dim, attn_out back to d_model).
+    head_dim: int | None = None
+    # RMSNorm over each head's q and k (learned scale over head_dim),
+    # before RoPE.
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-6
+    # Learned sparse attention (ops/sparse_attention.py): with
+    # ``indexer_heads`` > 0 an indexer of that many heads of
+    # ``indexer_head_dim`` (one key head, cached beside K and V) scores
+    # every token at or before a query, and the query attends over the
+    # ``sparse_topk`` best only.
+    indexer_heads: int = 0
+    indexer_head_dim: int = 64
+    sparse_topk: int = 0
 
     @nn.compact
     def __call__(
@@ -208,18 +223,38 @@ class Attention(nn.Module):
             raise ValueError(
                 f"unknown attention impl {self.impl!r}; choose from {ATTENTION_IMPLS}"
             )
-        if mode not in ("train", "prefill", "decode", "paged_decode"):
+        if mode not in (
+            "train", "prefill", "decode", "paged_decode", "paged_prefill"
+        ):
             raise ValueError(
-                f"unknown mode {mode!r}; choose from "
-                "('train', 'prefill', 'decode', 'paged_decode')"
+                f"unknown mode {mode!r}; choose from ('train', 'prefill', "
+                "'decode', 'paged_decode', 'paged_prefill')"
             )
         b, t, d_model = x.shape
-        if d_model % self.num_heads:
+        if self.head_dim is None and d_model % self.num_heads:
             raise ValueError(
                 f"d_model {d_model} not divisible by num_heads {self.num_heads}"
             )
-        head_dim = d_model // self.num_heads
+        head_dim = self.head_dim or d_model // self.num_heads
         tp = self.tensor_axis is not None and self.tensor_axis_size > 1
+        sparse = self.indexer_heads > 0
+        if sparse:
+            if self.sparse_topk < 1:
+                raise ValueError(
+                    "indexer_heads > 0 needs sparse_topk >= 1, got "
+                    f"{self.sparse_topk}"
+                )
+            if tp or (self.seq_axis is not None and self.seq_axis_size > 1):
+                raise ValueError(
+                    "the sparse-attention indexer runs on one device: no "
+                    "tensor or sequence axis"
+                )
+            if mode == "decode" or self.quant_kv_cache or not self.causal:
+                raise ValueError(
+                    "the sparse-attention indexer serves through the paged "
+                    "pools in float (modes train, prefill, paged_prefill, "
+                    "paged_decode; causal; no int8 KV)"
+                )
         if tp and self.num_heads % self.tensor_axis_size:
             raise ValueError(
                 f"num_heads {self.num_heads} not divisible by tensor axis "
@@ -265,11 +300,25 @@ class Attention(nn.Module):
         q = q.reshape(b, t, heads_local, head_dim)
         k = k.reshape(b, t, kv_local, head_dim)
         v = v.reshape(b, t, kv_local, head_dim)
+        if self.qk_norm:
+            qk_norm = partial(
+                nn.RMSNorm, epsilon=self.qk_norm_eps, dtype=self.dtype
+            )
+            q = qk_norm(name="q_norm")(q)
+            k = qk_norm(name="k_norm")(k)
+        if sparse:
+            q_idx = proj(
+                self.indexer_heads * self.indexer_head_dim, name="idx_q"
+            )(x).reshape(b, t, self.indexer_heads, self.indexer_head_dim)
+            k_idx = nn.LayerNorm(
+                epsilon=1e-6, dtype=self.dtype, name="idx_k_norm"
+            )(proj(self.indexer_head_dim, name="idx_k")(x))[:, :, None, :]
+            w_idx = proj(self.indexer_heads, name="idx_w")(x)
 
         if self.rope:
             # GLOBAL positions of this block's tokens: the shard offset
             # under sequence sharding, the cache position when decoding.
-            if mode in ("decode", "paged_decode"):
+            if mode in ("decode", "paged_decode", "paged_prefill"):
                 if decode_pos is None:
                     raise ValueError(f"mode={mode!r} needs decode_pos")
                 offset = decode_pos
@@ -285,6 +334,29 @@ class Attention(nn.Module):
                 positions = offset + jnp.arange(t)
             q = apply_rope(q, positions, self.rope_base)
             k = apply_rope(k, positions, self.rope_base)
+            if sparse:
+                q_idx = apply_rope(q_idx, positions, self.rope_base)
+                k_idx = apply_rope(k_idx, positions, self.rope_base)
+        if sparse:
+            k_idx = k_idx[:, :, 0]  # [B, T, Di]: one key head
+            # As cached: the row padded with zeros to whole 128-lane
+            # tiles. A [num_pages, page_size, 64] bf16 pool is laid out
+            # with num_pages minor-most by the TPU compiler, and every
+            # program then copies it to row-major and back
+            # (serve/layout.py: the K and V pools' fault before they
+            # were folded); 128 lanes tile without padding and stay
+            # row-major. Zero lanes add nothing to a dot product.
+            idx_lanes = -(-self.indexer_head_dim // 128) * 128
+            k_idx_row = jnp.pad(
+                k_idx, ((0, 0), (0, 0), (0, idx_lanes - k_idx.shape[-1]))
+            )
+
+            def idx_view(pool, table):  # cached indexer keys [B, S, Di]
+                from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
+                    gather_pages,
+                )
+
+                return gather_pages(pool, table)[..., : self.indexer_head_dim]
 
         decode_step = False
         if mode in ("prefill", "decode"):
@@ -327,7 +399,17 @@ class Attention(nn.Module):
                     jnp.float32,
                 )
 
+            if sparse:
+                cik = self.variable(
+                    "cache", "cached_index_key", jnp.zeros,
+                    (b, self.max_decode_len, idx_lanes), k_idx.dtype,
+                )
+
             def write_cache(pos0) -> None:
+                if sparse:
+                    cik.value = lax.dynamic_update_slice(
+                        cik.value, k_idx_row, (0, pos0, 0)
+                    )
                 if self.quant_kv_cache:
                     from cs744_pytorch_distributed_tutorial_tpu.ops.quant import (
                         quantize_kv,
@@ -371,7 +453,7 @@ class Attention(nn.Module):
                 # both shapes).
                 write_cache(decode_pos)
                 decode_step = True
-        elif mode == "paged_decode":
+        elif mode in ("paged_decode", "paged_prefill"):
             # Continuous-batching serve path (serve/): KV lives in a
             # POOL of fixed-size pages shared by every slot —
             # [num_pages, page_size, Hkv*D] per layer in the "pages"
@@ -401,17 +483,22 @@ class Attention(nn.Module):
                 )
             if self.page_size is None or self.num_pages is None:
                 raise ValueError(
-                    "mode='paged_decode' needs page_size and num_pages "
+                    f"mode={mode!r} needs page_size and num_pages "
                     "(the paged KV pool geometry; see serve/engine.py)"
                 )
             if decode_pos is None or page_table is None:
                 raise ValueError(
-                    "mode='paged_decode' needs decode_pos (per-slot "
+                    f"mode={mode!r} needs decode_pos (per-slot "
                     "depths, [B]) and page_table ([B, P] page indices)"
                 )
-            if t != 1:
+            if mode == "paged_decode" and t != 1:
                 raise ValueError(
                     f"paged decode steps one token at a time, got t={t}"
+                )
+            if mode == "paged_prefill" and self.quant_kv_cache:
+                raise ValueError(
+                    "chunked prefill writes float pools; an int8-KV engine "
+                    "prefills one shot (ServeConfig.prefill_chunk=None)"
                 )
             pool_shape = (self.num_pages, self.page_size, kv_local * head_dim)
             scale_shape = (self.num_pages, self.page_size, kv_local)
@@ -431,6 +518,92 @@ class Attention(nn.Module):
                     "pages", "value_scale_pages", jnp.ones, scale_shape,
                     jnp.float32,
                 )
+            if sparse:
+                # The indexer's keys: a third pool under the same page
+                # table, one (lane-padded) row a token.
+                ip = self.variable(
+                    "pages", "index_key_pages", jnp.zeros,
+                    (self.num_pages, self.page_size, idx_lanes), k_idx.dtype,
+                )
+        if mode == "paged_prefill":
+            # A chunk of t tokens a slot at positions decode_pos..+t-1:
+            # its K, V (and indexer keys) go into the slot's pages, then
+            # the chunk attends over the slot's whole view, which now
+            # holds it. Positions past the page table's width, or on
+            # entries the engine left 0, land on the trash page; rows
+            # past the prompt's end inside its last page are overwritten
+            # by decode before any query can see them (every mask is by
+            # position).
+            from cs744_pytorch_distributed_tutorial_tpu.ops import (
+                sparse_attention as sa,
+            )
+            from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
+                gather_pages,
+                unfold_heads,
+            )
+
+            positions = jnp.asarray(decode_pos)[:, None] + jnp.arange(t)
+            page_idx = positions // self.page_size
+            in_table = page_idx < page_table.shape[1]
+            rows_page = jnp.where(
+                in_table,
+                jnp.take_along_axis(
+                    page_table,
+                    jnp.minimum(page_idx, page_table.shape[1] - 1),
+                    axis=1,
+                ),
+                0,
+            )
+            rows_off = positions % self.page_size
+            kp.value = kp.value.at[rows_page, rows_off].set(
+                k.reshape(b, t, kv_local * head_dim)
+            )
+            vp.value = vp.value.at[rows_page, rows_off].set(
+                v.reshape(b, t, kv_local * head_dim)
+            )
+            if sparse:
+                ip.value = ip.value.at[rows_page, rows_off].set(k_idx_row)
+
+            # The chunk attends over the slot's paged view, but only over
+            # as much of it as the chunk's last position reaches: the
+            # view's width is one of a few static fractions of the
+            # slot's capacity, picked at run time (one program, a branch
+            # a width), so a chunk early in a long prompt does not score
+            # the whole capacity to mask most of it. Every width gives
+            # the same numbers: keys past the last position are masked.
+            pages_cap = page_table.shape[1]
+            widths = sorted(
+                {max(1, -(-pages_cap * i // 4)) for i in (1, 2, 3, 4)}
+            )
+
+            def attend(n_pages):
+                table = page_table[:, :n_pages]
+                allowed = None
+                if sparse:
+                    view = idx_view(ip.value, table)
+                    valid = (
+                        jnp.arange(view.shape[1])[None, None, :]
+                        <= positions[:, :, None]
+                    )
+                    allowed = sa.topk_mask(
+                        sa.indexer_scores(q_idx, view, w_idx), valid,
+                        self.sparse_topk,
+                    )
+                return sa.masked_attention(
+                    q,
+                    unfold_heads(gather_pages(kp.value, table), head_dim),
+                    unfold_heads(gather_pages(vp.value, table), head_dim),
+                    positions,
+                    allowed,
+                )
+
+            pages_needed = jnp.max(positions) // self.page_size + 1
+            paged_out = lax.switch(
+                sum((pages_needed > w).astype(jnp.int32) for w in widths[:-1]),
+                [partial(attend, w) for w in widths],
+            )
+            decode_step = True
+        elif mode == "paged_decode":
             # Scatter the new token's K/V. Inactive slots are parked on
             # the reserved trash page 0 by the engine — their writes
             # collide there harmlessly (the page is never gathered by a
@@ -448,7 +621,7 @@ class Attention(nn.Module):
                     "paged_attention_impl must be 'gather' or 'kernel', "
                     f"got {self.paged_attention_impl!r}"
                 )
-            use_kernel = self.paged_attention_impl == "kernel"
+            use_kernel = self.paged_attention_impl == "kernel" and not sparse
             if use_kernel:
                 from cs744_pytorch_distributed_tutorial_tpu.ops.paged_attention import (
                     paged_attention,
@@ -487,7 +660,52 @@ class Attention(nn.Module):
 
                 kp.value = kp.value.at[slot_page, slot_off].set(fold(k))
                 vp.value = vp.value.at[slot_page, slot_off].set(fold(v))
-                if use_kernel:
+                if sparse:
+                    # Score the slot's cached indexer keys, keep the
+                    # sparse_topk best positions, and read only those
+                    # rows of the K and V pools.
+                    from cs744_pytorch_distributed_tutorial_tpu.ops import (
+                        sparse_attention as sa,
+                    )
+
+                    ip.value = ip.value.at[slot_page, slot_off].set(
+                        k_idx_row[:, 0]
+                    )
+                    view = idx_view(ip.value, page_table)
+                    valid = (
+                        jnp.arange(view.shape[1])[None, :]
+                        <= decode_pos[:, None]
+                    )
+                    sel, sel_ok = sa.topk_indices(
+                        sa.indexer_scores(q_idx, view, w_idx)[:, 0], valid,
+                        self.sparse_topk,
+                    )
+                    rows = (
+                        jnp.take_along_axis(
+                            page_table, sel // self.page_size, axis=1
+                        ) * self.page_size + sel % self.page_size
+                    )
+
+                    def take_rows(pool):
+                        flat = pool.reshape(-1, kv_local * head_dim)
+                        return flat[rows].reshape(
+                            b, rows.shape[1], kv_local, head_dim
+                        )
+
+                    paged_out = sa.selected_rows_attention(
+                        q, take_rows(kp.value), take_rows(vp.value), sel_ok
+                    )
+                    # what the selection kept and what the indexer
+                    # scored, a slot (the engine's counters; a no-op
+                    # unless "serve_stats" is asked for)
+                    if not self.is_initializing():
+                        self.sow(
+                            "serve_stats", "selected_tokens", sel_ok.sum(-1)
+                        )
+                        self.sow(
+                            "serve_stats", "scored_tokens", valid.sum(-1)
+                        )
+                elif use_kernel:
                     paged_out = paged_attention(
                         q, kp.value, vp.value, page_table, decode_pos,
                         interpret=self.flash_interpret,
@@ -513,14 +731,14 @@ class Attention(nn.Module):
         sp_kv_native = self.impl in (
             "ring", "ring_flash", "ulysses", "ulysses_flash"
         ) and (self.seq_axis is not None and self.seq_axis_size > 1)
-        if not decode_step and not sp_kv_native:
+        if not decode_step and not sp_kv_native and not sparse:
             from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
                 repeat_kv,
             )
 
             k, v = repeat_kv(k, rep), repeat_kv(v, rep)
         if decode_step:
-            if mode == "paged_decode":
+            if mode in ("paged_decode", "paged_prefill"):
                 out = paged_out
             elif self.quant_kv_cache:
                 from cs744_pytorch_distributed_tutorial_tpu.ops.quant import (
@@ -532,6 +750,23 @@ class Attention(nn.Module):
                 )
             else:
                 out = decode_attention(q, ck.value, cv.value, decode_pos)
+        elif sparse:
+            # The full forward (and the one-shot prefill): causal
+            # attention masked to each query's selection.
+            from cs744_pytorch_distributed_tutorial_tpu.ops import (
+                sparse_attention as sa,
+            )
+
+            q_pos = jnp.broadcast_to(jnp.arange(t), (b, t))
+            allowed = sa.topk_mask(
+                sa.indexer_scores(q_idx, k_idx, w_idx),
+                jnp.arange(t)[None, None, :] <= q_pos[:, :, None],
+                self.sparse_topk,
+            )
+            if not self.is_initializing():
+                # S_t as a [B, T, T] mask, for who asks for intermediates
+                self.sow("intermediates", "selected", allowed)
+            out = sa.masked_attention(q, k, v, q_pos, allowed)
         elif self.seq_axis is None or self.seq_axis_size == 1:
             if self.impl in ("flash", "ring_flash", "ulysses_flash"):
                 from cs744_pytorch_distributed_tutorial_tpu.ops.flash_attention import (
@@ -622,6 +857,17 @@ class Block(nn.Module):
     page_size: int | None = None
     num_pages: int | None = None
     paged_attention_impl: str = "gather"
+    # Attention options passed through (see Attention): a head width
+    # apart from d_model / num_heads, per-head q/k RMSNorm, and the
+    # learned sparse-attention indexer.
+    head_dim: int | None = None
+    qk_norm: bool = False
+    indexer_heads: int = 0
+    indexer_head_dim: int = 64
+    sparse_topk: int = 0
+    # Expert biases b_in / b_out (models/moe.py::MoEFFN). With
+    # mlp="swiglu" the experts are gated as the dense MLP would be.
+    moe_bias: bool = True
 
     @nn.compact
     def __call__(
@@ -651,13 +897,18 @@ class Block(nn.Module):
             raise ValueError(
                 f"unknown mlp {self.mlp!r}; choose from {MLP_IMPLS}"
             )
-        if self.num_experts > 0 and self.mlp != "gelu":
-            # The MoE branch replaces the dense MLP entirely — a swiglu
-            # request would otherwise be silently ignored.
+        if (
+            self.num_experts > 0 and self.mlp == "swiglu"
+            and self.moe_dispatch != "dropless"
+        ):
+            # The MoE branch replaces the dense MLP entirely, and only
+            # the dropless path has gated experts — on the capacity
+            # paths a swiglu request would be silently ignored.
             raise ValueError(
                 f"mlp={self.mlp!r} does not compose with MoE "
-                f"(num_experts={self.num_experts}): the routed MoEFFN "
-                "replaces the dense MLP; drop --mlp swiglu or the experts"
+                f"(num_experts={self.num_experts}) under "
+                f"moe_dispatch={self.moe_dispatch!r}: gated experts run "
+                "on moe_dispatch='dropless'; drop --mlp swiglu or pick it"
             )
         drop = partial(
             nn.Dropout, rate=self.dropout_rate, deterministic=deterministic
@@ -685,6 +936,12 @@ class Block(nn.Module):
             page_size=self.page_size,
             num_pages=self.num_pages,
             paged_attention_impl=self.paged_attention_impl,
+            head_dim=self.head_dim,
+            qk_norm=self.qk_norm,
+            qk_norm_eps=self.norm_eps,
+            indexer_heads=self.indexer_heads,
+            indexer_head_dim=self.indexer_head_dim,
+            sparse_topk=self.sparse_topk,
             name="attn",
         )(h, mode=mode, decode_pos=decode_pos, page_table=page_table)
         if self.dropout_rate > 0.0:
@@ -710,6 +967,8 @@ class Block(nn.Module):
                 dtype=self.dtype,
                 expert_axis=self.expert_axis,
                 expert_axis_size=self.expert_axis_size,
+                gated=self.mlp == "swiglu",
+                use_bias=self.moe_bias,
                 name="moe",
             )(h)
             return x + y
@@ -845,6 +1104,17 @@ class TransformerLM(nn.Module):
     # "gather" (reference, bitwise vs dense cache) or "kernel" (Pallas
     # live-pages-only decode — ops/paged_attention.py; see Attention).
     paged_attention_impl: str = "gather"
+    # Attention options passed through (see Attention): a head width
+    # apart from d_model / num_heads, per-head q/k RMSNorm, and the
+    # learned sparse-attention indexer.
+    head_dim: int | None = None
+    qk_norm: bool = False
+    indexer_heads: int = 0
+    indexer_head_dim: int = 64
+    sparse_topk: int = 0
+    # Expert biases b_in / b_out (models/moe.py::MoEFFN). With
+    # mlp="swiglu" the experts are gated as the dense MLP would be.
+    moe_bias: bool = True
 
     @nn.compact
     def __call__(
@@ -855,7 +1125,12 @@ class TransformerLM(nn.Module):
         decode_pos: jnp.ndarray | None = None,
         page_table: jnp.ndarray | None = None,
         deterministic: bool = True,
+        logits_at: jnp.ndarray | None = None,
     ) -> jnp.ndarray:
+        """``logits_at`` ([B] indices into this call's tokens) asks for
+        the logits of one position a row only, ``[B, 1, vocab]``: the
+        final norm and the head then run on that row alone (a prefill
+        chunk needs one token's logits, not a chunk's)."""
         b, t_local = tokens.shape
         tok_embed = nn.Embed(
             self.vocab_size, self.d_model, dtype=self.dtype, name="tok_embed"
@@ -864,7 +1139,7 @@ class TransformerLM(nn.Module):
         # Global positions: a sequence-sharded block starts at the
         # device's offset along the seq axis, not at 0; a cached decode
         # step sits at its decode position.
-        if mode in ("decode", "paged_decode"):
+        if mode in ("decode", "paged_decode", "paged_prefill"):
             if decode_pos is None:
                 raise ValueError(f"mode={mode!r} needs decode_pos")
             offset = decode_pos
@@ -931,6 +1206,12 @@ class TransformerLM(nn.Module):
             page_size=self.page_size,
             num_pages=self.num_pages,
             paged_attention_impl=self.paged_attention_impl,
+            head_dim=self.head_dim,
+            qk_norm=self.qk_norm,
+            indexer_heads=self.indexer_heads,
+            indexer_head_dim=self.indexer_head_dim,
+            sparse_topk=self.sparse_topk,
+            moe_bias=self.moe_bias,
         )
         if self.scan_layers:
             if self.num_experts > 0:
@@ -986,6 +1267,8 @@ class TransformerLM(nn.Module):
                         x, deterministic, mode=mode, decode_pos=decode_pos,
                         page_table=page_table,
                     )
+        if logits_at is not None:
+            x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
         x = _norm_cls(self.norm, self.norm_eps)(dtype=self.dtype, name="ln_f")(x)
         if self.tie_embeddings:
             # The attend path reuses the (unquantized) embedding table —

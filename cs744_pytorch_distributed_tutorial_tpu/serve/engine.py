@@ -83,7 +83,51 @@ _CACHE_TO_PAGES = {
     "cached_value": "value_pages",
     "key_scale": "key_scale_pages",
     "value_scale": "value_scale_pages",
+    "cached_index_key": "index_key_pages",
 }
+
+
+def _named_leaves(tree: Any, name: str) -> list[Any]:
+    """Every leaf sown under ``name`` (flax keeps a tuple a name)."""
+    return [
+        leaf
+        for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+        if any(getattr(k, "key", None) == name for k in path)
+    ]
+
+
+def _step_counters(stats: Any, active: jnp.ndarray, num_experts: int):
+    """What the model sowed into "serve_stats" in one decode step, over
+    the active slots and summed over the layers, as four int32 that ride
+    behind the step's tokens: tokens the selection kept, tokens the
+    indexer scored, experts that received a token, and a thousand times
+    the layers' mean of (most tokens on one expert / mean tokens an
+    expert). None where the model sowed nothing."""
+    selected = _named_leaves(stats, "selected_tokens")
+    scored = _named_leaves(stats, "scored_tokens")
+    routed = _named_leaves(stats, "expert_idx")
+    if not (selected or routed):
+        return None
+    zero = jnp.int32(0)
+
+    def over_active(leaves):
+        return sum((jnp.sum(jnp.where(active, x, 0)) for x in leaves), zero)
+
+    hit, ratio = zero, jnp.float32(0.0)
+    for idx in routed:  # [B, K] expert ids of one layer
+        counts = jnp.sum(
+            jax.nn.one_hot(idx, num_experts, dtype=jnp.int32)
+            * active[:, None, None],
+            axis=(0, 1),
+        )
+        hit = hit + jnp.sum(counts > 0)
+        ratio = ratio + jnp.max(counts) * num_experts / jnp.maximum(
+            jnp.sum(counts), 1
+        )
+    milli = jnp.round(1e3 * ratio / max(len(routed), 1))
+    return jnp.stack(
+        [over_active(selected), over_active(scored), hit, milli]
+    ).astype(jnp.int32)
 
 
 @dataclass
@@ -115,6 +159,12 @@ class ServeConfig:
     # "kernel" on TPU backends and "gather" elsewhere — interpret-mode
     # Pallas would throttle a CPU deployment for no byte savings.
     paged_attention_impl: str = "auto"
+    # Prefill by chunks of this many tokens through ONE compiled program
+    # (mode="paged_prefill": a chunk writes its rows into the slot's
+    # pages and attends over what the slot already holds), every chunk
+    # of a request inside its admission. None = one dense pass over the
+    # whole prompt, a program a power-of-two length bucket.
+    prefill_chunk: int | None = None
 
 
 @dataclass
@@ -322,6 +372,20 @@ class ServingEngine:
         self._admit_steps = 0  # steps that admitted at least one request
         self._max_admits_in_step = 0
         self._pages_grown = 0  # pages the grow loop allocated
+        self._prefill_chunks = 0  # chunk programs run (prefill_chunk set)
+        # What the decode steps' models sowed (``_step_counters``), summed
+        # over steps and layers: sparse attention's kept and scored
+        # tokens, experts that received a token, and the running sum of
+        # the steps' expert-load ratio.
+        self._selected_tokens = 0
+        self._scored_tokens = 0
+        self._experts_hit = 0
+        self._expert_ratio_sum = 0.0
+        if cfg.prefill_chunk is not None and cfg.prefill_chunk < 1:
+            raise ValueError(
+                f"prefill_chunk must be >= 1 or None, got {cfg.prefill_chunk}"
+            )
+        self._chunk_program: Any = None
         self._base_key = jax.random.key(cfg.seed)
         # One PRNG stream PER REQUEST, indexed by absolute output-token
         # position: token t of request r always samples from
@@ -403,6 +467,28 @@ class ServingEngine:
 
         return jax.eval_shape(init_fn)
 
+    def _jit_pages_program(self, fn, n_replicated: int):
+        """``jax.jit`` of one of the engine's programs, ``fn(params,
+        pages, *replicated) -> (pages, tokens)``, the pools donated (XLA
+        aliases them in place; no program allocates a pool); under a mesh
+        wrapped in ``shard_map`` with the params' and the pools' specs."""
+        if self.mesh is None:
+            return jax.jit(fn, donate_argnums=(1,))
+        from jax.sharding import PartitionSpec as P
+
+        page_specs = self._page_specs()
+        return jax.jit(
+            jax.shard_map(
+                fn,
+                mesh=self.mesh,
+                in_specs=(self.param_specs, page_specs)
+                + (P(),) * n_replicated,
+                out_specs=(page_specs, P()),
+                check_vma=False,
+            ),
+            donate_argnums=(1,),
+        )
+
     def _build_decode_step(self):
         """ONE jitted fixed-shape step for the engine's lifetime: every
         argument is an array of static shape, so slot churn (retire /
@@ -423,7 +509,7 @@ class ServingEngine:
                 mode="paged_decode",
                 decode_pos=lengths,
                 page_table=page_table,
-                mutable=["pages"],
+                mutable=["pages", "serve_stats"],
             )
             # Per-slot sampling keys from the (request, token-index)
             # stream — see _sample_root. ``key`` is the constant stream
@@ -441,25 +527,18 @@ class ServingEngine:
                 )[0]
             )(logits[:, 0].astype(jnp.float32), keys)
             tok = jnp.where(active, tok, cfg.pad_id).astype(jnp.int32)
+            # Device-side counters ride behind the tokens, in the one
+            # array the host fetches a step; a model that sows none
+            # compiles the step it always did.
+            counters = _step_counters(
+                mutated.get("serve_stats", {}), active,
+                getattr(model, "num_experts", 0),
+            )
+            if counters is not None:
+                tok = jnp.concatenate([tok, counters])
             return mutated["pages"], tok
 
-        if self.mesh is None:
-            return jax.jit(step, donate_argnums=(1,))
-        from jax.sharding import PartitionSpec as P
-
-        page_specs = self._page_specs()
-        rep = P()
-        return jax.jit(
-            jax.shard_map(
-                step,
-                mesh=self.mesh,
-                in_specs=(self.param_specs, page_specs, rep, rep, rep, rep,
-                          rep, rep, rep),
-                out_specs=(page_specs, rep),
-                check_vma=False,
-            ),
-            donate_argnums=(1,),
-        )
+        return self._jit_pages_program(step, 7)
 
     def _prefill_fn(self, bucket: int):
         """Jitted prefill+commit for one prompt-length bucket: dense
@@ -532,26 +611,44 @@ class ServingEngine:
             pages = commit(pages, mutated["cache"], page_row, true_len)
             return pages, tok[0].astype(jnp.int32)
 
-        if self.mesh is None:
-            fn = jax.jit(prefill, donate_argnums=(1,))
-        else:
-            from jax.sharding import PartitionSpec as P
-
-            page_specs = self._page_specs()
-            rep = P()
-            fn = jax.jit(
-                jax.shard_map(
-                    prefill,
-                    mesh=self.mesh,
-                    in_specs=(self.param_specs, page_specs, rep, rep, rep,
-                              rep),
-                    out_specs=(page_specs, rep),
-                    check_vma=False,
-                ),
-                donate_argnums=(1,),
-            )
+        fn = self._jit_pages_program(prefill, 4)
         self._prefill_cache[bucket] = fn
         return fn
+
+    def _chunk_fn(self):
+        """The one jitted prefill program of a chunked engine: ``chunk``
+        tokens of one slot at ``offset``, written into the slot's pages
+        and attended over pages plus chunk (mode="paged_prefill"), and
+        the token sampled at ``last_idx`` of the chunk. Offset, page row
+        and index are traced, so every chunk of every prompt runs the
+        same executable."""
+        if self._chunk_program is not None:
+            return self._chunk_program
+        cfg = self.cfg
+        model = self.model
+
+        def prefill_chunk(params, pages, tokens, offset, page_row, last_idx,
+                          key):
+            logits, mutated = model.apply(
+                {"params": params, "pages": pages},
+                tokens,
+                mode="paged_prefill",
+                decode_pos=offset[None],
+                page_table=page_row[None],
+                logits_at=last_idx[None],
+                mutable=["pages"],
+            )
+            tok = sample_tokens(
+                logits[:, 0].astype(jnp.float32),
+                key,
+                temperature=cfg.temperature,
+                top_k=cfg.top_k,
+                top_p=cfg.top_p,
+            )
+            return mutated["pages"], tok[0].astype(jnp.int32)
+
+        self._chunk_program = self._jit_pages_program(prefill_chunk, 5)
+        return self._chunk_program
 
     @staticmethod
     def _bucket_for(n: int) -> int:
@@ -801,7 +898,9 @@ class ServingEngine:
             admit_kind = "prefill"
         req.replay_pending = False
         plen = int(req.prompt.size)
-        bucket = self._bucket_for(plen)
+        chunk = self.cfg.prefill_chunk
+        # the padded length a program sees: the chunk, or the bucket
+        bucket = chunk if chunk else self._bucket_for(plen)
         # The profiler's spans (docs/observability.md) open at the stamps
         # the tracer's hooks get, so both describe the same intervals.
         with profiling.annotate(
@@ -814,7 +913,8 @@ class ServingEngine:
                 pages = self.pool.alloc(need)
                 row = np.zeros((self.cfg.max_pages_per_slot,), np.int32)
                 row[: len(pages)] = pages
-                prompt = np.zeros((1, bucket), np.int32)
+                n_chunks = -(-plen // chunk) if chunk else 1
+                prompt = np.zeros((1, n_chunks * bucket), np.int32)
                 prompt[0, :plen] = req.prompt
                 # The (request, token-index) stream — a recompute-preempted
                 # request's re-prefill samples token index
@@ -825,23 +925,53 @@ class ServingEngine:
                     jax.random.fold_in(self._sample_root, req.req_id),
                     req.output_tokens,
                 )
-                prefill = self._prefill_fn(bucket)
-                args = (
-                    jnp.asarray(prompt), jnp.int32(plen), jnp.asarray(row),
-                )
+                if chunk:
+                    prefill = self._chunk_fn()
+                    row_dev = jnp.asarray(row)
+                else:
+                    prefill = self._prefill_fn(bucket)
+                    args = (
+                        jnp.asarray(prompt), jnp.int32(plen),
+                        jnp.asarray(row),
+                    )
             with profiling.annotate(
                 "serve/prefill", req=req.req_id, bucket=bucket
             ):
-                self._pages, first_tok = prefill(
-                    self.params, self._pages, *args, key
-                )
-                tok = int(first_tok)  # blocks — the request's first token
+                if chunk:
+                    # Every chunk inside this admission, one program and
+                    # one blocking fetch each; the last chunk's token is
+                    # the request's first.
+                    for ci in range(n_chunks):
+                        off = ci * chunk
+                        n = min(chunk, plen - off)
+                        with profiling.annotate(
+                            "serve/prefill_chunk", req=req.req_id, chunk=ci,
+                            offset=off, len=n,
+                        ):
+                            self._pages, first_tok = prefill(
+                                self.params, self._pages,
+                                jnp.asarray(prompt[:, off:off + chunk]),
+                                jnp.int32(off), row_dev, jnp.int32(n - 1),
+                                key,
+                            )
+                            tok = int(first_tok)
+                    self._prefill_chunks += n_chunks
+                else:
+                    self._pages, first_tok = prefill(
+                        self.params, self._pages, *args, key
+                    )
+                    tok = int(first_tok)  # blocks — the first token
             now = self.clock()
             first = req.first_token_time is None
             if first:
                 req.first_token_time = now
-            # Rows [plen, bucket) of the padded prompt scattered to trash.
-            self._trash_rows += bucket - plen
+            # Rows of the padded prompt past its pages scatter to trash
+            # (a chunked prompt's padding first fills its last page's
+            # tail, which decode overwrites).
+            self._trash_rows += (
+                max(0, prompt.shape[1] - need * self.cfg.page_size)
+                if chunk else bucket - plen
+            )
             if self.tracer is not None:
                 self.tracer.on_admit(
                     req, slot=slot_idx, bucket=bucket, t0=t_admit, t1=now,
@@ -1075,6 +1205,7 @@ class ServingEngine:
                 self.params, self._pages, *args, self._sample_root
             )
             toks = np.asarray(toks)  # graftlint: disable=GL001 -- the scheduler NEEDS this sync: retire/refill decisions read the sampled tokens; one fetch per engine step, outside any jit
+            toks, counters = toks[: cfg.num_slots], toks[cfg.num_slots:]
         with profiling.annotate("serve/retire", step=step) as retire_span:
             # NaN detection on the already-fetched tokens (zero extra
             # transfers): poisoned logits sample out-of-vocab. Raised
@@ -1089,6 +1220,11 @@ class ServingEngine:
                 )
             self._step_count += 1
             self._active_slot_steps += n_active
+            if counters.size:  # _step_counters, behind the tokens
+                self._selected_tokens += int(counters[0])
+                self._scored_tokens += int(counters[1])
+                self._experts_hit += int(counters[2])
+                self._expert_ratio_sum += float(counters[3]) / 1e3
             # Inactive slots still write one KV row per step — to the
             # trash page (fixed-shape contract).
             self._trash_rows += cfg.num_slots - n_active
@@ -1313,6 +1449,15 @@ class ServingEngine:
             "admit_steps": self._admit_steps,
             "max_admits_in_step": self._max_admits_in_step,
             "pages_grown": self._pages_grown,
+            "prefill_chunks": self._prefill_chunks,
+            # summed over decode steps and layers (``_step_counters``);
+            # selected / scored is the sparsity served
+            "selected_tokens": self._selected_tokens,
+            "scored_tokens": self._scored_tokens,
+            "experts_hit": self._experts_hit,
+            # mean over decode steps of the layers' mean of (most tokens
+            # on one expert / mean tokens an expert)
+            "expert_tokens_max_over_mean": self._expert_ratio_sum / steps,
         }
 
 

@@ -10,8 +10,9 @@ and back on the way out: two pool-sized ``copy`` ops a pool a program,
 three quarters of a serving chip's time. Nothing in the Python says so;
 the compiled module does.
 
-``compile_programs`` compiles the engine's decode step and one prefill
-bucket from shapes alone, so it runs on the chip and, given the
+``compile_programs`` compiles the engine's decode step and its prefill
+(one bucket, or the one chunk program of a chunked engine) from shapes
+alone, so it runs on the chip and, given the
 sharding of a described device, through the compile-only topology on a
 CPU box (no chip, a few seconds)::
 
@@ -49,7 +50,9 @@ class PoolLayout:
     # elements as one data pool, or as a scanned stack of them
     pool_copies: list[tuple[tuple[int, ...], tuple[int, ...]]]
     temp_bytes: int  # memory_analysis().temp_size_in_bytes
-    pool_bytes: int  # one data pool (one layer's, where they are stacked)
+    # the smallest data pool (one layer's, where they are stacked): K and
+    # V are alike, a sparse-attention model's index_key_pages is smaller
+    pool_bytes: int
     # major_to_minor of each data pool as the program takes it
     entry_layouts: list[tuple[int, ...]]
 
@@ -72,9 +75,10 @@ def compile_programs(
     engine: Any, bucket: int, sharding: Any = None
 ) -> dict[str, Any]:
     """``{"decode": ..., "prefill": ...}``: the engine's decode step and
-    its prefill+commit for ``bucket``, compiled from shapes (no array is
-    made or donated). ``sharding`` places every argument; None is the
-    default device."""
+    its prefill+commit for ``bucket`` (with ``ServeConfig.prefill_chunk``
+    set: its one chunk program, and ``bucket`` is not read), compiled
+    from shapes (no array is made or donated). ``sharding`` places every
+    argument; None is the default device."""
     cfg = engine.cfg
     b, p = cfg.num_slots, cfg.max_pages_per_slot
 
@@ -91,9 +95,16 @@ def compile_programs(
         params, pages, s((b,), i32), s((b,), i32), s((b, p), i32),
         s((b,), jnp.bool_), s((b,), i32), s((b,), i32), key,
     ).compile()
-    prefill = engine._prefill_fn(bucket).lower(
-        params, pages, s((1, bucket), i32), s((), i32), s((p,), i32), key,
-    ).compile()
+    if cfg.prefill_chunk:
+        prefill = engine._chunk_fn().lower(
+            params, pages, s((1, cfg.prefill_chunk), i32), s((), i32),
+            s((p,), i32), s((), i32), key,
+        ).compile()
+    else:
+        prefill = engine._prefill_fn(bucket).lower(
+            params, pages, s((1, bucket), i32), s((), i32), s((p,), i32),
+            key,
+        ).compile()
     return {"decode": decode, "prefill": prefill}
 
 
@@ -101,8 +112,13 @@ def audit(compiled: Any, engine: Any) -> PoolLayout:
     """Read one compiled program of ``compile_programs`` (both take the
     pools as their second argument)."""
     pools = _data_pools(engine._pages)
-    layer_shape = pools[0].shape[1:] if engine._scanned else pools[0].shape
-    sizes = {math.prod(layer_shape), math.prod(pools[0].shape)}
+
+    def layer_shape(pool):
+        return pool.shape[1:] if engine._scanned else pool.shape
+
+    sizes = {math.prod(layer_shape(p)) for p in pools} | {
+        math.prod(p.shape) for p in pools
+    }
     copies = []
     for dims, minor_to_major in _COPY.findall(compiled.as_text()):
         shape = tuple(int(d) for d in dims.split(","))
@@ -113,6 +129,8 @@ def audit(compiled: Any, engine: Any) -> PoolLayout:
     return PoolLayout(
         pool_copies=copies,
         temp_bytes=int(compiled.memory_analysis().temp_size_in_bytes),
-        pool_bytes=math.prod(layer_shape) * pools[0].dtype.itemsize,
+        pool_bytes=min(
+            math.prod(layer_shape(p)) * p.dtype.itemsize for p in pools
+        ),
         entry_layouts=[tuple(f.layout.major_to_minor) for f in formats],
     )

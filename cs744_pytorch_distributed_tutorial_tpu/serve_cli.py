@@ -55,6 +55,14 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--use-rope", action="store_true")
     p.add_argument("--quant-kv", action="store_true",
                    help="int8 KV pages (ops/quant.py::quantize_kv)")
+    p.add_argument("--keye-config", default=None, metavar="CONFIG.JSON",
+                   help="build the model from a KeyeVL2 config.json's "
+                        "language-model keys (models/hf_interop.py::"
+                        "keye_model_config: gated experts, q/k norm, the "
+                        "sparse-attention indexer) in place of the size "
+                        "flags above; --max-seq-len still caps a request. "
+                        "Random params, as ever; no --parity-check (the "
+                        "dense-cache generator has no indexer)")
     # engine geometry
     p.add_argument("--num-slots", type=int, default=8,
                    help="decode slots B in the fixed-shape jitted step")
@@ -64,6 +72,10 @@ def build_parser() -> argparse.ArgumentParser:
                    help="pool pages per layer (page 0 reserved as trash)")
     p.add_argument("--max-pages-per-slot", type=int, default=16,
                    help="page-table width P: caps one request's KV")
+    p.add_argument("--prefill-chunk", type=int, default=None,
+                   help="prefill by chunks of this many tokens through "
+                        "one compiled program (docs/serving.md); default: "
+                        "one pass a prompt, a program a length bucket")
     p.add_argument("--paged-attention-impl", default="auto",
                    choices=("auto", "gather", "kernel"),
                    help="decode attention: Pallas live-pages kernel or "
@@ -211,18 +223,31 @@ def main(argv: list[str] | None = None) -> None:
     )
     from cs744_pytorch_distributed_tutorial_tpu.utils import profiling
 
-    model = TransformerLM(
-        vocab_size=args.vocab_size,
-        num_layers=args.num_layers,
-        num_heads=args.num_heads,
-        num_kv_heads=args.num_kv_heads,
-        d_model=args.d_model,
-        d_ff=args.d_ff,
-        max_seq_len=args.max_seq_len,
-        attention_impl="dense",
-        use_rope=args.use_rope,
-        quant_kv_cache=args.quant_kv,
-    )
+    if args.keye_config:
+        import json
+
+        from cs744_pytorch_distributed_tutorial_tpu.models.hf_interop import (
+            keye_model_config,
+        )
+
+        with open(args.keye_config, encoding="utf-8") as f:
+            model = TransformerLM(
+                **keye_model_config(json.load(f), max_seq_len=args.max_seq_len)
+            )
+        args.vocab_size = model.vocab_size
+    else:
+        model = TransformerLM(
+            vocab_size=args.vocab_size,
+            num_layers=args.num_layers,
+            num_heads=args.num_heads,
+            num_kv_heads=args.num_kv_heads,
+            d_model=args.d_model,
+            d_ff=args.d_ff,
+            max_seq_len=args.max_seq_len,
+            attention_impl="dense",
+            use_rope=args.use_rope,
+            quant_kv_cache=args.quant_kv,
+        )
     params = model.init(
         jax.random.key(args.seed), jnp.zeros((1, 8), jnp.int32)
     )["params"]
@@ -237,6 +262,7 @@ def main(argv: list[str] | None = None) -> None:
         eos_id=args.eos_id,
         seed=args.seed,
         paged_attention_impl=args.paged_attention_impl,
+        prefill_chunk=args.prefill_chunk,
     )
     workload = make_poisson_workload(
         num_requests=args.requests,

@@ -235,3 +235,46 @@ def test_grouped_matmul_fused_matches_unfused(act):
     )
     for a, c in zip(gf, gu):
         np.testing.assert_allclose(a, c, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("gmm_impl", ["ragged", "pallas"])
+def test_gated_dropless_experts_match_a_per_token_loop(gmm_impl):
+    """Gated (SiLU) experts with no biases, softmax over all experts,
+    top-8 of 16, weights renormalised over the eight: every token
+    against a plain loop over its experts. Nothing is dropped, so each
+    token's output is the full weighted sum."""
+    e, k, d, f = 16, 8, 32, 24
+    layer = MoEFFN(
+        num_experts=e, d_ff=f, top_k=k, dispatch_impl="dropless",
+        gated=True, use_bias=False, gmm_impl=gmm_impl, gmm_interpret=True,
+        gmm_block_m=16, gmm_block_n=8,
+    )
+    x = jax.random.normal(jax.random.key(1), (2, 9, d), jnp.float32)
+    variables = layer.init(jax.random.key(0), x)
+    p = variables["params"]
+    assert set(p) == {"router", "w_in", "w_gate", "w_out"}  # no biases
+    assert "serve_stats" not in variables
+    y, sown = layer.apply(variables, x, mutable=["serve_stats"])
+    routed = np.asarray(sown["serve_stats"]["expert_idx"][0])  # [tokens, k]
+
+    xs = np.asarray(x, np.float64).reshape(-1, d)
+    w_r = np.asarray(p["router"]["kernel"], np.float64)
+    w1, w3, w2 = (np.asarray(p[n], np.float64) for n in ("w_gate", "w_in", "w_out"))
+    want = np.zeros_like(xs)
+    for t, h in enumerate(xs):
+        logits = h @ w_r
+        prob = np.exp(logits - logits.max())
+        prob /= prob.sum()
+        top = np.argsort(-prob, kind="stable")[:k]
+        assert set(top) == set(routed[t])
+        for ex in top:
+            a = h @ w1[ex]
+            want[t] += prob[ex] / prob[top].sum() * ((a / (1 + np.exp(-a))) * (h @ w3[ex])) @ w2[ex]
+    # float32 sums of 24 to 32 products in another order than float64's
+    np.testing.assert_allclose(np.asarray(y).reshape(-1, d), want, rtol=2e-5, atol=2e-5)
+
+
+def test_gated_experts_need_the_dropless_path():
+    x = jnp.zeros((1, 4, 8), jnp.float32)
+    with pytest.raises(ValueError, match="dropless"):
+        MoEFFN(num_experts=4, d_ff=8, gated=True).init(jax.random.key(0), x)

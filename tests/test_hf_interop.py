@@ -218,3 +218,54 @@ def test_llama_greedy_decode_matches_transformers(hf_llama):
             pad_token_id=0,
         ).numpy()[:, 8:]
     np.testing.assert_array_equal(ours, hf)
+
+
+def test_keye_model_config_maps_the_published_keys():
+    """The language model of a KeyeVL2 config.json, read from its keys
+    alone: the fields TransformerLM needs for the layer (GQA with its
+    own head width, q/k norm, gated dropless experts, the indexer), and
+    a model built from them has the parameter tree the layer implies."""
+    import jax
+    import jax.numpy as jnp
+
+    from cs744_pytorch_distributed_tutorial_tpu.models import (
+        TransformerLM,
+        keye_model_config,
+    )
+
+    hf = {
+        "model_type": "KeyeVL2", "vocab_size": 96, "hidden_size": 32,
+        "num_hidden_layers": 2, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": 16,  # 4 x 16 != hidden 32
+        "moe_intermediate_size": 24, "intermediate_size": 96,
+        "num_experts": 8, "num_experts_per_tok": 2, "norm_topk_prob": True,
+        "mlp_only_layers": [], "decoder_sparse_step": 1,
+        "rms_norm_eps": 1e-6, "rope_theta": 10000000,
+        "max_position_embeddings": 4096, "tie_word_embeddings": False,
+        "attention_bias": False, "use_sliding_window": False,
+        "sa_config": {"indexer_head_dim": 8, "indexer_num_heads": 2,
+                      "indexer_num_kv_heads": 1, "topk": 16},
+    }
+    cfg = keye_model_config(hf, max_seq_len=64)
+    assert cfg["head_dim"] == 16 and cfg["num_kv_heads"] == 2 and cfg["qk_norm"]
+    assert cfg["d_ff"] == 24 and cfg["num_experts"] == 8 and cfg["moe_top_k"] == 2
+    assert cfg["mlp"] == "swiglu" and cfg["moe_dispatch"] == "dropless" and not cfg["moe_bias"]
+    assert (cfg["indexer_heads"], cfg["indexer_head_dim"], cfg["sparse_topk"]) == (2, 8, 16)
+    assert cfg["rope_base"] == 1e7 and cfg["use_rope"] and cfg["norm"] == "rmsnorm"
+    assert cfg["max_seq_len"] == 64 and keye_model_config(hf)["max_seq_len"] == 4096
+    model = TransformerLM(**cfg)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+    )["params"]
+    attn, moe = params["block_1"]["attn"], params["block_1"]["moe"]
+    assert attn["q"]["kernel"].shape == (32, 64) and attn["k"]["kernel"].shape == (32, 32)
+    assert attn["attn_out"]["kernel"].shape == (64, 32)
+    assert attn["q_norm"]["scale"].shape == (16,) and attn["idx_q"]["kernel"].shape == (32, 16)
+    assert attn["idx_k"]["kernel"].shape == (32, 8) and attn["idx_w"]["kernel"].shape == (32, 2)
+    assert {k: v.shape for k, v in moe.items() if k != "router"} == {
+        "w_in": (8, 32, 24), "w_gate": (8, 32, 24), "w_out": (8, 24, 32),
+    }
+    assert "pos_embed" not in params and "lm_head" in params
+    for key, bad in (("mlp_only_layers", [0]), ("norm_topk_prob", False), ("attention_bias", True)):
+        with pytest.raises(ValueError):
+            keye_model_config({**hf, key: bad})

@@ -21,6 +21,17 @@ that is the scan's, not the layout's, and stays an open question in
 question there too: (a) and (c) hold its data pools, (b) is the float
 pools' alone.
 
+A model with the sparse-attention indexer has a third pool a layer, the
+indexer's keys, and is served by chunks: its decode step and its one
+chunk program are held to (a) and (c) for all three pools, at 64-wide
+indexer keys (the width the benchmark's configuration has). The pool is
+``[num_pages, page_size, 128]``, the keys padded to a whole lane tile:
+at ``[.., 64]`` bf16 the compiler laid it out with ``num_pages``
+minor-most and each program copied it twice, the data pools' old fault.
+(b) is held against the K pool for the decode step and against one
+layer's three pools for the chunk, whose temporaries are its attention
+scores: they grow with the slot's capacity and not with the pool.
+
 The topology is described inside a fixture and every compile runs in
 the test's own process (one process loads libtpu at a time); where no
 topology can be described the tests skip and say why.
@@ -122,3 +133,55 @@ def test_no_program_copies_a_pool(compiled, quant, scan, program):
     # this size, lanes padded 12 -> 128), which this layout leaves be.
     if not quant:
         assert got.temp_bytes < got.pool_bytes, got
+
+
+@pytest.fixture(scope="module")
+def sparse_compiled(one_chip):
+    from cs744_pytorch_distributed_tutorial_tpu.models import keye_model_config
+
+    hf = dict(
+        vocab_size=512, num_hidden_layers=2, num_attention_heads=8,
+        num_key_value_heads=2, head_dim=128, hidden_size=256,
+        moe_intermediate_size=256, max_position_embeddings=512,
+        rope_theta=1e7, rms_norm_eps=1e-6, num_experts=8,
+        num_experts_per_tok=2, norm_topk_prob=True,
+        sa_config=dict(indexer_head_dim=64, indexer_num_heads=4,
+                       indexer_num_kv_heads=1, topk=128),
+    )
+    model = TransformerLM(
+        **keye_model_config(hf), dtype=jnp.bfloat16, flash_interpret=False,
+    )
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+    )["params"]
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16), params
+    )
+    engine = ServingEngine(
+        model, params,
+        ServeConfig(
+            num_slots=4, page_size=16, num_pages=513, max_pages_per_slot=32,
+            prefill_chunk=128,
+        ),
+    )
+    return engine, compile_programs(engine, 0, one_chip)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_no_program_copies_any_of_three_pools(sparse_compiled, program):
+    engine, programs = sparse_compiled
+    names = {
+        path[-1].key
+        for path, _ in jax.tree_util.tree_leaves_with_path(engine._pages)
+    }
+    assert names == {"key_pages", "value_pages", "index_key_pages"}
+    got = audit(programs[program], engine)
+    assert len(got.entry_layouts) == 3 * 2 and got.row_major, got.entry_layouts
+    assert got.pool_copies == []
+    k_pool_bytes = 513 * 16 * 2 * 128 * 2
+    assert got.pool_bytes < k_pool_bytes  # the smallest pool: the indexer's
+    # (b): the decode step's temporaries under one K pool; the chunk's
+    # (float32 scores of a KV head's queries over the view) under one
+    # layer's three pools
+    most = k_pool_bytes if program == "decode" else 2 * k_pool_bytes + got.pool_bytes
+    assert got.temp_bytes < most, got

@@ -306,3 +306,68 @@ def test_annotate_passes_fields_and_late_metadata(tmp_path):
             span.set_metadata(pages=2)
     (probe,) = _read_spans(tmp_path)
     assert (probe["req"], probe["kind"], probe["pages"]) == (7, "prefill", 2)
+
+
+def test_chunked_prefill_spans_and_counters(tmp_path):
+    """With ``ServeConfig.prefill_chunk`` set, every chunk program of an
+    admission (and its blocking fetch) is a ``serve/prefill_chunk`` span
+    inside that admission's ``serve/prefill``, in order, carrying the
+    request, the chunk's number, its offset and how many prompt tokens
+    it holds; ``stats()`` counts the same chunks, and for a model with
+    an indexer and experts the decode steps' device-side counters
+    (behind the step's tokens: one fetch, one compiled step)."""
+    model = TransformerLM(
+        vocab_size=VOCAB, num_layers=2, num_heads=2, d_model=32, d_ff=16,
+        max_seq_len=64, attention_impl="dense", use_rope=True,
+        norm="rmsnorm", mlp="swiglu", num_experts=4, moe_top_k=2,
+        moe_dispatch="dropless", moe_bias=False, qk_norm=True,
+        indexer_heads=2, indexer_head_dim=8, sparse_topk=8,
+    )
+    params = model.init(
+        jax.random.key(0), jnp.zeros((1, 4), jnp.int32)
+    )["params"]
+    eng = ServingEngine(model, params, ServeConfig(
+        num_slots=2, page_size=4, num_pages=33, max_pages_per_slot=12,
+        prefill_chunk=8,
+    ))
+    prompts = {0: 21, 1: 8, 2: 13}  # 3, 1 and 2 chunks of 8
+    rng = np.random.default_rng(5)
+
+    def submit_all():
+        return [
+            eng.submit(Request(
+                prompt=rng.integers(1, VOCAB, size=n).astype(np.int32),
+                max_new_tokens=4,
+            ))
+            for n in prompts.values()
+        ]
+
+    submit_all()
+    _drive(eng)  # warm-up: the one chunk program and the decode step
+    compiles = CompileCounter()
+    stats0 = eng.stats()
+    with profiling.trace(str(tmp_path)):
+        reqs = submit_all()
+        _drive(eng)
+    stats1 = eng.stats()
+    assert compiles.count == 0
+    spans = _read_spans(tmp_path)
+    admits = _named(spans, "serve/admit")
+    assert len(admits) == len(prompts)
+    for admit, req, n in zip(admits, reqs, prompts.values()):
+        (prefill,) = _children(spans, admit, "serve/prefill")
+        chunks = _children(spans, admit, "serve/prefill_chunk")
+        assert all(_inside(ch, prefill) for ch in chunks)
+        assert [ch["chunk"] for ch in chunks] == list(range(-(-n // 8)))
+        assert [ch["offset"] for ch in chunks] == [8 * i for i in range(len(chunks))]
+        assert sum(ch["len"] for ch in chunks) == n == admit["prompt_len"]
+        assert {ch["req"] for ch in chunks} == {req.req_id} and admit["bucket"] == 8
+    n_chunks = len(_named(spans, "serve/prefill_chunk"))
+    assert n_chunks == 6 == stats1["prefill_chunks"] - stats0["prefill_chunks"]
+    # the decode steps' counters: each of a request's 3 decode steps at
+    # depth L scores L + 1 tokens a layer and keeps min(L + 1, 8)
+    depths = [n + i for n in prompts.values() for i in range(3)]
+    assert stats1["scored_tokens"] - stats0["scored_tokens"] == 2 * sum(d + 1 for d in depths)
+    assert stats1["selected_tokens"] - stats0["selected_tokens"] == 2 * 8 * len(depths)
+    assert stats1["experts_hit"] > stats0["experts_hit"]
+    assert 1.0 <= stats1["expert_tokens_max_over_mean"] <= 4.0
