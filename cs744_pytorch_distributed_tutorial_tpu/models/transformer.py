@@ -639,8 +639,8 @@ class Attention(nn.Module):
                 ksp.value = ksp.value.at[slot_page, slot_off].set(ks[:, 0])
                 vsp.value = vsp.value.at[slot_page, slot_off].set(vs[:, 0])
                 if use_kernel:
-                    # Dequant happens INSIDE the kernel (per-key scales
-                    # ride the same clamped page index_map) — no gather
+                    # Dequant happens INSIDE the kernel (a page's
+                    # scales are fetched beside its rows) — no gather
                     # of any of the four pools.
                     paged_out = paged_attention(
                         q, kp.value, vp.value, page_table, decode_pos,

@@ -11,45 +11,72 @@ dense cache), but its HBM traffic per step scales with the slot's page
 CAPACITY ``P``, not with how many tokens are actually live. Decode is
 memory-bound, so that is exactly the wrong scaling.
 
-This kernel reads **only live pages**, straight out of the pool:
+This kernel reads **only live pages**, straight out of the pool, and
+scores **every head of a block of rows in one matmul pair**.
 
-- Grid ``(slot, page_block)`` with the page dimension fastest. The page
-  table and per-slot depths ride as **scalar-prefetched** operands
-  (``PrefetchScalarGridSpec``), so each grid step's BlockSpec index_map
-  picks its page from ``page_table[slot, i]`` — data-dependent DMA, no
-  gather, no dense intermediate.
-- One block is a whole page, all KV heads: ``(1, page_size, Hkv*D)``,
-  one unpadded DMA; the kernel loops the heads over lane slices.
-  Folded, because the TPU's default layout of a 4-D ``[.., Hkv, D]``
-  pool puts ``num_pages`` minor-most, and this kernel and every scatter
-  then had the whole pool converted to row-major and back, two
-  pool-sized copies a pool a program (``serve/layout.py``;
-  ``tests/test_serve_layout.py`` checks it with the compile-only
-  topology).
-- Dead iterations (``i >= ceil((pos+1)/page_size)``) CLAMP their
-  index_map to the slot's last live page. Pallas skips the re-fetch when
-  a block index repeats, so capacity-sized grids cost live-sized HBM
-  reads — and the reserved trash page 0 is never touched past a slot's
-  first block boundary.
-- Flash-style online softmax (running max / normalizer / accumulator in
-  f32 VMEM scratch, ``ops/flash_attention.py`` discipline); the last
-  live page masks its tail rows by position, dead iterations are skipped
-  by ``pl.when``, and the output block flushes once at the end of each
-  slot's pass.
+**The walk** (float pools whose folded rows are whole lane tiles,
+``Hkv*D % 128 == 0``: every pool the benchmark serves from):
+
+- Grid ``(slot,)``: one grid step a slot. The page table and the slots'
+  depths ride as **scalar-prefetched** operands; the pools stay in HBM
+  (``memory_space=pl.ANY``) and the kernel copies pages itself, one
+  ``pltpu.make_async_copy`` a page of K and of V, into double-buffered
+  VMEM blocks of N pages.
+- A ``lax.fori_loop`` runs to ``ceil(live_pages / N)``, a runtime bound
+  read from the depths: no iteration exists for a dead page. The next
+  block's copies are in flight while this block is computed, and under a
+  slot's last block rides the next slot's first, so only the first slot
+  of a call starts on an empty buffer (which buffer a slot starts in is
+  carried in SMEM; the grid runs in order).
+- The last block starts copies for its live pages only. Rows it did not
+  copy keep what the buffer held: their scores are masked by position,
+  and their V rows are zeroed, since ``p = 0`` times a stale NaN is NaN.
+  Page 0 (the engine's trash page) and every page past a slot's live
+  length are never read.
+- N is ``_BLOCK_TOKENS // page_size`` pages (256 tokens: 0.28 ms a call
+  at the serve cell's shape against 0.32 at 128 and 0.28 at 512, PERF.md
+  PR 28), never more than a slot holds, halved until both buffers of K
+  and V fit ``_VMEM_BUDGET``. The copies of a block are started and
+  awaited in runtime loops over its live pages, not unrolled: sixteen
+  pages unrolled behind ``pl.when`` traced and lowered three times
+  slower, twelve layers a program, and tripled the engine's start-up.
+
+**All heads in one matmul pair** (both fetch paths). ``q`` arrives
+block-diagonal, ``[Hq, Hkv*D]`` with row ``h`` non-zero only on its KV
+head's lanes (built by the entry point in XLA), so ``scores = q_bd x
+K_block^T`` is ``[Hq, tokens]`` for every head at once, the online
+softmax runs on that one tile, and ``acc [Hq, Hkv*D] += p x V_block``
+holds each head's output in its diagonal ``[group, D]`` block, picked
+once at the slot's end. The zeros add nothing; the off-diagonal blocks
+are never read. GQA is the same with ``group`` rows a KV head. A loop
+over heads would slice 64 lanes out of 128-lane tiles and feed the MXU
+one row at a time; this feeds it whole folded rows as they lie in the
+pool.
+
+**A page a grid step** (the int8 variant, and float pools whose folded
+row is not a lane multiple). Mosaic refuses a DMA of a slice whose lane
+extent is not a multiple of 128 — a ``[page_size, Hkv]`` page of the
+scale pools, a ``[page_size, 192]`` page of a three-head pool — so these
+pools are fetched by BlockSpec's own pipeline instead: grid ``(slot,
+page)``, a page a step through a scalar-prefetched index map, running
+statistics in VMEM scratch. Dead steps re-point at the slot's last live
+page; an unchanged block index skips the copy, so the bytes read follow
+live pages there too, and only the grid's steps follow capacity. The
+body is the same ``_attend`` on one page's rows.
 
 Three variants share this one entry point:
 
 - float (f32/bf16 pools): numerics follow ``decode_attention`` — f32
-  scores/softmax, PV matmul in the pool dtype.
+  scores/softmax, PV matmul in the pool dtype, f32 accumulation.
 - int8-KV (``key/value_scale_pages`` given): dequant happens INSIDE the
-  kernel (each K/V row times its scale before the dots — the same
+  kernel (each K/V row times its scale before the products — the same
   algebra as ``ops/quant.py::decode_attention_quant``, which scales the
-  scores and probabilities after them) — the scale pools ride the same
-  clamped index_map, replacing ``paged_decode_attention_quant``'s
-  four-pool gather.
+  scores and probabilities after them); a 0/1 ``[Hkv, Hkv*D]`` matmul
+  widens a page's ``[page_size, Hkv]`` scales over each head's lanes.
+  Replaces ``paged_decode_attention_quant``'s four-pool gather.
 - tensor-parallel: under ``shard_map`` the pools arrive sliced over KV
   heads (a contiguous lane range of the folded last dimension) and
-  ``q`` over query heads; the blocks derive from the LOCAL shapes, so
+  ``q`` over query heads; everything derives from the LOCAL shapes, so
   the kernel partitions over the head axis with no changes.
 
 Online softmax reassociates the reduction, so kernel-vs-reference parity
@@ -57,10 +84,8 @@ is tolerance-level (tests/test_paged_attention.py), not bitwise — the
 gather path remains the reference implementation and the engine's
 bitwise dense-parity story stays on it.
 
-``pages_per_slot`` statically prunes the page-table width and grid — the
-compiled ``cost_analysis`` bytes-read then scales with
-``ceil(live/page_size) * page_size`` instead of capacity, which is how
-CPU CI checks the byte count analytically.
+``pages_per_slot`` statically narrows the page table to its first N
+columns (the walk's capacity, the other path's grid).
 
 ``interpret=True`` runs the same kernel on any backend for tests.
 """
@@ -75,11 +100,166 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
+# A block of the walk holds this many tokens (module docstring).
+_BLOCK_TOKENS = 256
+# Both buffers of K and of V together stay under this much VMEM.
+_VMEM_BUDGET = 8 * 1024 * 1024
 
 
-def _decode_kernel(
+def _pages_per_block(capacity: int, page_size: int, row_bytes: int) -> int:
+    """Pages a block of the walk holds: ``_BLOCK_TOKENS`` tokens, never
+    more than a slot can hold, halved until the double-buffered K and V
+    blocks fit the budget."""
+    n = max(1, min(capacity, _BLOCK_TOKENS // page_size))
+    while n > 1 and 4 * n * page_size * row_bytes > _VMEM_BUDGET:
+        n //= 2
+    return n
+
+
+def _attend(q, k, v, first, pos, scale, carry):
+    """Fold one block of rows into the online softmax, every head at
+    once. ``q`` is block-diagonal ``[Hq, Hkv*D]`` (row ``h`` non-zero on
+    its KV head's lanes), ``k``/``v`` ``[tokens, Hkv*D]`` whole folded
+    rows starting at position ``first``. The accumulator is ``[Hq,
+    Hkv*D]``: row ``h``'s own head sits in its diagonal block, the other
+    lanes hold products with other heads' values and are never read."""
+    m_prev, l_prev, acc = carry
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale  # [Hq, tokens] f32
+    k_pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(k_pos <= pos, s, _NEG)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    correction = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_new = correction * l_prev + p.sum(axis=-1, keepdims=True)
+    acc = acc * correction + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return m_new, l_new, acc
+
+
+def _init_carry(hq: int, folded: int):
+    return (
+        jnp.full((hq, 1), _NEG, jnp.float32),
+        jnp.zeros((hq, 1), jnp.float32),
+        jnp.zeros((hq, folded), jnp.float32),
+    )
+
+
+def _write_heads(o_ref, wide_ref):
+    """Once a slot: pick each head's diagonal ``[group, D]`` block out of
+    the normalised ``[Hq, Hkv*D]`` accumulator."""
+    hkv, group, d = o_ref.shape[1:]
+    for h in range(hkv):
+        o_ref[0, h] = wide_ref[
+            h * group:(h + 1) * group, h * d:(h + 1) * d
+        ].astype(o_ref.dtype)
+
+
+def _walk_kernel(
     page_size: int,
-    num_blocks: int,
+    block_pages: int,
+    capacity: int,
+    scale: float,
+    lens_ref,
+    pt_ref,
+    q_ref,
+    k_hbm,
+    v_hbm,
+    o_ref,
+    kbuf,
+    vbuf,
+    wide_ref,
+    sem,
+    first_buf,
+):
+    hq, folded = q_ref.shape[1:]
+    tokens = block_pages * page_size
+    b = pl.program_id(0)
+    pos = lens_ref[b]
+
+    def live_pages(slot):
+        # Page i holds positions [i*page_size, (i+1)*page_size); the
+        # slot's current token sits at ``pos``, so pages 0..pos//page_size
+        # are live.
+        return jnp.minimum(lens_ref[slot] // page_size + 1, capacity)
+
+    def copies(slot, blk, buf, j):
+        page = pt_ref[slot, blk * block_pages + j]
+        return [
+            pltpu.make_async_copy(src.at[page], dst.at[buf, j], sem.at[buf])
+            for src, dst in ((k_hbm, kbuf), (v_hbm, vbuf))
+        ]
+
+    def live_in_block(slot, blk):
+        return jnp.clip(live_pages(slot) - blk * block_pages, 0, block_pages)
+
+    def start(slot, blk, buf):
+        n = live_in_block(slot, blk)
+
+        @pl.loop(0, n)
+        def _copy(j):
+            for c in copies(slot, blk, buf, j):
+                c.start()
+
+        # A page the walk does not copy keeps what the buffer held: its
+        # scores are masked by position, but p = 0 times a stale NaN
+        # would still poison the PV product, so its V rows go 0.
+        @pl.loop(n, block_pages)
+        def _zero(j):
+            vbuf[buf, j] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
+
+    def wait(slot, blk, buf):
+        @pl.loop(0, live_in_block(slot, blk))
+        def _wait(j):
+            for c in copies(slot, blk, buf, j):
+                c.wait()
+
+    num_blocks = (live_pages(b) + block_pages - 1) // block_pages
+
+    @pl.when(b == 0)
+    def _first_slot():
+        first_buf[0] = 0
+        start(0, 0, 0)
+
+    # Buffers alternate through the whole grid, not a slot: the buffer
+    # this slot's first block was copied into is carried in SMEM.
+    base = first_buf[0]
+
+    def body(blk, carry):
+        buf = (base + blk) % 2
+        more = blk + 1 < num_blocks
+
+        @pl.when(more)
+        def _next_block():
+            start(b, blk + 1, 1 - buf)
+
+        # Under the slot's last block rides the next slot's first, so
+        # no slot but the first starts on an empty buffer.
+        @pl.when(jnp.logical_and(~more, b + 1 < pl.num_programs(0)))
+        def _next_slot():
+            start(b + 1, 0, 1 - buf)
+
+        wait(b, blk, buf)
+        k = kbuf[buf].reshape(tokens, folded)
+        v = vbuf[buf].reshape(tokens, folded)
+        return _attend(q_ref[0], k, v, blk * tokens, pos, scale, carry)
+
+    # Position 0 is always visible (pos >= 0), so l > 0 — no NaN rows
+    # even for freshly-admitted or parked slots.
+    _, l, acc = jax.lax.fori_loop(
+        0, num_blocks, body, _init_carry(hq, folded)
+    )
+    first_buf[0] = (base + num_blocks) % 2
+    wide_ref[...] = acc / l
+    _write_heads(o_ref, wide_ref)
+
+
+def _page_step_kernel(
+    page_size: int,
+    capacity: int,
     scale: float,
     quant: bool,
     lens_ref,
@@ -93,57 +273,46 @@ def _decode_kernel(
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
+    hkv, _, d = o_ref.shape[1:]
+    hq, folded = q_ref.shape[1:]
     b = pl.program_id(0)
     i = pl.program_id(1)
     pos = lens_ref[b]
-    # Page i holds positions [i*page_size, (i+1)*page_size); the slot's
-    # current token sits at ``pos``, so pages 0..pos//page_size are live.
-    live = pos // page_size + 1
 
     @pl.when(i == 0)
     def _init():
-        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        m_ref[...], l_ref[...], acc_ref[...] = _init_carry(hq, folded)
 
-    @pl.when(i < live)
+    @pl.when(i <= pos // page_size)
     def _update():
-        d = q_ref.shape[-1]
-        for h in range(q_ref.shape[1]):  # static: the page's KV heads
-            q = q_ref[0, h]  # [group, D]
-            k = k_ref[0, :, h * d:(h + 1) * d]  # [page_size, D]
-            v = v_ref[0, :, h * d:(h + 1) * d]
-            if quant:
-                # Per-row dequant ahead of the dots: the [page_size, 1]
-                # scale column broadcasts along lanes, where scaling the
-                # [group, page_size] scores would need it transposed.
-                q = q.astype(jnp.float32)
-                k = k.astype(jnp.float32) * ks_ref[0, :, h:h + 1]
-                v = v.astype(jnp.float32) * vs_ref[0, :, h:h + 1]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [group, page_size] f32
-            k_pos = i * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1
-            )
-            s = jnp.where(k_pos <= pos, s, _NEG)
-            m_prev, l_prev = m_ref[h], l_ref[h]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            correction = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            m_ref[h] = m_new
-            l_ref[h] = correction * l_prev + p.sum(axis=-1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * correction + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        if quant:
+            # Per-row dequant ahead of the products. A 0/1 [Hkv, Hkv*D]
+            # matmul widens the [page_size, Hkv] scales over each head's
+            # lanes (exact: one non-zero term a lane).
+            lane = jax.lax.broadcasted_iota(jnp.int32, (hkv, folded), 1)
+            lo = jax.lax.broadcasted_iota(jnp.int32, (hkv, folded), 0) * d
+            widen = jnp.logical_and(lane >= lo, lane < lo + d)
 
-    # Position 0 is always visible (pos >= 0), so l > 0 — no NaN rows
-    # even for freshly-admitted or parked slots.
-    @pl.when(i == num_blocks - 1)
+            def dequant(rows, scales):
+                return rows.astype(jnp.float32) * jax.lax.dot_general(
+                    scales, widen.astype(jnp.float32),
+                    (((1,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32,
+                )
+
+            q = q.astype(jnp.float32)
+            k, v = dequant(k, ks_ref[0]), dequant(v, vs_ref[0])
+        m_ref[...], l_ref[...], acc_ref[...] = _attend(
+            q, k, v, i * page_size, pos, scale,
+            (m_ref[...], l_ref[...], acc_ref[...]),
+        )
+
+    @pl.when(i == capacity - 1)
     def _finalize():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        acc_ref[...] = acc_ref[...] / l_ref[...]
+        _write_heads(o_ref, acc_ref)
 
 
 def paged_attention(
@@ -169,10 +338,9 @@ def paged_attention(
     pools for the int8 variant, matching
     ``paged_decode_attention_quant``). ``Hq`` may be a multiple
     of ``Hkv`` (GQA). ``pages_per_slot`` statically narrows the page
-    table and grid to the first N pages — the capacity stays a runtime
-    fact for the engine's fixed-shape step (live length enters via the
-    grid mask, never the shape), while analytical byte-accounting tests
-    pin it to make the live-scaling visible to ``cost_analysis``.
+    table to its first N columns; the live length is a runtime fact (the
+    walk's trip count, the position mask), never a shape, so the engine's
+    fixed-shape step compiles once.
     """
     b, t, hq, d = q.shape
     if t != 1:
@@ -200,45 +368,70 @@ def paged_attention(
     pt = page_table
     if pages_per_slot is not None:
         pt = pt[:, :pages_per_slot]
-    num_blocks = pt.shape[1]
-    qg = q[:, 0].reshape(b, hkv, group, d)
-
-    def q_map(bi, i, lens, table):
-        return bi, 0, 0, 0
-
-    def live_page(bi, i, lens, table):
-        # Dead iterations re-point at the last live page: an unchanged
-        # block index skips the DMA, so capacity-wide grids read
-        # live-sized bytes (and never the trash page past block 0).
-        return table[bi, jnp.minimum(i, lens[bi] // page_size)]
-
-    def page_map(bi, i, lens, table):
-        return live_page(bi, i, lens, table), 0, 0
-
-    q_spec = pl.BlockSpec((1, hkv, group, d), q_map)
-    kv_spec = pl.BlockSpec((1, page_size, folded), page_map)
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [qg, key_pages, value_pages]
-    if quant:
-        sc_spec = pl.BlockSpec((1, page_size, hkv), page_map)
-        in_specs += [sc_spec, sc_spec]
-        operands += [key_scale_pages, value_scale_pages]
+    capacity = pt.shape[1]
+    # Block-diagonal q: row h keeps its D values on its KV head's lanes
+    # and zeros elsewhere, so one [Hq, Hkv*D] x [Hkv*D, tokens] product
+    # scores every head against whole folded rows.
+    own = (jnp.arange(hq) // group)[:, None] == (jnp.arange(folded) // d)[None]
+    q_bd = jnp.where(own, jnp.tile(q[:, 0], (1, 1, hkv)), 0)
     out_dtype = q.dtype if quant else value_pages.dtype
+    # q and the output move a slot a grid step on either path (the index
+    # maps also receive the second grid index, where there is one, and
+    # the two prefetched scalars).
+    q_spec = pl.BlockSpec((1, hq, folded), lambda bi, *_: (bi, 0, 0))
+    out_spec = pl.BlockSpec((1, hkv, group, d), lambda bi, *_: (bi, 0, 0, 0))
+    operands = [q_bd, key_pages, value_pages]
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, num_blocks),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, hkv, group, d), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((hkv, group, 1), jnp.float32),
-            pltpu.VMEM((hkv, group, 1), jnp.float32),
-            pltpu.VMEM((hkv, group, d), jnp.float32),
-        ],
-    )
+    if not quant and folded % 128 == 0:
+        block_pages = _pages_per_block(
+            capacity, page_size, folded * key_pages.dtype.itemsize
+        )
+        buffers = pltpu.VMEM(
+            (2, block_pages, page_size, folded), key_pages.dtype
+        )
+        in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+        grid = (b,)
+        in_specs = [q_spec, in_hbm, in_hbm]
+        scratch_shapes = [
+            buffers,
+            buffers,
+            pltpu.VMEM((hq, folded), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ]
+        kernel = partial(
+            _walk_kernel, page_size, block_pages, capacity, d**-0.5
+        )
+    else:
+        def live_page(bi, i, lens, table):
+            # Dead steps re-point at the last live page: an unchanged
+            # block index skips the DMA, so the capacity-wide grid reads
+            # live-sized bytes (and never the trash page past block 0).
+            return table[bi, jnp.minimum(i, lens[bi] // page_size)], 0, 0
+
+        kv_spec = pl.BlockSpec((1, page_size, folded), live_page)
+        grid = (b, capacity)
+        in_specs = [q_spec, kv_spec, kv_spec]
+        if quant:
+            in_specs += [pl.BlockSpec((1, page_size, hkv), live_page)] * 2
+            operands += [key_scale_pages, value_scale_pages]
+        scratch_shapes = [
+            pltpu.VMEM((hq, 1), jnp.float32),
+            pltpu.VMEM((hq, 1), jnp.float32),
+            pltpu.VMEM((hq, folded), jnp.float32),
+        ]
+        kernel = partial(
+            _page_step_kernel, page_size, capacity, d**-0.5, quant
+        )
     out = pl.pallas_call(
-        partial(_decode_kernel, page_size, num_blocks, d**-0.5, quant),
-        grid_spec=grid_spec,
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_spec,
+            scratch_shapes=scratch_shapes,
+        ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), out_dtype),
         interpret=interpret,
     )(pos.astype(jnp.int32), pt.astype(jnp.int32), *operands)

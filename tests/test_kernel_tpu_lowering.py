@@ -69,16 +69,18 @@ def _sgd(p, m, g):
     )
 
 
-def _paged_args(hq, hkv, d, pool_dtype, q_dtype):
+def _paged_args(
+    hq, hkv, d, pool_dtype, q_dtype, slots=8, pages_per_slot=8, num_pages=64
+):
     args = [
-        _s((8, 1, hq, d), q_dtype),
-        _s((64, 16, hkv * d), pool_dtype),  # pools fold the heads
-        _s((64, 16, hkv * d), pool_dtype),
-        _s((8, 8), i32),
-        _s((8,), i32),
+        _s((slots, 1, hq, d), q_dtype),
+        _s((num_pages, 16, hkv * d), pool_dtype),  # pools fold the heads
+        _s((num_pages, 16, hkv * d), pool_dtype),
+        _s((slots, pages_per_slot), i32),
+        _s((slots,), i32),
     ]
     if pool_dtype == i8:
-        args += [_s((64, 16, hkv), f32)] * 2
+        args += [_s((num_pages, 16, hkv), f32)] * 2
     return args
 
 
@@ -105,7 +107,12 @@ CASES = {
     "paged_bf16_12x64": (_paged, _paged_args(12, 12, 64, bf16, bf16)),
     "paged_int8_12x64": (_paged, _paged_args(12, 12, 64, i8, bf16)),
     "paged_f32_gqa_4x128": (_paged, _paged_args(32, 4, 128, f32, f32)),
-    # Hkv*D = 192: not a lane multiple, the block's last dim is the array's
+    # the serve cell's own geometry: 128 slots of 64 pages, 8193 in the pool
+    "paged_bf16_12x64_serve_cell": (
+        _paged, _paged_args(12, 12, 64, bf16, bf16, 128, 64, 8193)
+    ),
+    # Hkv*D = 192: not a lane multiple, so a page a grid step, the
+    # block's last dim the array's
     "paged_bf16_3x64": (_paged, _paged_args(3, 3, 64, bf16, bf16)),
     "paged_int8_gqa_4x128": (_paged, _paged_args(32, 4, 128, i8, f32)),
     "int8_matmul_lm_head": (

@@ -1,30 +1,30 @@
 """Pallas paged-attention decode kernel (ops/paged_attention.py).
 
-Four contracts, each against the gather+einsum reference that stays in
-``parallel/ring_attention.py`` / ``ops/quant.py``:
+Against the gather+einsum reference that stays in
+``parallel/ring_attention.py`` / ``ops/quant.py``, over both ways the
+kernel fetches pages (``PATHS``: the walk, for float pools whose folded
+rows are whole lane tiles; a page a grid step, for the rest):
 
 1. **Parity** — float (f32/bf16 pools) and int8-KV (dequant inside the
    kernel) match the reference within the flash tolerance discipline.
    Online softmax reassociates the reduction, so this is tolerance-level
    by design, not bitwise (the gather path keeps the bitwise story).
-2. **Live pages only** — pages past a slot's live length are NEVER read:
-   poisoning every dead page with NaN must not change the output. This
-   is the functional face of the clamped index_map (dead grid iterations
-   re-point at the last live page, so no new DMA issues).
-3. **Tensor-parallel** — under ``shard_map`` with pools sharded over KV
+2. **The walk's edges** — live pages of 1, N-1, N, N+1 and the whole
+   capacity in one batch, ``pos`` 0, a last page holding one row, a page
+   table with repeated and shuffled pages, a capacity that is not a
+   multiple of the block; and a slot's stale buffer rows never leak into
+   the next slot's answer.
+3. **Live pages only** — pages past a slot's live length are NEVER read:
+   poisoning every dead page with NaN must not change the output.
+4. **Tensor-parallel** — under ``shard_map`` with pools sharded over KV
    heads (and q over query heads), per-shard kernels reproduce the
-   unsharded answer: the grid derives from local shapes.
-4. **Bytes scale with live tokens** — compiled ``cost_analysis``
-   bytes-accessed for a decode step grows linearly with the live page
-   count and is EXACTLY invariant to page-table capacity, at two pool
-   geometries. The XLA CPU cost model counts operand shapes (the
-   interpret-mode grid loop is counted once), so the test compiles a
-   step whose operands ARE the live working set: pages allocated
-   contiguously from 1, pool statically sliced to the live pages,
-   ``pages_per_slot`` pruning the table — making "bytes ~ live, not
-   max_seq_len" visible analytically on CPU. The same CPU cost model is
-   why the un-sliced comparison still pins the gather reference's bytes
-   growing with capacity while the kernel's stay flat.
+   unsharded answer: everything derives from local shapes.
+5. **Copies follow live pages, not capacity** — the walk's trip count is
+   a runtime value, so XLA's ``cost_analysis`` of the interpreted kernel
+   says nothing about it; instead every ``make_async_copy(...).start()``
+   the walk executes is counted through a debug callback: two a live
+   page (K and V), the same at any page-table capacity, where the gather
+   reference's compiled bytes grow with the capacity.
 """
 
 from __future__ import annotations
@@ -36,6 +36,9 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from cs744_pytorch_distributed_tutorial_tpu.obs.phases import compiled_costs
+from cs744_pytorch_distributed_tutorial_tpu.ops import (
+    paged_attention as kernel_module,
+)
 from cs744_pytorch_distributed_tutorial_tpu.ops.paged_attention import (
     paged_attention,
 )
@@ -47,6 +50,9 @@ from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
 )
 
 B, HQ, HKV, D = 3, 4, 2, 16
+# (Hq, Hkv, D) a fetch path: folded rows of 32 lanes go a page a grid
+# step, of 128 lanes through the walk.
+PATHS = {"page_step": (4, 2, 16), "walk": (4, 2, 64)}
 
 
 def _pools(key, num_pages, page_size, dtype=jnp.float32, hkv=HKV, d=D):
@@ -113,16 +119,18 @@ def _layout(num_pages, page_size, ppr, seed=0):
     return table, pos
 
 
+@pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize(
     "dtype,tol",
     [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)],
     ids=["f32", "bf16"],
 )
-def test_kernel_matches_gather_reference(dtype, tol):
+def test_kernel_matches_gather_reference(dtype, tol, path):
+    hq, hkv, d = PATHS[path]
     page_size, ppr = 4, 4
-    kp, vp = _pools(jax.random.key(0), 17, page_size, dtype)
+    kp, vp = _pools(jax.random.key(0), 17, page_size, dtype, hkv, d)
     table, pos = _layout(17, page_size, ppr)
-    q = jax.random.normal(jax.random.key(1), (B, 1, HQ, D), jnp.float32)
+    q = jax.random.normal(jax.random.key(1), (B, 1, hq, d), jnp.float32)
     q = q.astype(dtype)
     expected = np.asarray(
         paged_decode_attention(q, kp, vp, table, pos), jnp.float32
@@ -211,19 +219,28 @@ def test_folded_pools_match_host_reference(hq, hkv, d, quant):
     np.testing.assert_array_equal(np.asarray(gathered), np.asarray(exact))
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
-def test_pages_per_slot_prunes_without_changing_live_slots(quant):
-    """``pages_per_slot`` narrows table and grid; slots whose live pages
-    all lie inside the pruned width read the same answer."""
+VARIANTS = pytest.mark.parametrize(
+    "quant,path",
+    [(False, "page_step"), (True, "page_step"), (False, "walk")],
+    ids=["float", "int8", "float-walk"],
+)
+
+
+@VARIANTS
+def test_pages_per_slot_prunes_without_changing_live_slots(quant, path):
+    """``pages_per_slot`` narrows the page table (and with it the walk's
+    capacity, or the grid); slots whose live pages all lie inside the
+    pruned width read the same answer."""
+    hq, hkv, d = PATHS[path]
     page_size, ppr, num_pages = 4, 4, 17
     table, _ = _layout(num_pages, page_size, ppr, seed=6)
     pos = jnp.asarray([0, 5, 2 * page_size - 1], jnp.int32)  # <= 2 pages
-    q = jax.random.normal(jax.random.key(22), (B, 1, HQ, D), jnp.float32)
+    q = jax.random.normal(jax.random.key(22), (B, 1, hq, d), jnp.float32)
     if quant:
         kp, vp, ksc, vsc = _int8_pools(jax.random.key(23), num_pages, page_size)
         sc = dict(key_scale_pages=ksc, value_scale_pages=vsc)
     else:
-        kp, vp = _pools(jax.random.key(23), num_pages, page_size)
+        kp, vp = _pools(jax.random.key(23), num_pages, page_size, hkv=hkv, d=d)
         sc = {}
     full = paged_attention(q, kp, vp, table, pos, interpret=True, **sc)
     pruned = paged_attention(
@@ -232,15 +249,18 @@ def test_pages_per_slot_prunes_without_changing_live_slots(quant):
     np.testing.assert_array_equal(np.asarray(pruned), np.asarray(full))
 
 
-def test_kernel_never_reads_dead_pages():
+@pytest.mark.parametrize("path", PATHS)
+def test_kernel_never_reads_dead_pages(path):
     """Poison every page past each slot's live length (and every
-    unreferenced pool page) with NaN: the output must stay finite and
-    EQUAL to the clean run — the clamped index_map means dead grid
-    iterations issue no new reads."""
+    unreferenced pool page, the trash page 0 among them) with NaN: the
+    output must stay finite and EQUAL to the clean run. The walk copies
+    a slot's live pages and no other; a page a grid step, dead steps
+    re-point at the last live page and issue no new read."""
+    hq, hkv, d = PATHS[path]
     page_size, ppr, num_pages = 4, 4, 33
-    kp, vp = _pools(jax.random.key(4), num_pages, page_size)
+    kp, vp = _pools(jax.random.key(4), num_pages, page_size, hkv=hkv, d=d)
     table, pos = _layout(num_pages, page_size, ppr, seed=2)
-    q = jax.random.normal(jax.random.key(5), (B, 1, HQ, D), jnp.float32)
+    q = jax.random.normal(jax.random.key(5), (B, 1, hq, d), jnp.float32)
     clean = np.asarray(paged_attention(q, kp, vp, table, pos, interpret=True))
 
     live = np.asarray(pos) // page_size + 1
@@ -263,21 +283,24 @@ def test_kernel_never_reads_dead_pages():
     np.testing.assert_array_equal(poisoned, clean)
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
-def test_kernel_tensor_parallel_matches_unsharded(quant):
+@VARIANTS
+def test_kernel_tensor_parallel_matches_unsharded(quant, path):
     """Pools sharded over KV heads (a contiguous lane range of the
     folded last dimension), q over query heads (the serving TP layout):
-    per-shard grids over the LOCAL Hkv reproduce the unsharded kernel —
-    no head-index plumbing needed."""
+    per-shard kernels over the LOCAL Hkv reproduce the unsharded kernel —
+    no head-index plumbing needed. The walk's case shards 256 lanes into
+    two of 128, so each shard walks too."""
     from cs744_pytorch_distributed_tutorial_tpu.parallel import make_mesh
 
+    hq, hkv, d = PATHS[path]
+    d *= 2 if path == "walk" else 1
     page_size, ppr, num_pages = 4, 4, 17
     table, pos = _layout(num_pages, page_size, ppr, seed=3)
-    q = jax.random.normal(jax.random.key(6), (B, 1, HQ, D), jnp.float32)
+    q = jax.random.normal(jax.random.key(6), (B, 1, hq, d), jnp.float32)
     if quant:
         kp, vp, *scales = _int8_pools(jax.random.key(7), num_pages, page_size)
     else:
-        kp, vp = _pools(jax.random.key(7), num_pages, page_size)
+        kp, vp = _pools(jax.random.key(7), num_pages, page_size, hkv=hkv, d=d)
         scales = ()
 
     def call(q, kp, vp, *scales):
@@ -301,76 +324,188 @@ def test_kernel_tensor_parallel_matches_unsharded(quant):
     np.testing.assert_allclose(got, expected, rtol=2e-5, atol=2e-5)
 
 
-# ------------------------------------------------ analytical bytes gate
+# ------------------------------------------------------ the walk's edges
+
+WALK_PAGE = 16
+WALK_BLOCK = kernel_module._BLOCK_TOKENS // WALK_PAGE  # pages a block
 
 
-def _kernel_step_bytes(live_pages, capacity, page_size):
-    """Compiled bytes-accessed for one decode step over a LIVE working
-    set: pages contiguous from 1, pool sliced to them, table pruned to
-    ``pages_per_slot=live_pages`` (module docstring on why the slice is
-    what makes live-scaling visible to the CPU cost model)."""
-    k_live = B * live_pages + 1  # + trash page 0
-    kp, vp = _pools(jax.random.key(8), k_live, page_size)
+def _walk_batch(capacity, dtype=jnp.float32):
+    """One batch over the walk's edges: live pages of 1 (``pos`` 0, and a
+    full first page), N-1, N, N+1 whose last page holds one row, and the
+    whole capacity; pages shuffled through the pool, one slot's table
+    repeating a page."""
+    hq, hkv, d = PATHS["walk"]
+    n, ps = WALK_BLOCK, WALK_PAGE
+    depths = [
+        0, ps - 1, (n - 1) * ps - 3, n * ps - 1, n * ps,
+        capacity * ps - 1, (capacity - 1) * ps,
+    ]
+    slots = len(depths)
+    num_pages = 1 + slots * capacity
+    rng = np.random.default_rng(7)
+    table = 1 + rng.permutation(num_pages - 1).reshape(slots, capacity)
+    table[2, 4] = table[2, 1]  # the same page at two places of a slot
+    kp, vp = _pools(jax.random.key(30), num_pages, ps, dtype, hkv, d)
+    q = jax.random.normal(jax.random.key(31), (slots, 1, hq, d), jnp.float32)
+    return (
+        q.astype(dtype), kp, vp, jnp.asarray(table, jnp.int32),
+        jnp.asarray(depths, jnp.int32),
+    )
+
+
+@pytest.mark.parametrize(
+    "capacity",
+    [2 * WALK_BLOCK, 2 * WALK_BLOCK + WALK_BLOCK // 2],
+    ids=["whole-blocks", "ragged-capacity"],
+)
+@pytest.mark.parametrize(
+    "dtype,tol",
+    [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)],
+    ids=["f32", "bf16"],
+)
+def test_walk_block_edges(dtype, tol, capacity):
+    q, kp, vp, table, pos = _walk_batch(capacity, dtype)
+    assert kernel_module._pages_per_block(
+        capacity, WALK_PAGE, kp.shape[-1] * kp.dtype.itemsize
+    ) == WALK_BLOCK
+    want = _dense_reference(q, kp, vp, table, pos)
+    got = paged_attention(q, kp, vp, table, pos, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), want, rtol=tol, atol=tol
+    )
+
+
+def test_walk_stale_buffer_rows_do_not_leak():
+    """A slot whose LIVE rows hold NaN answers NaN; the next slot, whose
+    last block copies fewer pages than the buffer holds, must not: the
+    rows it did not copy are masked in the scores and zeroed in V."""
+    q, kp, vp, table, pos = _walk_batch(2 * WALK_BLOCK)
+    full, short = 5, 2  # the whole capacity, then N-1 pages
+    order = jnp.asarray([full, short])
+    # the last page of either block: a row of either buffer that the
+    # short slot's one block does not copy over
+    bad = table[full, jnp.asarray([WALK_BLOCK - 1, 2 * WALK_BLOCK - 1])]
+    vp = vp.at[bad].set(jnp.nan)
+    got = np.asarray(
+        paged_attention(
+            q[order], kp, vp, table[order], pos[order], interpret=True
+        )
+    )
+    assert np.isnan(got[0]).any()
+    want = _dense_reference(
+        q[order][1:], kp, vp, table[order][1:], pos[order][1:]
+    )
+    np.testing.assert_allclose(got[1:], want, rtol=2e-5, atol=2e-5)
+
+
+# --------------------------------------- copies follow live pages, not capacity
+
+
+@pytest.fixture
+def page_copies(monkeypatch):
+    """Counts every ``make_async_copy(...).start()`` the kernel EXECUTES
+    (a debug callback beside the start, under the same ``pl.when`` and
+    inside the same runtime-bounded loop)."""
+    started = []
+    real = kernel_module.pltpu.make_async_copy
+
+    class Counted:
+        def __init__(self, copy):
+            self.copy = copy
+
+        def start(self):
+            jax.debug.callback(lambda: started.append(1))
+            self.copy.start()
+
+        def wait(self):
+            self.copy.wait()
+
+    monkeypatch.setattr(
+        kernel_module.pltpu, "make_async_copy",
+        lambda *a: Counted(real(*a)),
+    )
+
+    def count(*args, **kw):
+        del started[:]
+        out = paged_attention(*args, interpret=True, **kw)
+        jax.block_until_ready(out)
+        jax.effects_barrier()
+        return len(started), np.asarray(out)
+
+    return count
+
+
+def _contiguous(live_pages, capacity, page_size):
+    """Every slot ``live_pages`` deep, pages handed out in order from 1;
+    the table's dead entries point at the trash page 0."""
+    hq, hkv, d = PATHS["walk"]
+    num_pages = 1 + B * 8
+    kp, vp = _pools(jax.random.key(8), num_pages, page_size, hkv=hkv, d=d)
     table = np.zeros((B, capacity), np.int32)
     for b in range(B):
         table[b, :live_pages] = 1 + b * live_pages + np.arange(live_pages)
     pos = jnp.full((B,), live_pages * page_size - 1, jnp.int32)
-    q = jax.random.normal(jax.random.key(9), (B, 1, HQ, D), jnp.float32)
-
-    def step(q, kp, vp, table):
-        return paged_attention(
-            q, kp, vp, table, pos, interpret=True,
-            pages_per_slot=live_pages,
-        )
-
-    compiled = jax.jit(step).lower(q, kp, vp, jnp.asarray(table)).compile()
-    return compiled_costs(compiled)["bytes_accessed"]
+    q = jax.random.normal(jax.random.key(9), (B, 1, hq, d), jnp.float32)
+    return q, kp, vp, jnp.asarray(table), pos
 
 
 @pytest.mark.parametrize("page_size", [4, 8])
-def test_cost_bytes_scale_with_live_pages_not_capacity(page_size):
-    """The perf claim, gated analytically: bytes per decode step grow
-    LINEARLY in live pages (equal increments per extra page) and are
-    EXACTLY unchanged by page-table capacity — live tokens, not
-    max_seq_len, set the HBM traffic."""
-    b1, b2, b4 = (
-        _kernel_step_bytes(n, capacity=8, page_size=page_size)
-        for n in (1, 2, 4)
+def test_page_copies_follow_live_pages_not_capacity(page_size, page_copies):
+    """The perf claim, counted: a decode step copies two pages (K and V)
+    a live page, whatever the page table could hold — live tokens, not
+    max_seq_len, set the HBM traffic. The trash page every dead table
+    entry points at is poisoned, so a copy of it would show."""
+    outs = {}
+    for live, capacity in [(1, 8), (2, 8), (4, 8), (2, 32)]:
+        q, kp, vp, table, pos = _contiguous(live, capacity, page_size)
+        kp, vp = kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan)
+        n, outs[live, capacity] = page_copies(q, kp, vp, table, pos)
+        assert n == 2 * B * live, (live, capacity, n)
+        assert np.isfinite(outs[live, capacity]).all()
+    np.testing.assert_allclose(
+        outs[2, 32], outs[2, 8], rtol=2e-6, atol=2e-6
     )
-    assert b1 < b2 < b4
-    # linear: the marginal cost of one more live page is constant
-    step1, step2 = b2 - b1, (b4 - b2) / 2
-    assert abs(step2 - step1) <= 0.25 * step1, (b1, b2, b4)
-    # capacity invariance: a 4x wider table moves nothing
-    assert b2 == _kernel_step_bytes(2, capacity=32, page_size=page_size)
 
 
-def test_cost_bytes_kernel_flat_where_gather_grows():
+def test_kernel_copies_flat_where_gather_grows(page_copies):
     """Same pools, same live length, growing capacity: the gather
     reference's compiled bytes grow with the table width (it always
-    materializes the dense [B, P*page_size] view); the kernel's do not."""
+    materializes the dense [B, P*page_size] view); the copies the kernel
+    issues do not, and every page past the live two is poisoned."""
+    hq, hkv, d = PATHS["walk"]
     page_size, num_pages = 4, 129
-    kp, vp = _pools(jax.random.key(10), num_pages, page_size)
-    q = jax.random.normal(jax.random.key(11), (B, 1, HQ, D), jnp.float32)
+    kp, vp = _pools(jax.random.key(10), num_pages, page_size, hkv=hkv, d=d)
+    q = jax.random.normal(jax.random.key(11), (B, 1, hq, d), jnp.float32)
     pos = jnp.full((B,), 2 * page_size - 1, jnp.int32)  # 2 live pages
 
-    def bytes_of(fn, capacity):
+    def table_of(capacity):
         table = np.zeros((B, capacity), np.int32)
         for b in range(B):
             table[b, :capacity] = 1 + b * capacity + np.arange(capacity)
-        lowered = jax.jit(fn).lower(q, kp, vp, jnp.asarray(table))
+        return table
+
+    def gather_bytes(capacity):
+        lowered = jax.jit(
+            lambda q, kp, vp, table: paged_decode_attention(
+                q, kp, vp, table, pos
+            )
+        ).lower(q, kp, vp, jnp.asarray(table_of(capacity)))
         return compiled_costs(lowered.compile())["bytes_accessed"]
 
-    def kernel(q, kp, vp, table):
-        return paged_attention(q, kp, vp, table, pos, interpret=True)
+    def kernel_copies(capacity):
+        table = table_of(capacity)
+        dead = np.setdiff1d(np.arange(num_pages), table[:, :2])
+        n, out = page_copies(
+            q, kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan),
+            jnp.asarray(table), pos,
+        )
+        assert np.isfinite(out).all()
+        return n
 
-    def gather(q, kp, vp, table):
-        return paged_decode_attention(q, kp, vp, table, pos)
-
-    g8, g32 = bytes_of(gather, 8), bytes_of(gather, 32)
-    k8, k32 = bytes_of(kernel, 8), bytes_of(kernel, 32)
+    g8, g32 = gather_bytes(8), gather_bytes(32)
     assert g32 > 1.5 * g8, (g8, g32)
-    assert k8 == k32, (k8, k32)
+    assert kernel_copies(8) == kernel_copies(32) == 2 * B * 2
 
 
 def test_validation():

@@ -165,11 +165,12 @@ def _paged_inputs(hq, hkv, d, dtype):
     return q, k, v, table, pos
 
 
-@pytest.mark.parametrize("hq,hkv,d", [(12, 12, 64), (32, 4, 128)])
+@pytest.mark.parametrize("hq,hkv,d", [(12, 12, 64), (32, 4, 128), (3, 3, 64)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_float(hq, hkv, d, dtype):
-    """GPT-2-small serving heads (12 x 64) and a GQA shape (32/4 x 128),
-    page 16, slots at mixed depths."""
+    """GPT-2-small serving heads (12 x 64) and a GQA shape (32/4 x 128)
+    through the walk, 3 x 64 (192 lanes: a page a grid step); page 16,
+    slots at mixed depths."""
     q, k, v, table, pos = _paged_inputs(hq, hkv, d, dtype)
     got = jax.jit(
         lambda *a: paged_attention(*a, interpret=False)
@@ -202,6 +203,30 @@ def test_paged_attention_int8_kv(hq, hkv, d, qdtype):
             q.astype(jnp.float32), kq, vq, ks, vs, table, pos
         )
     assert got.dtype == qdtype
+    assert_close(got, want, 3e-2)
+
+
+def test_paged_attention_serve_cell_shape():
+    """The serve cell's own call: 128 slots of up to 64 pages of 16 in a
+    pool of 8193, bf16, 12 x 64; depths from a fresh slot to a full one,
+    block edges of the walk (8 pages) among them."""
+    slots, capacity, num_pages = 128, 64, 8193
+    q = _normal(0, (slots, 1, 12, 64), jnp.bfloat16)
+    k = _normal(1, (num_pages, PAGE, 768), jnp.bfloat16)
+    v = _normal(2, (num_pages, PAGE, 768), jnp.bfloat16)
+    rng = np.random.default_rng(1)
+    table = 1 + rng.permutation(num_pages - 1)[: slots * capacity]
+    table = jnp.asarray(table.reshape(slots, capacity), jnp.int32)
+    pos = rng.integers(0, capacity * PAGE, slots)
+    pos[:8] = [0, 15, 111, 127, 128, 143, 1007, 1023]
+    pos = jnp.asarray(pos, jnp.int32)
+    got = jax.jit(
+        lambda *a: paged_attention(*a, interpret=False)
+    )(q, k, v, table, pos)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(paged_decode_attention)(
+            *(x.astype(jnp.float32) for x in (q, k, v)), table, pos
+        )
     assert_close(got, want, 3e-2)
 
 
