@@ -3,7 +3,7 @@
     python benchmarks/metrics_summary.py /tmp/run/metrics.jsonl
 
 Reads the stream the engines write with ``--metrics-dir`` (or a file
-``bench.py --metrics-dir`` appended to), filters the ``kind == "step"``
+``serve_cli --metrics-dir`` appended to), filters the ``kind == "step"``
 records, and prints a one-screen summary: steps covered, mean step time
 (first emission excluded — it amortizes compile), final/best loss, mean
 MFU where recorded, and total gradient bytes on the wire. Stdlib only —
@@ -115,7 +115,7 @@ def summarize(records: list[dict[str, Any]]) -> dict[str, Any]:
         tb = r.get("traceback")
         if isinstance(tb, str) and tb.strip():
             row["traceback_tail"] = tb.strip().splitlines()[-1].strip()
-    # graftscope per-phase records (bench.py --phase-breakdown) plus the
+    # per-phase records (no producer since ROADMAP D4b) plus the
     # serve-side kind:"serve_phase" twins (serve_cli --trace-dir): one
     # row per phase, keyed by name, latest record wins on repeat runs.
     phases: dict[str, dict[str, Any]] = {}
@@ -141,7 +141,7 @@ def summarize(records: list[dict[str, Any]]) -> dict[str, Any]:
         if r.get("kind") == "phase_summary"
         and isinstance(r.get("sync_exposed_ms"), (int, float))
     ]
-    # Fused-vs-overlapped sync comparison rows (bench.py --sync-compare):
+    # Fused-vs-overlapped sync comparison rows (no producer, ROADMAP D4b):
     # one row per wire format, latest record wins on repeat runs.
     sync_compare: dict[str, dict[str, Any]] = {}
     for r in records:

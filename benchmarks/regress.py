@@ -2,7 +2,7 @@
 
 Throughput history comes as bench envelopes (``BENCH_r*.json``, each
 holding a run's parsed headline record) or as ``kind="bench"`` records
-on telemetry JSONL streams (``bench.py --metrics-dir``). No envelope is
+on telemetry JSONL streams (``serve_cli --metrics-dir``). No envelope is
 checked in on this installation, so ``--baseline`` must name the
 history to compare against; without one the gate exits 2. This gate
 reads EITHER format on either side, takes the **median of the last ``--window`` baseline
@@ -12,10 +12,10 @@ it. When BOTH sides carry graftscope ``phase_summary`` records, the
 ``sync_exposed_ms`` metric is gated too (higher-is-worse, its own
 tolerance) — so a sync-overlap win (ROADMAP item 2), once landed,
 cannot silently regress. Independently, any baseline record carrying
-``sync_exposed_budget_ms`` (the checked-in
-``benchmarks/perf_smoke_budget.json`` envelope) arms an ABSOLUTE
-ceiling on the current stream's sync_exposed_ms — the on-by-default CI
-gate for the overlapped bucket schedule (``--sync-overlap``).
+``sync_exposed_budget_ms`` arms an ABSOLUTE ceiling on the current
+stream's sync_exposed_ms. Nothing in the tree emits ``phase_summary``
+records since the segmented profiler went (ROADMAP D4b): that half is
+held by ``tests/test_regress.py`` alone.
 
 Exit codes: 0 pass, 1 regression, 2 missing/unusable data (a gate that
 can't find its numbers must fail loudly, not pass vacuously).
@@ -125,10 +125,9 @@ def generic_budgets(records: list[dict[str, Any]]) -> list[dict[str, Any]]:
 def sync_exposed_budget(records: list[dict[str, Any]]) -> float | None:
     """Absolute sync_exposed_ms ceiling carried by the baseline side.
 
-    A checked-in budget envelope (``benchmarks/perf_smoke_budget.json``)
-    carries ``sync_exposed_budget_ms``; its presence among the baseline
-    records ARMS the budget gate — no extra CLI flag needed, so the CI
-    perf-smoke job gates sync_exposed_ms by default. Last value wins."""
+    A budget envelope carries ``sync_exposed_budget_ms``; its presence
+    among the baseline records ARMS the budget gate — no extra CLI flag
+    needed. Last value wins."""
     budget = None
     for r in records:
         if isinstance(r.get("sync_exposed_budget_ms"), (int, float)):

@@ -43,9 +43,13 @@ import numpy as np
 COLLECTIVE_CLASS = {
     "psum": "psum",
     "psum2": "psum",  # legacy shard_map's check_rep rewrite of psum
+    # jax 0.9.0, check_vma=True: lax.psum/pmean of a varying value, and
+    # the transpose of pvary (every replicated parameter's gradient)
+    "psum_invariant": "psum",
     "pmax": "pmax",
     "pmin": "pmin",
     "all_gather": "all_gather",
+    "all_gather_invariant": "all_gather",  # jax 0.9.0: varying -> invariant
     "reduce_scatter": "reduce_scatter",  # what lax.psum_scatter binds
     "psum_scatter": "reduce_scatter",
     "ppermute": "ppermute",

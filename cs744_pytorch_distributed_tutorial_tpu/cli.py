@@ -91,7 +91,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="use the Pallas fused SGD kernel (ops/fused_sgd.py)")
     p.add_argument("--fast-conv", action="store_true", default=None,
                    help="Pallas wgrad backward for wide ResNet 3x3 convs "
-                        "(off by default; see benchmarks/ablate.py)")
+                        "(off by default; see docs/kernels.md)")
     p.add_argument("--no-augment", action="store_false", dest="augment",
                    default=None,
                    help="disable train-time crop/flip (deterministic inputs)")

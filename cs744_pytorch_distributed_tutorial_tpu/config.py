@@ -135,7 +135,7 @@ class TrainConfig:
     # kernel (ops/fused_conv.py). Off by default: in-graph measurement
     # on the v5e showed XLA's batch-minor activation layouts force
     # relayout copies around the custom call that outweigh the kernel's
-    # isolated win (see benchmarks/ablate.py round-2 notes); the flag
+    # isolated win (docs/kernels.md, layout lessons); the flag
     # exists for shapes/layouts where the kernel wins and for tests.
     fast_conv: bool = False
 

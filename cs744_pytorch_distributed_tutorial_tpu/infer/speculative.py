@@ -2,7 +2,7 @@
 
 No counterpart exists in the reference (it never runs inference beyond
 a float eval loop, ``master/part1/part1.py:47-62``). Motivation from
-this repo's own measurements (``benchmarks/bench_generate.py``): small-
+this repo's own measurements (``docs/kernels.md``, decode rows): small-
 model decode is OP-LATENCY-bound — the serial one-token-at-a-time chain,
 not bandwidth or FLOPs, sets the wall-clock. Speculative decoding
 converts up to ``k`` serial target steps into ONE chunked verification

@@ -15,7 +15,7 @@ Design, TPU-first:
   scatter-add/gather default — measured on a v5e, scatter at one
   global group beats the einsum path's best grouped setting while
   keeping the ungrouped near-zero drop rate (einsum at the same drop
-  rate is 2.9x slower; benchmarks/bench_vit_moe.py).
+  rate is 2.9x slower; docs/kernels.md).
 - **Expert parallelism is one ``lax.all_to_all`` pair.** With experts
   sharded over a mesh axis (here: the ``data`` axis — the standard
   "EP over DP" layout), each device dispatches its local tokens into
@@ -73,7 +73,7 @@ class MoEFFN(nn.Module):
     # costs O(N * E * C * D) with C ~ k*N_group*cf/E, so G groups cut it
     # G-fold — at N=16k tokens/device the ungrouped formulation measured
     # 4.8x slower than a FLOPs-matched dense FFN
-    # (benchmarks/bench_vit_moe.py). Capacity (and hence drop decisions)
+    # (docs/kernels.md). Capacity (and hence drop decisions)
     # becomes per-group — num_groups is part of the routing semantics,
     # not just a performance knob. 0 = auto: target ~1024 tokens/group.
     num_groups: int = 1

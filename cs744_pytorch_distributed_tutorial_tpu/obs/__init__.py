@@ -25,15 +25,6 @@ from .fleet import (
     write_fleet_artifacts,
 )
 from .flight import FlightRecorder, HbmHighWater, StragglerMonitor
-from .phases import (
-    PhaseReport,
-    PhaseStat,
-    capture_device_profile,
-    phase_records_from_stream,
-    profile_lm_phases,
-    profile_phases,
-    render_phase_table,
-)
 from .run_manifest import build_manifest, read_manifest, write_manifest
 from .serve_trace import (
     ServeTracer,
@@ -73,13 +64,6 @@ __all__ = [
     "FlightRecorder",
     "HbmHighWater",
     "StragglerMonitor",
-    "PhaseReport",
-    "PhaseStat",
-    "capture_device_profile",
-    "phase_records_from_stream",
-    "profile_lm_phases",
-    "profile_phases",
-    "render_phase_table",
     "build_manifest",
     "read_manifest",
     "write_manifest",

@@ -1,16 +1,9 @@
 """Render telemetry artifacts from the command line.
 
-    python -m cs744_pytorch_distributed_tutorial_tpu.obs report <metrics_dir>
     python -m cs744_pytorch_distributed_tutorial_tpu.obs serve-report \\
         <trace_dir> [--check]
     python -m cs744_pytorch_distributed_tutorial_tpu.obs fleet-report \\
         <store_dir> [--check] [--no-artifacts]
-
-``report`` reads a metrics dir (or a metrics.jsonl / phase_report.json
-directly), filters the graftscope ``kind="phase"``/``"phase_summary"``
-records, and prints the per-phase attribution table — same renderer
-``bench.py --phase-breakdown`` prints live, usable after the fact on
-any machine the JSONL landed on.
 
 ``serve-report`` summarizes a graftserve trace dir (``serve_cli.py
 --trace-dir``: span/window/request JSONL + the Perfetto trace);
@@ -33,48 +26,7 @@ gate.
 from __future__ import annotations
 
 import argparse
-import json
-import os
 import sys
-
-from .metrics import METRICS_NAME
-from .phases import phase_records_from_stream, render_phase_table
-
-
-def _load_stream(path: str) -> list[dict]:
-    """metrics dir, JSONL stream, or a phase_report.json array."""
-    if os.path.isdir(path):
-        for name in (METRICS_NAME, "phase_report.json"):
-            candidate = os.path.join(path, name)
-            if os.path.exists(candidate):
-                path = candidate
-                break
-        else:
-            raise FileNotFoundError(
-                f"{path}: no {METRICS_NAME} or phase_report.json"
-            )
-    with open(path, encoding="utf-8") as f:
-        text = f.read()
-    try:
-        obj = json.loads(text)
-        if isinstance(obj, list):
-            return [r for r in obj if isinstance(r, dict)]
-        if isinstance(obj, dict):
-            return [obj]
-    except json.JSONDecodeError:
-        pass
-    records = []
-    for line in text.splitlines():
-        line = line.strip()
-        if not line:
-            continue
-        try:
-            rec = json.loads(line)
-        except json.JSONDecodeError:
-            continue
-        if isinstance(rec, dict):
-            records.append(rec)
-    return records
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -83,10 +35,6 @@ def main(argv: list[str] | None = None) -> int:
         description=__doc__,
     )
     sub = p.add_subparsers(dest="cmd", required=True)
-    rep = sub.add_parser("report", help="render phase records as a table")
-    rep.add_argument(
-        "path", help="metrics dir, metrics.jsonl, or phase_report.json"
-    )
     srv = sub.add_parser(
         "serve-report", help="summarize a graftserve trace dir"
     )
@@ -153,35 +101,26 @@ def main(argv: list[str] | None = None) -> int:
             print("fleet check: OK")
         return 0
 
-    if args.cmd == "serve-report":
-        from .serve_trace import (
-            check_spans,
-            load_trace_dir,
-            reconcile,
-            render_serve_report,
+    from .serve_trace import (
+        check_spans,
+        load_trace_dir,
+        reconcile,
+        render_serve_report,
+    )
+
+    data = load_trace_dir(args.path)
+    print(render_serve_report(data))
+    if args.check:
+        problems = check_spans(data["spans"])
+        problems += reconcile(data["spans"], data["requests"])
+        if problems:
+            for prob in problems:
+                print(f"serve-trace check: {prob}", file=sys.stderr)
+            return 1
+        print(
+            f"serve-trace check: OK ({len(data['spans'])} spans, "
+            f"{len(data['requests'])} requests)"
         )
-
-        data = load_trace_dir(args.path)
-        print(render_serve_report(data))
-        if args.check:
-            problems = check_spans(data["spans"])
-            problems += reconcile(data["spans"], data["requests"])
-            if problems:
-                for prob in problems:
-                    print(f"serve-trace check: {prob}", file=sys.stderr)
-                return 1
-            print(
-                f"serve-trace check: OK ({len(data['spans'])} spans, "
-                f"{len(data['requests'])} requests)"
-            )
-        return 0
-
-    records = phase_records_from_stream(_load_stream(args.path))
-    if not records:
-        print("no phase records found (run bench.py --phase-breakdown "
-              "with --metrics-dir first)", file=sys.stderr)
-        return 1
-    print(render_phase_table(records))
     return 0
 
 

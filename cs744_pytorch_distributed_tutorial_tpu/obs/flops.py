@@ -1,7 +1,7 @@
 """Analytic FLOPs models and MFU accounting.
 
-Training telemetry and ``bench.py`` share this ONE definition of model
-FLOPs and peak throughput.
+Training telemetry's ONE definition of model FLOPs and peak throughput
+(the benchmark counts its own work, ``perfbench/work.py``).
 
 Conventions (the standard MFU accounting):
 - FLOPs = 2 * MACs.
@@ -20,6 +20,8 @@ __all__ = [
     "V5E_PEAK_FLOPS",
     "peak_flops_per_chip",
     "peak_hbm_bytes_per_sec",
+    "DEFAULT_RIDGE_FLOPS_PER_BYTE",
+    "roofline_classify",
     "resnet18_cifar_train_flops_per_sample",
     "transformer_train_flops_per_token",
     "mfu",
@@ -43,7 +45,7 @@ _PEAKS: tuple[tuple[str, float], ...] = (
 
 # Peak HBM bandwidth (bytes/sec) per chip, same matching discipline.
 # Pairs with _PEAKS to give each chip's roofline ridge point
-# (peak_flops / peak_hbm_bw) for graftscope's phase classification.
+# (peak_flops / peak_hbm_bw) for roofline_classify.
 _HBM_PEAKS: tuple[tuple[str, float], ...] = (
     ("v5 lite", 819e9),
     ("v5e", 819e9),
@@ -74,6 +76,29 @@ def peak_hbm_bytes_per_sec(device_kind: str) -> float | None:
         if sub in kind:
             return peak
     return None
+
+
+# Ridge point (flops/byte) used by roofline_classify when the device
+# kind has no known peak pair: v5e's 197e12 / 819e9 ~= 240.
+DEFAULT_RIDGE_FLOPS_PER_BYTE = 240.0
+
+
+def roofline_classify(
+    flops: float | None,
+    bytes_accessed: float | None,
+    device_kind: str | None,
+) -> str:
+    """'compute' | 'memory' | 'unknown': arithmetic intensity against the
+    chip's ridge point (peak_flops / peak_hbm_bw) when both peaks are
+    known, else the documented v5e default ridge."""
+    if not flops or not bytes_accessed:
+        return "unknown"
+    peak_f = peak_flops_per_chip(device_kind or "")
+    peak_b = peak_hbm_bytes_per_sec(device_kind or "")
+    ridge = (
+        peak_f / peak_b if (peak_f and peak_b) else DEFAULT_RIDGE_FLOPS_PER_BYTE
+    )
+    return "compute" if flops / bytes_accessed >= ridge else "memory"
 
 
 def resnet18_cifar_train_flops_per_sample() -> float:

@@ -23,13 +23,12 @@ syncs — the post-warmup 0-retrace contract holds with tracing on):
   not only from the post-hoc ``serve_summary``. The ITL reservoir is
   fed from the same surfaced-token gaps ``loadgen._summarize`` diffs,
   so windowed and post-hoc percentiles agree on a drained run.
-- **Serve-side graftscope** (:func:`profile_serve_programs`): device
-  time (``capture_device_profile``), compiled ``cost_analysis``
-  flops/bytes, and roofline class for the decode step and every warmed
-  prefill bucket, plus ``decode_host_exposed_ms`` — the serving analog
-  of ``sync_exposed_ms``: mean live host wall per decode step minus the
-  profiled program time, i.e. what the host scheduler costs the decode
-  loop.
+- **Program profile** (:func:`profile_serve_programs`): device time
+  (``utils.profiling.capture_device_profile``), compiled
+  ``cost_analysis`` flops/bytes, and roofline class for the decode step
+  and every warmed prefill bucket, plus ``decode_host_exposed_ms``:
+  mean live host wall per decode step minus the profiled program time,
+  i.e. what the host scheduler costs the decode loop.
 
 Spans survive LIFO preemption (``decode_run`` closes, a new ``queue``
 span opens at the preempt instant) and kill/resume replay (the fresh
@@ -718,7 +717,7 @@ def render_serve_report(data: dict[str, list[dict[str, Any]]]) -> str:
 
 
 # ---------------------------------------------------------------------------
-# Serve-side graftscope: device time + cost analysis for the programs
+# Program profile: device time + cost analysis for the programs
 # ---------------------------------------------------------------------------
 
 
@@ -739,17 +738,13 @@ def profile_serve_programs(
     one ``kind:"serve_phase_summary"`` carrying
     ``decode_host_exposed_ms``: mean host wall per LIVE decode step
     (engine-recorded) minus the profiled program time — the host
-    scheduling overhead a decode token actually pays, the serving
-    analog of graftscope's ``sync_exposed_ms``.
+    scheduling overhead a decode token actually pays.
     """
     import jax
     import jax.numpy as jnp
 
-    from .phases import (
-        capture_device_profile,
-        compiled_costs,
-        roofline_classify,
-    )
+    from ..utils.profiling import capture_device_profile, compiled_costs
+    from .flops import roofline_classify
 
     cfg = engine.cfg
     b, p = cfg.num_slots, cfg.max_pages_per_slot

@@ -13,9 +13,9 @@ of concrete sinks behind it:
 - :class:`RingSink`: bounded in-memory deque — the tail the watchdog
   flushes when a step wedges, and what tests assert against.
 - :class:`MultiSink` / :class:`NullSink` / :class:`StreamSink`:
-  fan-out, no-op, and write-to-stream (``bench.py`` uses the stream
-  sink to keep printing its one-line JSON to stdout through the same
-  schema path as training telemetry).
+  fan-out, no-op, and write-to-stream (``serve_cli`` uses the stream
+  sink to print its records to stdout through the same schema path
+  as training telemetry).
 
 ``rank_zero(sink)`` wraps any sink so only process 0 writes on
 multihost — every process computes the same replicated scalars, so
@@ -197,8 +197,8 @@ class NullSink:
 class StreamSink:
     """One JSON line per record to an arbitrary text stream.
 
-    ``bench.py`` routes its stdout JSON through this so benchmark
-    output and training telemetry share one serialization path (same
+    ``serve_cli`` routes its stdout JSON through this so its output
+    and training telemetry share one serialization path (same
     sanitization, same schema fields).
     """
 

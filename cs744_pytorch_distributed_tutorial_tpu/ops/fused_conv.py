@@ -2,7 +2,7 @@
 step's hot spot.
 
 Profiling the ResNet-18/CIFAR training step on the TPU (see
-``benchmarks/ablate.py``) shows the conv *weight gradients* are where
+``docs/kernels.md``) shows the conv *weight gradients* are where
 XLA leaves the most on the table: the stage-1 wgrads run at ~55 TF/s
 (``EmitAllBatchInSublanes`` emitter) while the same chip does ~190 TF/s
 on the forward convs of deeper stages. The reference hits the analogous

@@ -339,7 +339,7 @@ def paged_decode_attention_quant(
 QUANT_MODULES = frozenset(
     {"q", "k", "v", "attn_out", "mlp_in", "mlp_gate", "mlp_out", "lm_head"}
 )
-# Measured default (one v5e, bench_generate shapes): every Pallas call
+# Measured default (one v5e, docs/kernels.md's decode rows): every Pallas call
 # in the decode step carries a fixed dispatch cost, so quantizing the
 # small per-layer projections LOSES to XLA while the wide head matmul —
 # most of the weight bytes at LM vocab sizes — wins. "head" quantizes

@@ -314,8 +314,8 @@ def sync_grads(
     """
     fn = get_sync(name)
     # named_scope: pure HLO metadata (zero jaxpr eqns, graftcheck-TA003
-    # invisible) that labels the collective rows in Perfetto captures —
-    # graftscope's phase attribution relies on these names.
+    # invisible) that labels the collective rows in Perfetto captures
+    # (docs/observability.md has the label table).
     if bucket_bytes and name in _BUCKETED and axis_size > 1:
         with jax.named_scope(f"graftscope/sync/{name}/bucketed"):
             rows = axis_size if name == "ring" else 0

@@ -728,9 +728,9 @@ class Trainer:
             out_specs=(state_specs, metric_specs),
             check_vma=self._check_vma,
         )
-        # Un-jitted, un-donated handle for instrumentation (graftscope's
-        # parity/timing path re-jits WITHOUT donation so repeated calls
-        # on the same state don't hit deleted buffers).
+        # Un-jitted, un-donated handle for instrumentation: re-jit it
+        # WITHOUT donation and repeated calls on the same state don't
+        # hit deleted buffers.
         self.mapped_train = mapped_train
         self.train_step = jax.jit(mapped_train, donate_argnums=0)
 
@@ -1476,26 +1476,23 @@ def make_trace_entry(**overrides):
         )
         else cfg.accum_steps
     )
-    if cfg.sync in ("auto", "none"):
-        # Framework-inserted sync: the averaging collectives come from the
-        # AD transpose, not a hand-traced strategy — no fixed contract.
-        schedule = None
-    else:
-        units = sync_units(
-            state.params,
-            cfg.sync,
-            trainer.axis_size,
-            bucket_bytes=trainer._bucket_bytes,
-            grad_compress=cfg.grad_compress,
-            overlap=trainer._overlap,
-        )
-        schedule = expected_collective_schedule(
-            cfg.sync,
-            trainer.axis_size,
-            units,
-            grad_compress=cfg.grad_compress,
-            syncs_per_step=syncs_per_step,
-        )
+    # 'auto' has a contract too: the AD transpose inserts one psum a
+    # parameter leaf (sync_units counts leaves for it), 'none' inserts none.
+    units = sync_units(
+        state.params,
+        cfg.sync,
+        trainer.axis_size,
+        bucket_bytes=trainer._bucket_bytes,
+        grad_compress=cfg.grad_compress,
+        overlap=trainer._overlap,
+    )
+    schedule = expected_collective_schedule(
+        cfg.sync,
+        trainer.axis_size,
+        units,
+        grad_compress=cfg.grad_compress,
+        syncs_per_step=syncs_per_step,
+    )
     wire_bytes = syncs_per_step * sync_wire_bytes(
         state.params,
         cfg.sync,

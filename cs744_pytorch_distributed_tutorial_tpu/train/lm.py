@@ -1194,9 +1194,9 @@ class LMTrainer:
             out_specs=(param_specs, opt_specs, metric_specs),
             check_vma=False,
         )
-        # Un-jitted, un-donated handle for instrumentation (graftscope
-        # re-jits WITHOUT donation so repeated parity/timing calls on the
-        # same (params, opt_state) don't hit deleted buffers).
+        # Un-jitted, un-donated handle for instrumentation: re-jit it
+        # WITHOUT donation and repeated calls on the same
+        # (params, opt_state) don't hit deleted buffers.
         self.mapped_train = mapped_train
         mapped_step = jax.jit(mapped_train, donate_argnums=(0, 1))
 
@@ -1214,7 +1214,7 @@ class LMTrainer:
 
         self.train_step = train_step
         # The raw jitted step, for AOT lower/compile with explicit
-        # compiler_options (bench.py's scoped-vmem recipe); call with an
+        # compiler_options or for memory_analysis(); call with an
         # explicit jnp.int32 step argument.
         self.jitted_train_step = mapped_step
 

@@ -7,7 +7,7 @@ not move between runs: either the operator places it
 (``JAX_COMPILATION_CACHE_DIR``, which JAX reads itself) or it is one
 fixed, git-ignored directory at the root of the checkout.
 
-Called from the mains (``cli``, ``lm_cli``, ``serve_cli``, ``bench.py``,
+Called from the mains (``cli``, ``lm_cli``, ``serve_cli``,
 ``chip_smoke.py``), never at package import: a library user and the test
 suite keep JAX's own default (no persistent cache).
 """

@@ -215,11 +215,14 @@ def test_int8_sync_names_route_through_compression(mesh4):
     assert np.isfinite(losses).all()
 
 
-def test_zero1_bucketed_update_bitwise(mesh4):
+def test_zero1_bucketed_update_within_one_ulp(mesh4):
     """Zero1SGD's bucketed reduce-scatter/all-gather (one collective per
-    ~bucket instead of per leaf) is bitwise identical to the per-leaf
-    path: column-concatenation preserves each leaf's per-row placement,
-    so psum_scatter delivers the exact same shards."""
+    ~bucket instead of per leaf) equals the per-leaf path to ONE float32
+    ulp. Column-concatenation preserves each leaf's per-row placement, so
+    psum_scatter delivers the same shards; what differs is how XLA fuses
+    the momentum update over a bucket and over a leaf (2 of 1,152
+    elements off by 1.5e-8 at 0.127 on the CPU, one step's loss at
+    6.0e-8 relative on the v5e), so bitwise is not the contract."""
     from jax import lax
 
     from cs744_pytorch_distributed_tutorial_tpu.parallel.zero import Zero1SGD
@@ -245,7 +248,11 @@ def test_zero1_bucketed_update_bitwise(mesh4):
     p0, m0 = run(0)
     p1, m1 = run(B.DEFAULT_BUCKET_BYTES)
     for a, b in zip(jax.tree.leaves((p0, m0)), jax.tree.leaves((p1, m1))):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        a, b = np.asarray(a), np.asarray(b)
+        if a.dtype == np.float32:
+            np.testing.assert_array_max_ulp(a, b, maxulp=1)
+        else:  # numpy counts no ulps in bfloat16: that leaf stays exact
+            np.testing.assert_array_equal(a, b)
 
 
 def test_compress_rejects_incompatible_sync(mesh4):

@@ -35,7 +35,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from cs744_pytorch_distributed_tutorial_tpu.obs.phases import compiled_costs
+from cs744_pytorch_distributed_tutorial_tpu.utils.profiling import compiled_costs
 from cs744_pytorch_distributed_tutorial_tpu.ops import (
     paged_attention as kernel_module,
 )

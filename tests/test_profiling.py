@@ -92,180 +92,10 @@ def test_device_op_breakdown_cpu():
     assert total >= 0.0
     assert isinstance(rows, list)
     # on CPU the device lanes may be named differently per backend
-    # version; the contract is "no crash, sane types", the TPU value was
-    # validated by hand in benchmarks/ablate.py round-2 notes
+    # version; the contract is "no crash, sane types" (on the chip the
+    # serve cell's traced runs go through this parse, docs/kernels.md)
     for ms, name in rows:
         assert ms >= 0.0 and isinstance(name, str)
-
-
-# ---------------------------------------------------------------------------
-# graftscope: segmented-step phase attribution (obs/phases.py)
-# ---------------------------------------------------------------------------
-
-
-def _cifar_step_inputs(mesh, cfg):
-    """(trainer, state, x, y, key) — the canonical parity-suite recipe."""
-    import jax
-
-    from cs744_pytorch_distributed_tutorial_tpu.data import synthetic_cifar10
-    from cs744_pytorch_distributed_tutorial_tpu.parallel.mesh import (
-        shard_global_batch,
-    )
-
-    tr = Trainer(cfg, mesh=mesh)
-    state = tr.init()
-    ds = synthetic_cifar10(cfg.global_batch_size, 8, seed=0)
-    x, y = shard_global_batch(mesh, ds.train_images, ds.train_labels)
-    return tr, state, x, y, jax.random.key(cfg.seed)
-
-
-@pytest.mark.parametrize(
-    "sync,compress,overrides",
-    [
-        ("allreduce", "none", {}),  # bucketed flat allreduce (default)
-        ("allreduce", "none", {"sync_bucket_mb": 0}),  # per-leaf
-        ("ring", "none", {}),
-        ("allreduce", "int8", {}),
-        pytest.param(  # fused scatter/apply/gather
-            "zero1", "none", {}, marks=pytest.mark.slow
-        ),
-        ("zero1", "none", {"sync_overlap": "bucket"}),
-        pytest.param(
-            "zero1", "int8", {"sync_overlap": "bucket+int8"},
-            marks=pytest.mark.slow,
-        ),
-    ],
-    ids=[
-        "allreduce", "allreduce-perleaf", "ring", "int8",
-        "zero1", "zero1-overlap", "zero1-int8",
-    ],
-)
-def test_segmented_fused_parity_cifar(mesh4, sync, compress, overrides):
-    """The segmented profiled step (forward/grads | sync | opt as separate
-    jitted programs) must produce the SAME loss and params as the fused
-    fast path — same tolerance discipline as test_sync_parity."""
-    import jax
-    import numpy as np
-
-    from cs744_pytorch_distributed_tutorial_tpu.obs.phases import (
-        PARITY_ATOL,
-        PARITY_LOSS_RTOL,
-        PARITY_RTOL,
-        build_cifar_segments,
-    )
-
-    cfg = TrainConfig(
-        **TINY_DP4_CFG, sync=sync, grad_compress=compress,
-        compute_dtype="float32", **overrides,
-    )
-    tr, state, x, y, key = _cifar_step_inputs(mesh4, cfg)
-    segs = build_cifar_segments(tr)
-    new_f, m_f = segs.fused(state, x, y, key)
-    new_s, loss_s = segs.segmented_step(state, x, y, key)
-    loss_f = float(m_f["loss"])
-    assert abs(float(loss_s) - loss_f) <= PARITY_LOSS_RTOL * max(
-        1.0, abs(loss_f)
-    )
-    for a, b in zip(
-        jax.tree.leaves(new_f.params), jax.tree.leaves(new_s.params)
-    ):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=PARITY_RTOL, atol=PARITY_ATOL
-        )
-
-
-@pytest.mark.parametrize("compress", ["none", "int8"])
-def test_segmented_fused_parity_lm(compress):
-    """Same contract on the LM engine (pure-DP configs)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from cs744_pytorch_distributed_tutorial_tpu.obs.phases import (
-        PARITY_ATOL,
-        PARITY_LOSS_RTOL,
-        PARITY_RTOL,
-        build_lm_segments,
-    )
-    from cs744_pytorch_distributed_tutorial_tpu.train import LMConfig, LMTrainer
-
-    cfg = LMConfig(
-        vocab_size=64, num_layers=2, num_heads=2, d_model=32, d_ff=64,
-        max_seq_len=16, seq_len=16, global_batch_size=8, data_parallel=4,
-        seq_parallel=1, grad_compress=compress,
-    )
-    tr = LMTrainer(cfg)
-    params, opt_state = tr.init()
-    import numpy as _np
-
-    toks = _np.random.RandomState(0).randint(0, 64, size=(8, 17))
-    x, y = tr.shard_batch(toks)
-    segs = build_lm_segments(tr)
-    step = jnp.int32(0)
-    new_p, _new_o, m_f = segs.fused(params, opt_state, x, y, step)
-    (seg_p, _seg_o), loss_s = segs.segmented_step(params, opt_state, x, y, step)
-    loss_f = float(m_f["loss"])
-    assert abs(float(loss_s) - loss_f) <= PARITY_LOSS_RTOL * max(
-        1.0, abs(loss_f)
-    )
-    for a, b in zip(jax.tree.leaves(new_p), jax.tree.leaves(seg_p)):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=PARITY_RTOL, atol=PARITY_ATOL
-        )
-
-
-def test_cifar_segments_reject_fsdp(mesh4):
-    """fsdp's gradient reduction is the AD transpose of its parameter
-    all_gather — there is no separable sync phase, so segmentation must
-    fail loudly, not silently mis-attribute. (zero1 IS segmentable:
-    see the zero1 cases in the parity sweep above.)"""
-    from cs744_pytorch_distributed_tutorial_tpu.obs.phases import (
-        build_cifar_segments,
-    )
-
-    cfg = TrainConfig(**TINY_DP4_CFG, sync="fsdp")
-    tr = Trainer(cfg, mesh=mesh4)
-    with pytest.raises(ValueError, match="fsdp"):
-        build_cifar_segments(tr)
-
-
-def test_cifar_segments_reject_unbucketed_zero1(mesh4):
-    """zero1 segmentation carves the BUCKETED schedule; the per-leaf
-    fallback (sync_bucket_mb=0) has no bucket lanes to time."""
-    from cs744_pytorch_distributed_tutorial_tpu.obs.phases import (
-        build_cifar_segments,
-    )
-
-    cfg = TrainConfig(**TINY_DP4_CFG, sync="zero1", sync_bucket_mb=0)
-    tr = Trainer(cfg, mesh=mesh4)
-    with pytest.raises(ValueError, match="bucket"):
-        build_cifar_segments(tr)
-
-
-def test_profile_phases_end_to_end(mesh4):
-    """profile_phases: parity gate + the four-phase report with
-    sink-ready records and a renderable table."""
-    from cs744_pytorch_distributed_tutorial_tpu.obs.phases import (
-        PHASE_NAMES,
-        phase_records_from_stream,
-        profile_phases,
-        render_phase_table,
-    )
-
-    cfg = TrainConfig(
-        **TINY_DP4_CFG, sync="allreduce", compute_dtype="float32"
-    )
-    tr, state, x, y, key = _cifar_step_inputs(mesh4, cfg)
-    report = profile_phases(tr, state, x, y, key, iters=1)
-    assert report.parity_ok
-    assert tuple(p.name for p in report.phases) == PHASE_NAMES
-    assert report.sync_exposed_ms >= 0.0
-    assert report.phase("grad_sync").comm_bytes > 0
-    assert report.phase("grad_sync").roofline == "comms"
-    records = report.records(run="test")
-    assert len(phase_records_from_stream(records)) == len(PHASE_NAMES) + 1
-    table = render_phase_table(records)
-    assert "grad_sync" in table and "sync_exposed_ms" in table
 
 
 # ---------------------------------------------------------------------------

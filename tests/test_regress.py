@@ -82,7 +82,7 @@ def test_bench_envelope_parsing(tmp_path):
     """BENCH_rNN.json driver envelopes (headline record under "parsed")
     read the same as JSONL streams."""
     envelope = {
-        "n": 5, "cmd": "python bench.py", "rc": 0, "tail": "...",
+        "n": 5, "cmd": "python3 -m perfbench", "rc": 0, "tail": "...",
         "parsed": {"metric": METRIC, "value": 35330.5, "unit": "s/s/chip"},
     }
     p = tmp_path / "BENCH_r05.json"

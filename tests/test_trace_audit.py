@@ -70,6 +70,78 @@ def audit(step: TracedStep, rules=TRACE_ONLY, name: str = "fixture"):
     return findings
 
 
+# ============================================ the collective-name table
+def _replicated_weight_grad(x):
+    """Gradient of a replicated weight: the all-reduce nobody writes
+    (``sync="auto"``). Under ``check_vma`` the AD transpose of the
+    weight's ``pvary`` binds it; without, nothing does."""
+    w = jnp.ones((4,), x.dtype)
+    return x + jax.grad(lambda w: (x * w).sum())(w)
+
+
+_RING4 = [(i, (i + 1) % 4) for i in range(4)]
+
+#: lax call -> (two-line shard_map body, canonical class); every body
+#: maps a [4, 4] shard to a [4, 4] shard so one spec serves all
+COLLECTIVE_BODIES = {
+    "psum": (lambda x: jax.lax.psum(x, "data"), "psum"),
+    "pmean": (lambda x: jax.lax.pmean(x, "data"), "psum"),
+    "pmax": (lambda x: jax.lax.pmax(x, "data"), "pmax"),
+    "pmin": (lambda x: jax.lax.pmin(x, "data"), "pmin"),
+    "all_gather": (
+        lambda x: jax.lax.all_gather(x, "data", tiled=True)[:4],
+        "all_gather",
+    ),
+    "psum_scatter": (
+        lambda x: jnp.tile(
+            jax.lax.psum_scatter(x, "data", tiled=True), (4, 1)
+        ),
+        "reduce_scatter",
+    ),
+    "ppermute": (lambda x: jax.lax.ppermute(x, "data", _RING4), "ppermute"),
+    "all_to_all": (
+        lambda x: jax.lax.all_to_all(x, "data", 0, 0, tiled=True),
+        "all_to_all",
+    ),
+    "grad_of_replicated": (_replicated_weight_grad, "psum"),
+    # no public name in jax 0.9.0, but a primitive that moves bytes
+    "all_gather_invariant": (
+        lambda x: jax._src.lax.parallel.all_gather_invariant(
+            x, "data", tiled=True
+        )[:4],
+        "all_gather",
+    ),
+}
+
+
+@pytest.mark.parametrize("check_vma", [True, False])
+@pytest.mark.parametrize("call", list(COLLECTIVE_BODIES))
+def test_collective_table_knows_what_jax_binds(call, check_vma, mesh4):
+    """Whatever primitive this jax binds for each ``lax`` collective,
+    with the replication checker on and off, ``COLLECTIVE_CLASS`` folds
+    it to the right class. A rename upstream (0.9.0 binds
+    ``psum_invariant`` for ``lax.psum`` under ``check_vma``) fails here
+    by name, not as audits that silently see no collectives."""
+    from jax.sharding import PartitionSpec as P
+
+    body, cls = COLLECTIVE_BODIES[call]
+    fn = jax.shard_map(
+        body, mesh=mesh4, in_specs=P("data"), out_specs=P("data"),
+        check_vma=check_vma,
+    )
+    closed = jax.make_jaxpr(fn)(jnp.ones((16, 4), jnp.float32))
+    found = jaxpr_utils.collect_collectives(closed, dict(mesh4.shape))
+    if call == "grad_of_replicated" and not check_vma:
+        expected = []  # local gradients: the manual strategies' start
+    else:
+        expected = [cls]
+    bound = sorted(
+        {e.primitive.name for e, _ in jaxpr_utils.iter_eqns(closed.jaxpr)}
+    )
+    assert [c.cls for c in found] == expected, f"{call} binds {bound}"
+    assert all(c.group_size == 4 and not c.trivial for c in found)
+
+
 # =================================================== TA003 schedule sweep
 CIFAR_SYNCS = [
     "allreduce",

@@ -1,7 +1,7 @@
 """``jax.block_until_ready`` is a completion fence on this device.
 
-The timers (``utils/timing.py``, ``obs/phases.py``, ``bench.py``) close
-their regions with it. This test times the same 30 training steps at
+The timers (``utils/timing.py``, ``utils/profiling.py``'s
+``capture_device_profile``) close their regions with it. This test times the same 30 training steps at
 the smoke's full width twice per round — closed once by
 ``block_until_ready`` and once by fetching a scalar that depends on the
 last step (a host round-trip cannot return before the work it reads) —
