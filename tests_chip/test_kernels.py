@@ -20,6 +20,7 @@ from parity import assert_close
 
 from cs744_pytorch_distributed_tutorial_tpu.ops.flash_attention import (
     flash_attention,
+    flash_tile_plan,
 )
 from cs744_pytorch_distributed_tutorial_tpu.ops.fused_conv import conv3x3_wgrad
 from cs744_pytorch_distributed_tutorial_tpu.ops.fused_sgd import FusedSGD
@@ -63,10 +64,19 @@ def _dense_causal(q, k, v):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v, precision=HIGHEST)
 
 
-@pytest.mark.parametrize("heads,head_dim", [(12, 64), (8, 128)])
-def test_flash_attention_fwd_bwd(heads, head_dim):
-    """Causal, T=1024, bf16 — GPT-2-small's and a llama-style head."""
-    shape = (2, 1024, heads, head_dim)
+@pytest.mark.parametrize(
+    "batch,heads,head_dim",
+    [(2, 12, 64), (2, 8, 128), (16, 12, 64)],
+    ids=["12x64", "8x128", "lm_cell_b16_12x64"],
+)
+def test_flash_attention_fwd_bwd(batch, heads, head_dim):
+    """Causal, T=1024, bf16 — GPT-2-small's and a llama-style head, and
+    the LM training cell's own call ([16, 1024, 12, 64]), under the
+    tiles ``flash_tile_plan`` gives them."""
+    shape = (batch, 1024, heads, head_dim)
+    for sweep in ("keys", "queries"):
+        plan = flash_tile_plan(1024, 1024, True, sweep)
+        assert plan.skipped > 0 and plan.run > plan.masked
     q, k, v = (_normal(i, shape, jnp.bfloat16) for i in range(3))
     w = _normal(3, shape)  # fixed cotangent: a weighted sum as the loss
 
