@@ -281,6 +281,123 @@ def keye_model_config(hf_config: Mapping[str, Any], max_seq_len: int | None = No
     )
 
 
+def _rope_scaling(entry: Mapping[str, Any], what: str):
+    """A ``rope_parameters`` entry as ``RopeScaling`` (None: default)."""
+    kind = entry.get("rope_type", "default")
+    if kind == "default":
+        return None
+    if kind != "yarn":
+        raise ValueError(
+            f"rope_type {kind!r} on the {what} layers is not supported: "
+            "apply_rope knows the default rotation and YaRN"
+        )
+    from cs744_pytorch_distributed_tutorial_tpu.models.transformer import (
+        RopeScaling,
+    )
+
+    return RopeScaling(
+        factor=float(entry["factor"]),
+        original_max_position=int(entry["original_max_position_embeddings"]),
+        beta_fast=float(entry.get("beta_fast", 32)),
+        beta_slow=float(entry.get("beta_slow", 1)),
+        attention_factor=entry.get("attention_factor"),
+    )
+
+
+def mellum_model_config(hf_config: Mapping[str, Any], max_seq_len: int | None = None) -> dict:
+    """``TransformerLM`` kwargs for a ``mellum`` ``config.json``
+    (JetBrains/Mellum2-12B-A2.5B-Instruct): RMSNorm, GQA with a head
+    width of its own and per-head q/k RMSNorm, every layer routed (gated
+    experts on the dropless path, top-k weights renormalised), and
+    layers that differ by ``layer_types``: a ``sliding_attention`` layer
+    sees ``sliding_window`` keys and rotates by the ``sliding_attention``
+    entry of ``rope_parameters`` (default RoPE), a ``full_attention``
+    layer sees every key and rotates by the ``full_attention`` entry
+    (YaRN as published). Read from the published keys alone, no tensor.
+    ``max_seq_len`` defaults to ``max_position_embeddings``."""
+    layers = hf_config["num_hidden_layers"]
+    # A file cut in depth keeps the published lists whole: the first
+    # num_hidden_layers entries are the layers that run.
+    kinds = tuple(hf_config.get("layer_types") or ("full_attention",) * layers)
+    if len(kinds) < layers:
+        raise ValueError(
+            f"layer_types names {len(kinds)} layers, num_hidden_layers is "
+            f"{layers}"
+        )
+    kinds = kinds[:layers]
+    if any(k != "sparse" for k in (hf_config.get("mlp_layer_types") or ())[:layers]):
+        raise ValueError(
+            "dense layers among the routed ones (mlp_layer_types) are not "
+            "supported: every block of TransformerLM has the same MLP"
+        )
+    if not hf_config.get("norm_topk_prob", True):
+        raise ValueError(
+            "norm_topk_prob=false is not supported: MoEFFN renormalises "
+            "the top-k weights"
+        )
+    if hf_config.get("attention_bias"):
+        raise ValueError("attention_bias is not supported")
+    sliding = "sliding_attention" in kinds
+    if sliding and not (
+        hf_config.get("use_sliding_window", True) and hf_config.get("sliding_window")
+    ):
+        raise ValueError(
+            "sliding_attention layers need use_sliding_window and a "
+            "sliding_window"
+        )
+    rope = hf_config.get("rope_parameters") or {}
+    full = rope.get("full_attention", rope)
+    window = rope.get("sliding_attention", {})
+    if _rope_scaling(window, "sliding_attention") is not None:
+        raise ValueError(
+            "the sliding_attention layers rotate by default RoPE: a scaled "
+            "rope_type there is not supported"
+        )
+    return dict(
+        vocab_size=hf_config["vocab_size"],
+        num_layers=layers,
+        num_heads=hf_config["num_attention_heads"],
+        num_kv_heads=hf_config["num_key_value_heads"],
+        head_dim=hf_config["head_dim"],
+        d_model=hf_config["hidden_size"],
+        d_ff=hf_config["moe_intermediate_size"],
+        max_seq_len=max_seq_len or hf_config["max_position_embeddings"],
+        use_rope=True,
+        rope_base=float(full.get("rope_theta", hf_config.get("rope_theta", 1e4))),
+        rope_scaling=_rope_scaling(full, "full_attention"),
+        tie_embeddings=bool(hf_config.get("tie_word_embeddings", False)),
+        norm="rmsnorm",
+        norm_eps=hf_config["rms_norm_eps"],
+        mlp="swiglu",
+        qk_norm=True,
+        num_experts=hf_config["num_experts"],
+        moe_top_k=hf_config["num_experts_per_tok"],
+        moe_dispatch="dropless",
+        moe_bias=False,
+        layer_types=kinds if sliding else None,
+        window=int(hf_config["sliding_window"]) if sliding else None,
+        window_rope_base=float(window["rope_theta"]) if "rope_theta" in window else None,
+        attn_bias=False,
+        attention_impl="dense",
+    )
+
+
+# ``model_type`` of a published config.json -> the builder of its kwargs
+CONFIG_BUILDERS = {"KeyeVL2": keye_model_config, "mellum": mellum_model_config}
+
+
+def model_config_from_hf(hf_config: Mapping[str, Any], max_seq_len: int | None = None) -> dict:
+    """``TransformerLM`` kwargs from a published ``config.json``, by its
+    ``model_type``."""
+    kind = hf_config.get("model_type")
+    if kind not in CONFIG_BUILDERS:
+        raise ValueError(
+            f"no builder for model_type {kind!r}; known: "
+            f"{sorted(CONFIG_BUILDERS)}"
+        )
+    return CONFIG_BUILDERS[kind](hf_config, max_seq_len=max_seq_len)
+
+
 def lm_params_from_hf_llama(state_dict: Mapping[str, Any]) -> dict:
     """Convert a ``LlamaForCausalLM.state_dict()`` into the ``params``
     tree of the matching ``TransformerLM`` (``llama_model_config``).

@@ -20,7 +20,7 @@ the param tree is identical either way).
 from __future__ import annotations
 
 from functools import partial
-from typing import Any
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -72,8 +72,60 @@ def _dense_cls(quant: bool):
     return QuantDense
 
 
+class RopeScaling(NamedTuple):
+    """YaRN's parameters as a published ``rope_parameters`` entry of
+    ``rope_type: "yarn"`` gives them. ``attention_factor`` None is the
+    paper's ``0.1 ln(factor) + 1``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float | None = None
+
+
+def rope_inv_freq(
+    head_dim: int, base: float, scaling: RopeScaling | None = None
+) -> tuple[jnp.ndarray, float]:
+    """The ``head_dim // 2`` rotary frequencies and the factor on cos and
+    sin, for both kinds of RoPE. Default: ``base**(-i/half)``, factor 1.
+    YaRN: a frequency that turns more than ``beta_fast`` times over the
+    original context is kept, one that turns less than ``beta_slow``
+    times is divided by ``factor``, those between are blended linearly
+    in the frequency's index; cos and sin carry ``attention_factor``, so
+    the scores carry its square."""
+    half = head_dim // 2
+    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if scaling is None:
+        return freqs, 1.0
+    import math
+
+    def turns_at(r: float) -> float:  # the index that turns r times
+        return (
+            head_dim
+            * math.log(scaling.original_max_position / (2 * math.pi * r))
+            / (2 * math.log(base))
+        )
+
+    low = max(math.floor(turns_at(scaling.beta_fast)), 0)
+    high = min(math.ceil(turns_at(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001  # no division by zero (as the published code has it)
+    ramp = jnp.clip(
+        (jnp.arange(half, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0
+    )
+    freqs = freqs * ((1.0 - ramp) + ramp / scaling.factor)
+    factor = scaling.attention_factor
+    if factor is None:
+        factor = 0.1 * math.log(scaling.factor) + 1.0
+    return freqs, float(factor)
+
+
 def apply_rope(
-    x: jnp.ndarray, positions: jnp.ndarray, base: float = 10000.0
+    x: jnp.ndarray,
+    positions: jnp.ndarray,
+    base: float = 10000.0,
+    scaling: RopeScaling | None = None,
 ) -> jnp.ndarray:
     """Rotary position embedding on [B, T, H, D] (D even).
 
@@ -82,19 +134,22 @@ def apply_rope(
     positions only, which is what makes RoPE exact under sequence
     sharding: each shard rotates its q/k by its GLOBAL positions before
     any collective, and ring/all-to-all attention needs no further
-    position bookkeeping.
+    position bookkeeping. ``scaling`` (YaRN) changes the frequencies and
+    scales cos and sin (``rope_inv_freq``); None is the plain rotation.
     """
     d = x.shape[-1]
     if d % 2:
         raise ValueError(f"RoPE needs an even head_dim, got {d}")
     half = d // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    freqs, scale = rope_inv_freq(d, base, scaling)
     # positions is [T] (shared across the batch) or [B, T] (per-slot
     # depths on the paged-decode serve path — each slot rotates by its
     # own global position).
     angles = positions.astype(jnp.float32)[..., :, None] * freqs
     sin = jnp.sin(angles)[..., None, :]
     cos = jnp.cos(angles)[..., None, :]
+    if scaling is not None:
+        sin, cos = sin * scale, cos * scale
     if angles.ndim == 2:  # [T, half] -> broadcast over batch as before
         sin, cos = sin[None], cos[None]
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
@@ -209,6 +264,50 @@ class Attention(nn.Module):
     indexer_heads: int = 0
     indexer_head_dim: int = 64
     sparse_topk: int = 0
+    # A sliding window: a query at t sees the keys s with
+    # t - window < s <= t (``window`` keys, itself among them). Built for
+    # modes train (impl "dense"), paged_prefill and paged_decode; in the
+    # paged modes ``page_table`` lists the slot's live window pages only
+    # and ``first_pos`` gives the position of the table's first row
+    # (serve/engine.py: the window page group).
+    window: int | None = None
+    # YaRN on this layer's RoPE (``rope_inv_freq``); None = plain RoPE.
+    rope_scaling: RopeScaling | None = None
+    # A name scope around the paged-attention kernel's call
+    # ("attn_window" / "attn_full": a model whose layers differ names
+    # them by kind); None leaves the call under the module's own name.
+    attn_scope: str | None = None
+
+    def _window_attention(self, q, k, v, q_pos):
+        """Causal attention of ``q`` [B, C, H, D] at row positions
+        ``q_pos`` [B, C] over the rows of ``k`` / ``v`` [B, S, Hkv, D],
+        no further back than the window."""
+        from cs744_pytorch_distributed_tutorial_tpu.ops.sparse_attention import (
+            masked_attention,
+        )
+
+        in_window = jnp.arange(k.shape[1])[None, None, :] > (
+            q_pos[:, :, None] - self.window
+        )
+        return masked_attention(q, k, v, q_pos, in_window)
+
+    def _window_view_attention(self, q, key_pages, value_pages, table, rel):
+        """The same over a slot's window pages: the table's whole view
+        (row ``r`` holds position ``first_pos + r``), queries at
+        ``rel`` [B, C] rows into it. One static view: the table is as
+        wide as a window and a chunk need (serve/engine.py: P_w)."""
+        from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
+            gather_pages,
+            unfold_heads,
+        )
+
+        d = q.shape[-1]
+        return self._window_attention(
+            q,
+            unfold_heads(gather_pages(key_pages, table), d),
+            unfold_heads(gather_pages(value_pages, table), d),
+            rel,
+        )
 
     @nn.compact
     def __call__(
@@ -218,6 +317,7 @@ class Attention(nn.Module):
         mode: str = "train",
         decode_pos: jnp.ndarray | None = None,
         page_table: jnp.ndarray | None = None,
+        first_pos: jnp.ndarray | None = None,
     ) -> jnp.ndarray:
         if self.impl not in ATTENTION_IMPLS:
             raise ValueError(
@@ -254,6 +354,38 @@ class Attention(nn.Module):
                     "the sparse-attention indexer serves through the paged "
                     "pools in float (modes train, prefill, paged_prefill, "
                     "paged_decode; causal; no int8 KV)"
+                )
+        windowed = self.window is not None
+        if windowed:
+            if self.window < 1:
+                raise ValueError(f"window must be >= 1, got {self.window}")
+            if tp or (self.seq_axis is not None and self.seq_axis_size > 1):
+                raise ValueError(
+                    "a window layer runs on one device: no tensor or "
+                    "sequence axis (the window's pages and mask are not "
+                    "sharded)"
+                )
+            if mode in ("prefill", "decode"):
+                raise ValueError(
+                    f"mode={mode!r} keeps a dense cache of every position, "
+                    "which a window layer neither needs nor masks: serve "
+                    "it through the paged pools (ServeConfig.prefill_chunk)"
+                )
+            if self.impl != "dense" and mode == "train":
+                raise ValueError(
+                    f"impl={self.impl!r} has no window (the flash tile plan "
+                    "knows one diagonal); a window layer trains with "
+                    "impl='dense'"
+                )
+            if self.quant_kv_cache or sparse or not self.causal:
+                raise ValueError(
+                    "a window layer is causal, caches float K and V and "
+                    "has no indexer (no int8 KV, no sparse_topk)"
+                )
+            if mode in ("paged_prefill", "paged_decode") and first_pos is None:
+                raise ValueError(
+                    f"mode={mode!r} on a window layer needs first_pos (the "
+                    "position of the window page table's first row, [B])"
                 )
         if tp and self.num_heads % self.tensor_axis_size:
             raise ValueError(
@@ -332,11 +464,13 @@ class Attention(nn.Module):
                 positions = jnp.asarray(offset)[:, None] + jnp.arange(t)
             else:
                 positions = offset + jnp.arange(t)
-            q = apply_rope(q, positions, self.rope_base)
-            k = apply_rope(k, positions, self.rope_base)
+            rope = partial(
+                apply_rope, positions=positions, base=self.rope_base,
+                scaling=self.rope_scaling,
+            )
+            q, k = rope(q), rope(k)
             if sparse:
-                q_idx = apply_rope(q_idx, positions, self.rope_base)
-                k_idx = apply_rope(k_idx, positions, self.rope_base)
+                q_idx, k_idx = rope(q_idx), rope(k_idx)
         if sparse:
             k_idx = k_idx[:, :, 0]  # [B, T, Di]: one key head
             # As cached: the row padded with zeros to whole 128-lane
@@ -543,8 +677,15 @@ class Attention(nn.Module):
             )
 
             positions = jnp.asarray(decode_pos)[:, None] + jnp.arange(t)
-            page_idx = positions // self.page_size
-            in_table = page_idx < page_table.shape[1]
+            if windowed:
+                # The window group's table starts at the page that holds
+                # position first_pos (a multiple of page_size).
+                rel = positions - jnp.asarray(first_pos)[:, None]
+                page_idx = rel // self.page_size
+                in_table = (page_idx >= 0) & (page_idx < page_table.shape[1])
+            else:
+                page_idx = positions // self.page_size
+                in_table = page_idx < page_table.shape[1]
             rows_page = jnp.where(
                 in_table,
                 jnp.take_along_axis(
@@ -597,19 +738,29 @@ class Attention(nn.Module):
                     allowed,
                 )
 
-            pages_needed = jnp.max(positions) // self.page_size + 1
-            paged_out = lax.switch(
-                sum((pages_needed > w).astype(jnp.int32) for w in widths[:-1]),
-                [partial(attend, w) for w in widths],
-            )
+            if windowed:  # one static view: nothing to choose
+                paged_out = self._window_view_attention(
+                    q, kp.value, vp.value, page_table, rel
+                )
+            else:
+                pages_needed = jnp.max(positions) // self.page_size + 1
+                paged_out = lax.switch(
+                    sum(
+                        (pages_needed > w).astype(jnp.int32)
+                        for w in widths[:-1]
+                    ),
+                    [partial(attend, w) for w in widths],
+                )
             decode_step = True
         elif mode == "paged_decode":
             # Scatter the new token's K/V. Inactive slots are parked on
             # the reserved trash page 0 by the engine — their writes
             # collide there harmlessly (the page is never gathered by a
             # live slot).
+            # (a window layer's table starts at position first_pos)
+            rel_pos = decode_pos - first_pos if windowed else decode_pos
             slot_page = jnp.take_along_axis(
-                page_table, (decode_pos // self.page_size)[:, None], axis=1
+                page_table, (rel_pos // self.page_size)[:, None], axis=1
             )[:, 0]
             slot_off = decode_pos % self.page_size
 
@@ -706,9 +857,25 @@ class Attention(nn.Module):
                             "serve_stats", "scored_tokens", valid.sum(-1)
                         )
                 elif use_kernel:
-                    paged_out = paged_attention(
-                        q, kp.value, vp.value, page_table, decode_pos,
-                        interpret=self.flash_interpret,
+                    # where the layers differ the kernel's calls carry
+                    # the layer kind in their name, for the trace to
+                    # tell them apart
+                    import contextlib
+
+                    with (
+                        jax.named_scope(self.attn_scope)
+                        if self.attn_scope
+                        else contextlib.nullcontext()
+                    ):
+                        paged_out = paged_attention(
+                            q, kp.value, vp.value, page_table, decode_pos,
+                            first_pos=first_pos if windowed else None,
+                            window=self.window,
+                            interpret=self.flash_interpret,
+                        )
+                elif windowed:  # the reference: the whole view, masked
+                    paged_out = self._window_view_attention(
+                        q, kp.value, vp.value, page_table, rel_pos[:, None]
                     )
                 else:
                     paged_out = paged_decode_attention(
@@ -731,7 +898,7 @@ class Attention(nn.Module):
         sp_kv_native = self.impl in (
             "ring", "ring_flash", "ulysses", "ulysses_flash"
         ) and (self.seq_axis is not None and self.seq_axis_size > 1)
-        if not decode_step and not sp_kv_native and not sparse:
+        if not decode_step and not sp_kv_native and not sparse and not windowed:
             from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
                 repeat_kv,
             )
@@ -767,6 +934,12 @@ class Attention(nn.Module):
                 # S_t as a [B, T, T] mask, for who asks for intermediates
                 self.sow("intermediates", "selected", allowed)
             out = sa.masked_attention(q, k, v, q_pos, allowed)
+        elif windowed:
+            # The full forward of a window layer: causal, and no key
+            # further back than the window.
+            out = self._window_attention(
+                q, k, v, jnp.broadcast_to(jnp.arange(t), (b, t))
+            )
         elif self.seq_axis is None or self.seq_axis_size == 1:
             if self.impl in ("flash", "ring_flash", "ulysses_flash"):
                 from cs744_pytorch_distributed_tutorial_tpu.ops.flash_attention import (
@@ -868,6 +1041,11 @@ class Block(nn.Module):
     # Expert biases b_in / b_out (models/moe.py::MoEFFN). With
     # mlp="swiglu" the experts are gated as the dense MLP would be.
     moe_bias: bool = True
+    # This block's attention window, RoPE scaling and kernel name scope
+    # (see Attention): a block's own where the layers differ.
+    window: int | None = None
+    rope_scaling: RopeScaling | None = None
+    attn_scope: str | None = None
 
     @nn.compact
     def __call__(
@@ -878,6 +1056,7 @@ class Block(nn.Module):
         mode: str = "train",
         decode_pos: jnp.ndarray | None = None,
         page_table: jnp.ndarray | None = None,
+        first_pos: jnp.ndarray | None = None,
     ) -> jnp.ndarray:
         # ``deterministic`` is positional (arg index 2 counting self) so
         # the remat wrapper can declare it static — as a kw-only arg it
@@ -942,8 +1121,14 @@ class Block(nn.Module):
             indexer_heads=self.indexer_heads,
             indexer_head_dim=self.indexer_head_dim,
             sparse_topk=self.sparse_topk,
+            window=self.window,
+            rope_scaling=self.rope_scaling,
+            attn_scope=self.attn_scope,
             name="attn",
-        )(h, mode=mode, decode_pos=decode_pos, page_table=page_table)
+        )(
+            h, mode=mode, decode_pos=decode_pos, page_table=page_table,
+            first_pos=first_pos,
+        )
         if self.dropout_rate > 0.0:
             attn_out = drop(name="attn_drop")(attn_out)
         x = x + attn_out
@@ -1115,6 +1300,25 @@ class TransformerLM(nn.Module):
     # Expert biases b_in / b_out (models/moe.py::MoEFFN). With
     # mlp="swiglu" the experts are gated as the dense MLP would be.
     moe_bias: bool = True
+    # YaRN on the RoPE of the full-attention layers (every layer, where
+    # ``layer_types`` is None); see ``rope_inv_freq``.
+    rope_scaling: RopeScaling | None = None
+    # Layers that differ by kind: one entry a layer, "full_attention" or
+    # "sliding_attention" (the published ``layer_types``). A sliding
+    # layer sees ``window`` keys (Attention.window), rotates by plain
+    # RoPE over ``window_rope_base`` (None = ``rope_base``) and, served,
+    # keeps its K and V in a page group of its own: pools of
+    # ``window_num_pages`` pages under ``window_page_table`` /
+    # ``window_first_pos`` (serve/engine.py sizes and fills them). None
+    # = every layer full, the model as it always was.
+    layer_types: tuple | None = None
+    window: int | None = None
+    window_rope_base: float | None = None
+    window_num_pages: int | None = None
+
+    def window_layers(self) -> int:
+        """How many layers are sliding-window layers."""
+        return sum(k == "sliding_attention" for k in self.layer_types or ())
 
     @nn.compact
     def __call__(
@@ -1126,12 +1330,41 @@ class TransformerLM(nn.Module):
         page_table: jnp.ndarray | None = None,
         deterministic: bool = True,
         logits_at: jnp.ndarray | None = None,
+        window_page_table: jnp.ndarray | None = None,
+        window_first_pos: jnp.ndarray | None = None,
     ) -> jnp.ndarray:
         """``logits_at`` ([B] indices into this call's tokens) asks for
         the logits of one position a row only, ``[B, 1, vocab]``: the
         final norm and the head then run on that row alone (a prefill
         chunk needs one token's logits, not a chunk's)."""
         b, t_local = tokens.shape
+        if self.layer_types is not None:
+            kinds = set(self.layer_types)
+            if len(self.layer_types) != self.num_layers or not kinds <= {
+                "full_attention", "sliding_attention"
+            }:
+                raise ValueError(
+                    f"layer_types must name {self.num_layers} layers as "
+                    "'full_attention' or 'sliding_attention', got "
+                    f"{self.layer_types!r}"
+                )
+            if "sliding_attention" in kinds and self.window is None:
+                raise ValueError("sliding_attention layers need a window")
+            if "sliding_attention" in kinds and (
+                (self.seq_axis is not None and self.seq_axis_size > 1)
+                or (self.tensor_axis is not None and self.tensor_axis_size > 1)
+            ):
+                raise ValueError(
+                    "a model with window layers runs on one device: no "
+                    "tensor or sequence axis (the window's pages and mask "
+                    "are not sharded)"
+                )
+            if self.scan_layers:
+                raise ValueError(
+                    "scan_layers runs ONE block body over stacked "
+                    "parameters; blocks that differ by layer_types do not "
+                    "stack. Run them unrolled"
+                )
         tok_embed = nn.Embed(
             self.vocab_size, self.d_model, dtype=self.dtype, name="tok_embed"
         )
@@ -1212,6 +1445,7 @@ class TransformerLM(nn.Module):
             indexer_head_dim=self.indexer_head_dim,
             sparse_topk=self.sparse_topk,
             moe_bias=self.moe_bias,
+            rope_scaling=self.rope_scaling,
         )
         if self.scan_layers:
             if self.num_experts > 0:
@@ -1252,7 +1486,21 @@ class TransformerLM(nn.Module):
             )(block_cls(**block_kw, name="blocks"), x)
         else:
             for i in range(self.num_layers):
-                block = block_cls(**block_kw, name=f"block_{i}")
+                kw, table, first = block_kw, page_table, None
+                if self.layer_types is not None:
+                    if self.layer_types[i] == "sliding_attention":
+                        kw = dict(
+                            block_kw,
+                            window=self.window,
+                            rope_scaling=None,
+                            rope_base=self.window_rope_base or self.rope_base,
+                            num_pages=self.window_num_pages,
+                            attn_scope="attn_window",
+                        )
+                        table, first = window_page_table, window_first_pos
+                    else:
+                        kw = dict(block_kw, attn_scope="attn_full")
+                block = block_cls(**kw, name=f"block_{i}")
                 # remat (train-only) rejects non-array kwargs; the
                 # defaults ARE train mode, so pass the decode kwargs only
                 # off of it. ``deterministic`` rides positionally so the
@@ -1265,8 +1513,24 @@ class TransformerLM(nn.Module):
                     # parity is the scan_layers contract).
                     x = block(
                         x, deterministic, mode=mode, decode_pos=decode_pos,
-                        page_table=page_table,
+                        page_table=table, first_pos=first,
                     )
+            if (
+                mode == "paged_decode" and self.window_layers()
+                and not self.is_initializing()
+            ):
+                # Keys each slot's step attended, summed over the layers
+                # of a kind (the engine's counters; a no-op unless
+                # "serve_stats" is asked for).
+                n_window = self.window_layers()
+                self.sow(
+                    "serve_stats", "full_tokens_read",
+                    (self.num_layers - n_window) * (decode_pos + 1),
+                )
+                self.sow(
+                    "serve_stats", "window_tokens_read",
+                    n_window * jnp.minimum(decode_pos + 1, self.window),
+                )
         if logits_at is not None:
             x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
         x = _norm_cls(self.norm, self.norm_eps)(dtype=self.dtype, name="ln_f")(x)

@@ -41,6 +41,22 @@ scores **every head of a block of rows in one matmul pair**.
   pages unrolled behind ``pl.when`` traced and lowered three times
   slower, twelve layers a program, and tripled the engine's start-up.
 
+**A window** (``window``, ``first_pos``; a model with sliding-window
+layers, whose engine keeps those layers' pages in a group of their own).
+The query sees positions ``pos - window + 1 .. pos`` only, and the page
+table lists the slot's live window pages from the one that holds
+position ``first_pos[slot]`` on (a third scalar-prefetched operand).
+The walk is the same walk, started at the table's first page that holds
+a key inside the window (the engine may still hold a page or a chunk's
+worth behind it) and run to the page that holds ``pos``: the trip count
+is ``ceil(window / page_size) + 1`` pages at most whatever the context,
+and the position mask has a lower side. Without a window nothing is
+added: the kernel traces to the one it always was. The page tables ride
+WHOLE as scalar-prefetched operands (SMEM), a full group's ``[32,
+2080]`` int32 (266 KB, 278 KB padded; 33k positions a slot) among them:
+Mosaic accepts that on the v5e (compiled for it and run on the chip,
+PERF.md PR 32), so no grid step copies a table row.
+
 **All heads in one matmul pair** (both fetch paths). ``q`` arrives
 block-diagonal, ``[Hq, Hkv*D]`` with row ``h`` non-zero only on its KV
 head's lanes (built by the entry point in XLA), so ``scores = q_bd x
@@ -116,19 +132,22 @@ def _pages_per_block(capacity: int, page_size: int, row_bytes: int) -> int:
     return n
 
 
-def _attend(q, k, v, first, pos, scale, carry):
+def _attend(q, k, v, first, pos, scale, carry, oldest=None):
     """Fold one block of rows into the online softmax, every head at
     once. ``q`` is block-diagonal ``[Hq, Hkv*D]`` (row ``h`` non-zero on
     its KV head's lanes), ``k``/``v`` ``[tokens, Hkv*D]`` whole folded
     rows starting at position ``first``. The accumulator is ``[Hq,
     Hkv*D]``: row ``h``'s own head sits in its diagonal block, the other
-    lanes hold products with other heads' values and are never read."""
+    lanes hold products with other heads' values and are never read.
+    ``oldest`` (a window layer) is the first position the query sees."""
     m_prev, l_prev, acc = carry
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # [Hq, tokens] f32
     k_pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(k_pos <= pos, s, _NEG)
+    if oldest is not None:
+        s = jnp.where(k_pos >= oldest, s, _NEG)
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     correction = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
@@ -163,31 +182,52 @@ def _walk_kernel(
     block_pages: int,
     capacity: int,
     scale: float,
+    window: int | None,
     lens_ref,
     pt_ref,
-    q_ref,
-    k_hbm,
-    v_hbm,
-    o_ref,
-    kbuf,
-    vbuf,
-    wide_ref,
-    sem,
-    first_buf,
+    *refs,
 ):
+    # A window layer's table starts at position ``first_ref[slot]`` (a
+    # third prefetched scalar a slot) and the walk at the table's first
+    # page that holds a key inside the window; without a window the
+    # table starts at position 0 and so does the walk, and this traces
+    # to the kernel it always was.
+    first_ref = None
+    if window is not None:
+        first_ref, *refs = refs
+    q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, wide_ref, sem, first_buf = refs
     hq, folded = q_ref.shape[1:]
     tokens = block_pages * page_size
     b = pl.program_id(0)
     pos = lens_ref[b]
 
+    def oldest_page(slot):
+        # index, in the slot's table, of the page that holds the oldest
+        # key the slot's query sees
+        oldest = jnp.maximum(lens_ref[slot] - window + 1, first_ref[slot])
+        return (oldest - first_ref[slot]) // page_size
+
     def live_pages(slot):
         # Page i holds positions [i*page_size, (i+1)*page_size); the
         # slot's current token sits at ``pos``, so pages 0..pos//page_size
         # are live.
-        return jnp.minimum(lens_ref[slot] // page_size + 1, capacity)
+        if window is None:
+            return jnp.minimum(lens_ref[slot] // page_size + 1, capacity)
+        newest = (lens_ref[slot] - first_ref[slot]) // page_size
+        return jnp.clip(newest - oldest_page(slot) + 1, 1, capacity)
 
-    def copies(slot, blk, buf, j):
-        page = pt_ref[slot, blk * block_pages + j]
+    def block_start(slot, blk):
+        # (window only) table index of block ``blk``'s first page, worked
+        # out once a block and not once a page
+        return None if window is None else (
+            oldest_page(slot) + blk * block_pages
+        )
+
+    def copies(slot, blk, buf, j, at=None):
+        if window is None:
+            page = pt_ref[slot, blk * block_pages + j]
+        else:
+            page = pt_ref[slot, at + j]
         return [
             pltpu.make_async_copy(src.at[page], dst.at[buf, j], sem.at[buf])
             for src, dst in ((k_hbm, kbuf), (v_hbm, vbuf))
@@ -198,10 +238,11 @@ def _walk_kernel(
 
     def start(slot, blk, buf):
         n = live_in_block(slot, blk)
+        at = block_start(slot, blk)
 
         @pl.loop(0, n)
         def _copy(j):
-            for c in copies(slot, blk, buf, j):
+            for c in copies(slot, blk, buf, j, at):
                 c.start()
 
         # A page the walk does not copy keeps what the buffer held: its
@@ -212,9 +253,11 @@ def _walk_kernel(
             vbuf[buf, j] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
 
     def wait(slot, blk, buf):
+        at = block_start(slot, blk)
+
         @pl.loop(0, live_in_block(slot, blk))
         def _wait(j):
-            for c in copies(slot, blk, buf, j):
+            for c in copies(slot, blk, buf, j, at):
                 c.wait()
 
     num_blocks = (live_pages(b) + block_pages - 1) // block_pages
@@ -245,7 +288,13 @@ def _walk_kernel(
         wait(b, blk, buf)
         k = kbuf[buf].reshape(tokens, folded)
         v = vbuf[buf].reshape(tokens, folded)
-        return _attend(q_ref[0], k, v, blk * tokens, pos, scale, carry)
+        if window is None:
+            return _attend(q_ref[0], k, v, blk * tokens, pos, scale, carry)
+        return _attend(
+            q_ref[0], k, v,
+            first_ref[b] + oldest_page(b) * page_size + blk * tokens,
+            pos, scale, carry, oldest=pos - window + 1,
+        )
 
     # Position 0 is always visible (pos >= 0), so l > 0 — no NaN rows
     # even for freshly-admitted or parked slots.
@@ -326,6 +375,8 @@ def paged_attention(
     value_scale_pages: jax.Array | None = None,
     interpret: bool | None = None,
     pages_per_slot: int | None = None,
+    first_pos: jax.Array | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """One decode step of ``q`` [B, 1, Hq, D] against paged KV pools,
     reading only each slot's live pages (module docstring).
@@ -341,6 +392,15 @@ def paged_attention(
     table to its first N columns; the live length is a runtime fact (the
     walk's trip count, the position mask), never a shape, so the engine's
     fixed-shape step compiles once.
+
+    ``window`` (with ``first_pos`` ``[B]``): the query sees the keys at
+    positions ``pos - window + 1 .. pos`` only, and ``page_table`` lists
+    the pages from the one that holds position ``first_pos`` (a multiple
+    of ``page_size``) on: the engine's window page group. The walk
+    starts at the first page that holds a key inside the window and
+    masks by position inside the first and the last. Float pools with
+    whole-lane rows only. Without a window the lowered kernel is the one
+    it always was.
     """
     b, t, hq, d = q.shape
     if t != 1:
@@ -357,6 +417,14 @@ def paged_attention(
     quant = key_scale_pages is not None
     if quant != (value_scale_pages is not None):
         raise ValueError("pass both scale pools or neither")
+    if (window is None) != (first_pos is None):
+        raise ValueError("pass both window and first_pos or neither")
+    if window is not None and (quant or folded % 128):
+        raise ValueError(
+            "the window walks float pools whose folded row Hkv*D is a "
+            f"multiple of 128 lanes (got {folded}, int8={quant}): the "
+            "page-a-step path has no window"
+        )
     if interpret is None:
         from cs744_pytorch_distributed_tutorial_tpu.ops._backend import (
             default_interpret,
@@ -400,7 +468,7 @@ def paged_attention(
             pltpu.SMEM((1,), jnp.int32),
         ]
         kernel = partial(
-            _walk_kernel, page_size, block_pages, capacity, d**-0.5
+            _walk_kernel, page_size, block_pages, capacity, d**-0.5, window
         )
     else:
         def live_page(bi, i, lens, table):
@@ -423,10 +491,13 @@ def paged_attention(
         kernel = partial(
             _page_step_kernel, page_size, capacity, d**-0.5, quant
         )
+    prefetched = [pos.astype(jnp.int32), pt.astype(jnp.int32)]
+    if window is not None:
+        prefetched.append(first_pos.astype(jnp.int32))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(prefetched),
             grid=grid,
             in_specs=in_specs,
             out_specs=out_spec,
@@ -434,5 +505,5 @@ def paged_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), out_dtype),
         interpret=interpret,
-    )(pos.astype(jnp.int32), pt.astype(jnp.int32), *operands)
+    )(*prefetched, *operands)
     return out.reshape(b, 1, hq, d)

@@ -29,6 +29,14 @@ padding. This engine serves at REQUEST granularity instead:
   Admission guarantees any single request fits the pool alone, so the
   oldest request always completes — no deadlock.
 
+A model whose layers differ by kind (``TransformerLM.layer_types``:
+sliding-window and full attention mixed) is served from TWO page groups:
+the full layers' pages grow with the context as above, the window
+layers' live in a second, small pool under a second page table and are
+given back as the window passes them, in prefill and in decode
+(``window_pool``, ``_window_advance``; docs/serving.md). A model without
+window layers has one pool, one table and the programs it always had.
+
 Decode attention has two implementations (``paged_attention_impl``):
 the "gather" reference is BITWISE-identical to the dense-cache path (the
 gathered page view reproduces the cache layout exactly and runs the same
@@ -102,11 +110,15 @@ def _step_counters(stats: Any, active: jnp.ndarray, num_experts: int):
     behind the step's tokens: tokens the selection kept, tokens the
     indexer scored, experts that received a token, and a thousand times
     the layers' mean of (most tokens on one expert / mean tokens an
-    expert). None where the model sowed nothing."""
+    expert); behind them, from a model with window layers, two more:
+    keys attended on its full layers and on its window layers. None
+    where the model sowed nothing."""
     selected = _named_leaves(stats, "selected_tokens")
     scored = _named_leaves(stats, "scored_tokens")
     routed = _named_leaves(stats, "expert_idx")
-    if not (selected or routed):
+    full_read = _named_leaves(stats, "full_tokens_read")
+    window_read = _named_leaves(stats, "window_tokens_read")
+    if not (selected or routed or full_read):
         return None
     zero = jnp.int32(0)
 
@@ -125,9 +137,10 @@ def _step_counters(stats: Any, active: jnp.ndarray, num_experts: int):
             jnp.sum(counts), 1
         )
     milli = jnp.round(1e3 * ratio / max(len(routed), 1))
-    return jnp.stack(
-        [over_active(selected), over_active(scored), hit, milli]
-    ).astype(jnp.int32)
+    counters = [over_active(selected), over_active(scored), hit, milli]
+    if full_read:
+        counters += [over_active(full_read), over_active(window_read)]
+    return jnp.stack(counters).astype(jnp.int32)
 
 
 @dataclass
@@ -243,6 +256,11 @@ class _Slot:
     pages: list[int]
     last_tok: int
     admit_seq: int  # global admission counter — LIFO preemption order
+    # The window page group (a model with window layers): the slot's
+    # live window pages in sequence order, and the index of the first
+    # (it holds positions from ``window_first * page_size`` on).
+    window_pages: list[int] = field(default_factory=list)
+    window_first: int = 0
 
 
 @dataclass
@@ -335,10 +353,43 @@ class ServingEngine:
         # byte-identical to the unguarded engine.
         self.guard = guard
         self.pool = PagePool(cfg.num_pages, cfg.page_size)
+        # The window page group: a model with sliding-window layers
+        # keeps their K and V in pools of their own, under a second page
+        # table of ``window_table_width`` (P_w) pages a slot: what a
+        # window and a chunk can touch, whatever the context. Its pool
+        # holds num_slots * P_w + 1 pages, so it never runs dry and is
+        # no ServeConfig field. A model without such layers has no
+        # second pool, table or program argument.
+        self.window_pool: PagePool | None = None
+        self.window_table_width = 0
+        self._window = None
+        geometry: dict[str, Any] = {}
+        if getattr(model, "layer_types", None) and model.window_layers():
+            if not cfg.prefill_chunk:
+                raise ValueError(
+                    "a model with sliding-window layers is served by "
+                    "chunks: the one-shot prefill keeps a dense cache of "
+                    "every position, which a window layer neither needs "
+                    "nor masks. Set ServeConfig.prefill_chunk"
+                )
+            if mesh is not None:
+                raise ValueError(
+                    "a model with sliding-window layers serves on one "
+                    "device: the window page group is not sharded"
+                )
+            self._window = int(model.window)
+            self.window_table_width = (
+                -(-(self._window + cfg.prefill_chunk - 1) // cfg.page_size) + 1
+            )
+            self.window_pool = PagePool(
+                cfg.num_slots * self.window_table_width + 1, cfg.page_size
+            )
+            geometry["window_num_pages"] = self.window_pool.num_pages
         self.model = model.clone(
             page_size=cfg.page_size,
             num_pages=cfg.num_pages,
             paged_attention_impl=impl,
+            **geometry,
         )
         self.max_seq_len = model.max_seq_len
         self._scanned = bool(getattr(model, "scan_layers", False))
@@ -347,6 +398,11 @@ class ServingEngine:
         self._queue: deque[Request] = deque()
         self._slots: list[_Slot | None] = [None] * b
         self._page_table = np.zeros((b, p), np.int32)  # 0 = trash page
+        self._window_table = np.zeros((b, self.window_table_width), np.int32)
+        self._window_first = np.zeros((b,), np.int32)  # first row's position
+        self._window_pages_freed = 0  # given back as the window passed
+        self._full_tokens_read = 0  # keys attended, by layer kind
+        self._window_tokens_read = 0
         self._next_id = 0
         self._admit_seq = 0
         self._step_count = 0
@@ -463,9 +519,22 @@ class ServingEngine:
                 mode="paged_decode",
                 decode_pos=jnp.zeros((b,), jnp.int32),
                 page_table=jnp.zeros((b, p), jnp.int32),
+                **self._window_kw(
+                    jnp.zeros((b, self.window_table_width), jnp.int32),
+                    jnp.zeros((b,), jnp.int32),
+                ),
             )["pages"]
 
         return jax.eval_shape(init_fn)
+
+    def _window_kw(self, *window: Any) -> dict[str, Any]:
+        """The model's keywords for the window group's table and its
+        first row's positions; none for a model without window layers
+        (``window`` is then empty or not looked at)."""
+        if self.window_pool is None:
+            return {}
+        table, first = window
+        return {"window_page_table": table, "window_first_pos": first}
 
     def _jit_pages_program(self, fn, n_replicated: int):
         """``jax.jit`` of one of the engine's programs, ``fn(params,
@@ -501,7 +570,7 @@ class ServingEngine:
 
         def step(
             params, pages, tokens, lengths, page_table, active, req_ids,
-            tok_idx, key,
+            tok_idx, key, *window,
         ):
             logits, mutated = model.apply(
                 {"params": params, "pages": pages},
@@ -510,6 +579,7 @@ class ServingEngine:
                 decode_pos=lengths,
                 page_table=page_table,
                 mutable=["pages", "serve_stats"],
+                **self._window_kw(*window),
             )
             # Per-slot sampling keys from the (request, token-index)
             # stream — see _sample_root. ``key`` is the constant stream
@@ -628,7 +698,7 @@ class ServingEngine:
         model = self.model
 
         def prefill_chunk(params, pages, tokens, offset, page_row, last_idx,
-                          key):
+                          key, *window):
             logits, mutated = model.apply(
                 {"params": params, "pages": pages},
                 tokens,
@@ -637,6 +707,7 @@ class ServingEngine:
                 page_table=page_row[None],
                 logits_at=last_idx[None],
                 mutable=["pages"],
+                **self._window_kw(*(w[None] for w in window)),
             )
             tok = sample_tokens(
                 logits[:, 0].astype(jnp.float32),
@@ -870,12 +941,51 @@ class ServingEngine:
         slot = self._slots[i]
         self.pool.free(slot.pages)
         self._page_table[i, :] = 0
+        if self.window_pool is not None:
+            self.window_pool.free(slot.window_pages)
+            self._window_table[i, :] = 0
+            self._window_first[i] = 0
         self._slots[i] = None
         if __debug__:
             # Every page-freeing path (retire, preempt, deadline expiry)
             # funnels through here — audit the free-list/live accounting
             # at the moment a leak or double-lease would be introduced.
             self.pool.check_invariants()
+            if self.window_pool is not None:
+                self.window_pool.check_invariants()
+
+    def _window_advance(
+        self, pages: list[int], first: int, oldest: int, newest: int
+    ) -> tuple[int, int]:
+        """Move one slot's window pages on: give back those that lie
+        wholly behind position ``oldest`` (the first a coming query
+        sees), lease those needed to hold position ``newest``. ``pages``
+        is edited in place; returns (index of its first page, pages
+        given back). The group's pool cannot run dry: a slot never holds
+        more than ``window_table_width`` pages, and the pool has that
+        many a slot."""
+        size = self.cfg.page_size
+        keep_from = max(oldest, 0) // size
+        dropped = min(max(keep_from - first, 0), len(pages))
+        if dropped:
+            self.window_pool.free(pages[:dropped])
+            del pages[:dropped]
+        if not pages:
+            first = keep_from
+        else:
+            first += dropped
+        wanted = newest // size + 1 - (first + len(pages))
+        if wanted > 0:
+            pages.extend(self.window_pool.alloc(wanted))
+        assert len(pages) <= self.window_table_width, (len(pages), oldest, newest)
+        return first, dropped
+
+    def _can_admit(self, prompt_len: int) -> bool:
+        """Pages for the prompt are free, in each group that exists."""
+        ok = self.pool.can_alloc(max(1, self.pool.pages_for(prompt_len)))
+        if ok and self.window_pool is not None:
+            ok = self.window_pool.can_alloc(self.window_table_width)
+        return ok
 
     def _ensure_pages(self, n: int) -> bool:
         """Make n pages allocatable, preempting LIFO as needed."""
@@ -925,6 +1035,8 @@ class ServingEngine:
                     jax.random.fold_in(self._sample_root, req.req_id),
                     req.output_tokens,
                 )
+                window_pages: list[int] = []
+                window_first = 0
                 if chunk:
                     prefill = self._chunk_fn()
                     row_dev = jnp.asarray(row)
@@ -944,6 +1056,27 @@ class ServingEngine:
                     for ci in range(n_chunks):
                         off = ci * chunk
                         n = min(chunk, plen - off)
+                        window_args = ()
+                        if self.window_pool is not None:
+                            # The chunk's queries see back to
+                            # off - window + 1: what lies behind goes
+                            # back, what the chunk writes is leased.
+                            with profiling.annotate(
+                                "serve/window_free", req=req.req_id
+                            ) as free_span:
+                                window_first, freed = self._window_advance(
+                                    window_pages, window_first,
+                                    off - self._window + 1, off + n - 1,
+                                )
+                                self._window_pages_freed += freed
+                                self._set_window_row(
+                                    slot_idx, window_pages, window_first
+                                )
+                                window_args = (
+                                    jnp.asarray(self._window_table[slot_idx]),
+                                    jnp.asarray(self._window_first[slot_idx]),
+                                )
+                                free_span.set_metadata(pages=freed)
                         with profiling.annotate(
                             "serve/prefill_chunk", req=req.req_id, chunk=ci,
                             offset=off, len=n,
@@ -952,7 +1085,7 @@ class ServingEngine:
                                 self.params, self._pages,
                                 jnp.asarray(prompt[:, off:off + chunk]),
                                 jnp.int32(off), row_dev, jnp.int32(n - 1),
-                                key,
+                                key, *window_args,
                             )
                             tok = int(first_tok)
                     self._prefill_chunks += n_chunks
@@ -986,7 +1119,8 @@ class ServingEngine:
             self._admit_seq += 1
             self._slots[slot_idx] = _Slot(
                 req=req, length=plen, pages=pages, last_tok=tok,
-                admit_seq=self._admit_seq,
+                admit_seq=self._admit_seq, window_pages=window_pages,
+                window_first=window_first,
             )
             self._page_table[slot_idx, :] = row
             if self._slot_done(self._slots[slot_idx]):
@@ -1133,8 +1267,7 @@ class ServingEngine:
                 break
             if self._slots[i] is not None:
                 continue
-            plen = int(self._queue[0].prompt.size)
-            if not self.pool.can_alloc(max(1, self.pool.pages_for(plen))):
+            if not self._can_admit(int(self._queue[0].prompt.size)):
                 break
             self._admit(i, self._queue.popleft())
             admits += 1
@@ -1175,6 +1308,9 @@ class ServingEngine:
         cfg = self.cfg
         t_d0 = self.clock()
         with profiling.annotate("serve/decode_prep", step=step):
+            window_args = ()
+            if self.window_pool is not None:
+                window_args = self._window_step(step)
             tokens = np.full((cfg.num_slots,), cfg.pad_id, np.int32)
             lengths = np.zeros((cfg.num_slots,), np.int32)
             active = np.zeros((cfg.num_slots,), bool)
@@ -1202,7 +1338,8 @@ class ServingEngine:
             )
         with profiling.annotate("serve/decode", step=step, active=n_active):
             self._pages, toks = self._decode_step(
-                self.params, self._pages, *args, self._sample_root
+                self.params, self._pages, *args, self._sample_root,
+                *window_args,
             )
             toks = np.asarray(toks)  # graftlint: disable=GL001 -- the scheduler NEEDS this sync: retire/refill decisions read the sampled tokens; one fetch per engine step, outside any jit
             toks, counters = toks[: cfg.num_slots], toks[cfg.num_slots:]
@@ -1225,6 +1362,9 @@ class ServingEngine:
                 self._scored_tokens += int(counters[1])
                 self._experts_hit += int(counters[2])
                 self._expert_ratio_sum += float(counters[3]) / 1e3
+                if counters.size > 4:
+                    self._full_tokens_read += int(counters[4])
+                    self._window_tokens_read += int(counters[5])
             # Inactive slots still write one KV row per step — to the
             # trash page (fixed-shape contract).
             self._trash_rows += cfg.num_slots - n_active
@@ -1262,6 +1402,41 @@ class ServingEngine:
                 retired=len(self._completed) - done_at_fetch
             )
             return self._completed[done_before:]
+
+    def _set_window_row(self, i: int, pages: list[int], first: int) -> None:
+        """Slot ``i``'s row of the window group's table: its live window
+        pages in sequence order, and the position of the first's row."""
+        row = self._window_table[i]
+        row[:] = 0
+        row[: len(pages)] = pages
+        self._window_first[i] = first * self.cfg.page_size
+
+    def _window_step(self, step: int) -> tuple[Any, Any]:
+        """Before a decode step of a model with window layers: every
+        active slot gives back the window pages its next query no longer
+        sees and leases the page its next row needs; returns the group's
+        table and first positions on the device."""
+        with profiling.annotate("serve/window_free", step=step) as span:
+            freed = 0
+            for i, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                held = len(slot.window_pages)
+                slot.window_first, dropped = self._window_advance(
+                    slot.window_pages, slot.window_first,
+                    slot.length - self._window + 1, slot.length,
+                )
+                if dropped or len(slot.window_pages) != held:
+                    self._set_window_row(
+                        i, slot.window_pages, slot.window_first
+                    )
+                freed += dropped
+            self._window_pages_freed += freed
+            span.set_metadata(pages=freed)
+            return (
+                jnp.asarray(self._window_table),
+                jnp.asarray(self._window_first),
+            )
 
     def run(self) -> list[Request]:
         """Drain: step until the queue and every slot are empty."""
@@ -1458,6 +1633,17 @@ class ServingEngine:
             # mean over decode steps of the layers' mean of (most tokens
             # on one expert / mean tokens an expert)
             "expert_tokens_max_over_mean": self._expert_ratio_sum / steps,
+            # the page groups: live pages of each now, window pages given
+            # back as the window passed them, and the keys the decode
+            # steps attended on the layers of each kind (a model with
+            # window layers sows them; 0 otherwise)
+            "pages_live_full": self.pool.allocated_pages,
+            "pages_live_window": (
+                self.window_pool.allocated_pages if self.window_pool else 0
+            ),
+            "window_pages_freed": self._window_pages_freed,
+            "full_tokens_read": self._full_tokens_read,
+            "window_tokens_read": self._window_tokens_read,
         }
 
 

@@ -91,14 +91,19 @@ def compile_programs(
     params, pages = shapes(engine.params), shapes(engine._pages)
     key = shapes(engine._sample_root)
     i32 = jnp.int32
+    # a model with window layers: the second group's table and the
+    # position of its first row, a slot (decode) or the one slot (chunk)
+    w = engine.window_table_width
     decode = engine._decode_step.lower(
         params, pages, s((b,), i32), s((b,), i32), s((b, p), i32),
         s((b,), jnp.bool_), s((b,), i32), s((b,), i32), key,
+        *((s((b, w), i32), s((b,), i32)) if w else ()),
     ).compile()
     if cfg.prefill_chunk:
         prefill = engine._chunk_fn().lower(
             params, pages, s((1, cfg.prefill_chunk), i32), s((), i32),
             s((p,), i32), s((), i32), key,
+            *((s((w,), i32), s((), i32)) if w else ()),
         ).compile()
     else:
         prefill = engine._prefill_fn(bucket).lower(

@@ -55,14 +55,19 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--use-rope", action="store_true")
     p.add_argument("--quant-kv", action="store_true",
                    help="int8 KV pages (ops/quant.py::quantize_kv)")
-    p.add_argument("--keye-config", default=None, metavar="CONFIG.JSON",
-                   help="build the model from a KeyeVL2 config.json's "
-                        "language-model keys (models/hf_interop.py::"
-                        "keye_model_config: gated experts, q/k norm, the "
-                        "sparse-attention indexer) in place of the size "
-                        "flags above; --max-seq-len still caps a request. "
-                        "Random params, as ever; no --parity-check (the "
-                        "dense-cache generator has no indexer)")
+    p.add_argument("--model-config", "--keye-config", dest="model_config",
+                   default=None, metavar="CONFIG.JSON",
+                   help="build the model from a published config.json, the "
+                        "builder picked by its model_type (models/"
+                        "hf_interop.py::model_config_from_hf: KeyeVL2 = gated "
+                        "experts, q/k norm, the sparse-attention indexer; "
+                        "mellum = gated experts, q/k norm, sliding-window "
+                        "and full layers mixed, YaRN on the full ones) in "
+                        "place of the size flags above; --max-seq-len still "
+                        "caps a request. Random params, as ever; no "
+                        "--parity-check (the dense-cache generator has no "
+                        "indexer and no window); window layers need "
+                        "--prefill-chunk")
     # engine geometry
     p.add_argument("--num-slots", type=int, default=8,
                    help="decode slots B in the fixed-shape jitted step")
@@ -223,16 +228,16 @@ def main(argv: list[str] | None = None) -> None:
     )
     from cs744_pytorch_distributed_tutorial_tpu.utils import profiling
 
-    if args.keye_config:
+    if args.model_config:
         import json
 
         from cs744_pytorch_distributed_tutorial_tpu.models.hf_interop import (
-            keye_model_config,
+            model_config_from_hf,
         )
 
-        with open(args.keye_config, encoding="utf-8") as f:
+        with open(args.model_config, encoding="utf-8") as f:
             model = TransformerLM(
-                **keye_model_config(json.load(f), max_seq_len=args.max_seq_len)
+                **model_config_from_hf(json.load(f), max_seq_len=args.max_seq_len)
             )
         args.vocab_size = model.vocab_size
     else:

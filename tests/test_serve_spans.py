@@ -371,3 +371,81 @@ def test_chunked_prefill_spans_and_counters(tmp_path):
     assert stats1["selected_tokens"] - stats0["selected_tokens"] == 2 * 8 * len(depths)
     assert stats1["experts_hit"] > stats0["experts_hit"]
     assert 1.0 <= stats1["expert_tokens_max_over_mean"] <= 4.0
+
+
+def test_window_free_spans_and_counters(tmp_path):
+    """A model with sliding-window layers keeps a second page group; the
+    host work of moving it on is a ``serve/window_free`` span: one before
+    each chunk program inside the admission (``req``, ``pages``), one a
+    decode step inside ``serve/decode_prep`` (``step``, ``pages``). The
+    spans' ``pages`` add up to ``stats()``'s ``window_pages_freed``, and
+    the decode steps' device-side counters of keys attended ride behind
+    the step's tokens: no new transfer, no compile after warm-up. A model
+    without such layers writes no such span."""
+    kinds = ("sliding_attention",) * 3 + ("full_attention",)
+    model = TransformerLM(
+        vocab_size=VOCAB, num_layers=4, num_heads=2, d_model=32, d_ff=16,
+        max_seq_len=96, attention_impl="dense", use_rope=True,
+        norm="rmsnorm", mlp="swiglu", num_experts=4, moe_top_k=2,
+        moe_dispatch="dropless", moe_bias=False, qk_norm=True,
+        layer_types=kinds, window=8,
+    )
+    params = model.init(
+        jax.random.key(0), jnp.zeros((1, 4), jnp.int32)
+    )["params"]
+    eng = ServingEngine(model, params, ServeConfig(
+        num_slots=2, page_size=4, num_pages=65, max_pages_per_slot=20,
+        prefill_chunk=8,
+    ))
+    prompts = {0: 37, 1: 8, 2: 50}  # 5, 1 and 7 chunks of 8
+    rng = np.random.default_rng(5)
+
+    def submit_all():
+        return [
+            eng.submit(Request(
+                prompt=rng.integers(1, VOCAB, size=n).astype(np.int32),
+                max_new_tokens=6,
+            ))
+            for n in prompts.values()
+        ]
+
+    submit_all()
+    _drive(eng)  # warm-up: the one chunk program and the decode step
+    compiles = CompileCounter()
+    stats0 = eng.stats()
+    with profiling.trace(str(tmp_path)):
+        reqs = submit_all()
+        _drive(eng)
+    stats1 = eng.stats()
+    assert compiles.count == 0
+    spans = _read_spans(tmp_path)
+    frees = _named(spans, "serve/window_free")
+    admits = _named(spans, "serve/admit")
+    in_admit = 0
+    for admit, req, n in zip(admits, reqs, prompts.values()):
+        mine = _children(spans, admit, "serve/window_free")
+        chunks = _children(spans, admit, "serve/prefill_chunk")
+        assert len(mine) == len(chunks) == -(-n // 8)
+        # each before its chunk, inside the admission's prefill
+        (prefill,) = _children(spans, admit, "serve/prefill")
+        assert all(_inside(f, prefill) and f["hi"] <= ch["lo"] for f, ch in zip(mine, chunks))
+        assert {f["req"] for f in mine} == {req.req_id}
+        # chunk c sees back to 8 c - 7: the pages before that one's go back
+        assert sum(f["pages"] for f in mine) == max(0, (8 * (len(chunks) - 1) - 7) // 4)
+        in_admit += len(mine)
+    preps = _named(spans, "serve/decode_prep")
+    for prep in preps:
+        (free,) = _children(spans, prep, "serve/window_free")
+        assert free["step"] == prep["step"] and free["pages"] >= 0
+    assert len(frees) == in_admit + len(preps)
+    freed = stats1["window_pages_freed"] - stats0["window_pages_freed"]
+    assert sum(f["pages"] for f in frees) == freed > 0
+    assert stats1["pages_live_window"] == stats1["pages_live_full"] == 0
+    # keys attended by the decode steps: 5 steps a request at depths n ..
+    depths = [n + i for n in prompts.values() for i in range(5)]
+    assert stats1["full_tokens_read"] - stats0["full_tokens_read"] == sum(d + 1 for d in depths)
+    assert stats1["window_tokens_read"] - stats0["window_tokens_read"] == 3 * 8 * len(depths)
+
+
+def test_a_model_without_window_layers_writes_no_window_free_span(capture):
+    assert not _named(capture["spans"], "serve/window_free")

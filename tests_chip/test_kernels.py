@@ -240,6 +240,75 @@ def test_paged_attention_serve_cell_shape():
     assert_close(got, want, 3e-2)
 
 
+def test_paged_attention_mixed_cell_shapes():
+    """The mixed cell's two calls (32 slots, 32 heads of 128 over 4 KV
+    heads, pages of 16, bf16). A window layer's: the window group's
+    table of 97 pages a slot, the walk started inside it by
+    ``first_pos`` and ``window`` 1,024, slots from a fresh one to one 33k
+    deep, the table's first page well behind the window in some. A full
+    layer's: the full group's whole ``[32, 2080]`` table as a
+    scalar-prefetched operand, depths to 33,279."""
+    from cs744_pytorch_distributed_tutorial_tpu.ops.sparse_attention import (
+        masked_attention,
+    )
+    from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
+        gather_pages,
+        unfold_heads,
+    )
+
+    slots, window, width = 32, 1024, 97
+    q = _normal(0, (slots, 1, 32, 128), jnp.bfloat16)
+    rng = np.random.default_rng(2)
+    # ---- a window layer
+    pages = slots * width + 1
+    k = _normal(1, (pages, PAGE, 512), jnp.bfloat16)
+    v = _normal(2, (pages, PAGE, 512), jnp.bfloat16)
+    table = jnp.asarray(
+        (1 + rng.permutation(pages - 1)).reshape(slots, width), jnp.int32
+    )
+    pos = rng.integers(0, 33_279, slots)
+    pos[:8] = [0, 15, 16, 1022, 1023, 1024, 1039, 33_279]
+    # the table starts up to 32 pages behind the page of the oldest key
+    oldest_page = np.maximum(pos - window + 1, 0) // PAGE
+    first = (np.maximum(oldest_page - rng.integers(0, 33, slots), 0)) * PAGE
+    pos, first = jnp.asarray(pos, jnp.int32), jnp.asarray(first, jnp.int32)
+    got = jax.jit(lambda *a: paged_attention(
+        *a[:5], first_pos=a[5], window=window, interpret=False
+    ))(q, k, v, table, pos, first)
+    rel = (pos - first)[:, None]
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda q, k, v: masked_attention(
+            q,
+            unfold_heads(gather_pages(k, table), 128),
+            unfold_heads(gather_pages(v, table), 128),
+            rel,
+            jnp.arange(width * PAGE)[None, None, :] > rel[:, :, None] - window,
+        ))(*(x.astype(jnp.float32) for x in (q, k, v)))
+    assert_close(got, want, 3e-2)
+    # ---- a full layer: the whole table rides in SMEM
+    capacity = 2080
+    pages = slots * capacity + 1
+    k = _normal(3, (pages, PAGE, 512), jnp.bfloat16)
+    v = _normal(4, (pages, PAGE, 512), jnp.bfloat16)
+    table = jnp.asarray(
+        (1 + rng.permutation(pages - 1)).reshape(slots, capacity), jnp.int32
+    )
+    pos = rng.integers(0, capacity * PAGE, slots)
+    pos[:4] = [0, 255, 256, capacity * PAGE - 1]
+    pos = jnp.asarray(pos, jnp.int32)
+    got = jax.jit(
+        lambda *a: paged_attention(*a, interpret=False)
+    )(q, k, v, table, pos)
+    # the reference a slot at a time: the dense view of 33k keys in f32
+    for b in (0, 1, 2, 3, 17):
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(paged_decode_attention)(
+                q[b:b + 1].astype(jnp.float32), k.astype(jnp.float32),
+                v.astype(jnp.float32), table[b:b + 1], pos[b:b + 1],
+            )
+        assert_close(got[b:b + 1], want, 3e-2)
+
+
 @pytest.fixture(scope="module")
 def serve_cell_programs():
     from cs744_pytorch_distributed_tutorial_tpu.models import TransformerLM
