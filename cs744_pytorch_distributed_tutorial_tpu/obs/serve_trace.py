@@ -812,9 +812,9 @@ def profile_serve_programs(
         fn = engine._prefill_cache[bucket]
         plen = min(bucket, engine.max_seq_len - 1)
         pf_args = (
-            jnp.ones((1, bucket), jnp.int32),
-            jnp.int32(plen),
-            jnp.zeros((p,), jnp.int32),
+            engine._pack_program_arg(
+                bucket, np.ones((plen,), np.int32), (plen, 0, 0), []
+            ),
             key,
         )
         state = {"pages": fresh_pages()}

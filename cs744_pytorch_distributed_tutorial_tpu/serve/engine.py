@@ -22,7 +22,10 @@ padding. This engine serves at REQUEST granularity instead:
   program, so the hand-off to the decode pool is a device-side commit.
   Because it is a separate program from decode, running it on separate
   mesh slices (prefill/decode disaggregation) is a deployment choice,
-  not a code change;
+  not a code change. An admission never waits for the device: it costs
+  the host one packed put and one dispatch (a chunk), the step's
+  admissions are enqueued back to back, and their first tokens come
+  back in ONE blocking fetch after the last is enqueued;
 - when the pool runs dry the engine PREEMPTS the most recently admitted
   slot (LIFO victim): its pages free instantly and the request re-queues
   with prompt+generated as the new prompt (recompute-style preemption).
@@ -264,6 +267,24 @@ class _Slot:
 
 
 @dataclass
+class _Admission:
+    """An admission whose programs are enqueued and whose first token is
+    still on the device: what ``_complete_admit`` needs once the step's
+    one fetch has brought the token. Until then the request is in no
+    slot, no table row is written, and only the pools know its pages."""
+
+    slot_idx: int
+    req: Request
+    kind: str  # the tracer's span vocabulary: prefill / recompute / ...
+    t_admit: float
+    bucket: int
+    pages: list[int]
+    first_tok: Any  # device scalar: the (last chunk's) sampled token
+    window_pages: list[int]  # as in _Slot
+    window_first: int
+
+
+@dataclass
 class ServeSnapshot:
     """Recoverable image of an engine's request state (not its KV).
 
@@ -426,6 +447,7 @@ class ServingEngine:
         # so stats() gives admissions per step without a trace.
         self._admissions = 0
         self._admit_steps = 0  # steps that admitted at least one request
+        self._admit_fetches = 0  # blocking first-token fetches made
         self._max_admits_in_step = 0
         self._pages_grown = 0  # pages the grow loop allocated
         self._prefill_chunks = 0  # chunk programs run (prefill_chunk set)
@@ -615,8 +637,10 @@ class ServingEngine:
         causal pass over the padded prompt, sample the first token from
         the true last position, scatter the prompt's KV rows into the
         slot's pages. One trace per bucket (buckets are powers of two —
-        a bounded set); true_len/page_row are traced arrays, so every
-        prompt in the bucket reuses the executable."""
+        a bounded set); prompt, true length, page row and the sampling
+        stream's two integers arrive in one traced vector
+        (``_pack_program_arg``), so every prompt in the bucket reuses
+        the executable."""
         cached = self._prefill_cache.get(bucket)
         if cached is not None:
             return cached
@@ -666,7 +690,10 @@ class ServingEngine:
 
             return walk(pages, cache)
 
-        def prefill(params, pages, prompt, true_len, page_row, key):
+        def prefill(params, pages, packed, key):
+            prompt, (true_len,), page_row, key, _ = self._unpack_program_arg(
+                packed, key, bucket, 3
+            )
             logits, mutated = model.apply(
                 {"params": params}, prompt, mode="prefill", mutable=["cache"]
             )
@@ -681,7 +708,7 @@ class ServingEngine:
             pages = commit(pages, mutated["cache"], page_row, true_len)
             return pages, tok[0].astype(jnp.int32)
 
-        fn = self._jit_pages_program(prefill, 4)
+        fn = self._jit_pages_program(prefill, 2)
         self._prefill_cache[bucket] = fn
         return fn
 
@@ -689,16 +716,19 @@ class ServingEngine:
         """The one jitted prefill program of a chunked engine: ``chunk``
         tokens of one slot at ``offset``, written into the slot's pages
         and attended over pages plus chunk (mode="paged_prefill"), and
-        the token sampled at ``last_idx`` of the chunk. Offset, page row
-        and index are traced, so every chunk of every prompt runs the
+        the token sampled at ``last_idx`` of the chunk. Tokens, offset,
+        index and page rows arrive in one traced vector
+        (``_pack_program_arg``), so every chunk of every prompt runs the
         same executable."""
         if self._chunk_program is not None:
             return self._chunk_program
         cfg = self.cfg
         model = self.model
 
-        def prefill_chunk(params, pages, tokens, offset, page_row, last_idx,
-                          key, *window):
+        def prefill_chunk(params, pages, packed, key):
+            tokens, (offset, last_idx), page_row, key, window = (
+                self._unpack_program_arg(packed, key, cfg.prefill_chunk, 4)
+            )
             logits, mutated = model.apply(
                 {"params": params, "pages": pages},
                 tokens,
@@ -718,8 +748,59 @@ class ServingEngine:
             )
             return mutated["pages"], tok[0].astype(jnp.int32)
 
-        self._chunk_program = self._jit_pages_program(prefill_chunk, 5)
+        self._chunk_program = self._jit_pages_program(prefill_chunk, 2)
         return self._chunk_program
+
+    # The prefill programs take ONE int32 vector beside the pools and
+    # the constant stream root, so an admission (a chunk) is one put:
+    #   [tokens, padded to ``width`` | scalars | page row |
+    #    window row | window first pos]
+    # (the last two of a model with window layers only). The bucket
+    # program's scalars are (true_len, req_id, token index), the chunk
+    # program's (offset, last_idx, req_id, token index): the last two
+    # are the sampling stream's, and the programs fold the key from
+    # them as the decode step does.
+
+    def _program_arg_len(self, width: int, n_scalars: int) -> int:
+        w = self.window_table_width
+        return (
+            width + n_scalars + self.cfg.max_pages_per_slot
+            + (w + 1 if w else 0)
+        )
+
+    def _pack_program_arg(
+        self, width: int, tokens: np.ndarray, scalars: tuple[int, ...],
+        pages: Any, window_pages: Any = (), window_first: int = 0,
+    ) -> np.ndarray:
+        packed = np.zeros(
+            (self._program_arg_len(width, len(scalars)),), np.int32
+        )
+        packed[: tokens.size] = tokens
+        row = width + len(scalars)
+        packed[width:row] = scalars
+        packed[row: row + len(pages)] = pages
+        if self.window_pool is not None:
+            w0 = row + self.cfg.max_pages_per_slot
+            packed[w0: w0 + len(window_pages)] = window_pages
+            packed[-1] = window_first * self.cfg.page_size
+        return packed
+
+    def _unpack_program_arg(self, packed, key, width: int, n_scalars: int):
+        """Inside a program: (tokens [1, width], the scalars before the
+        stream's two, page row, this token's key, the window group's
+        (row, first position) or ())."""
+        row = width + n_scalars
+        row_end = row + self.cfg.max_pages_per_slot
+        key = jax.random.fold_in(
+            jax.random.fold_in(key, packed[row - 2]), packed[row - 1]
+        )
+        window = ()
+        if self.window_pool is not None:
+            window = (packed[row_end:-1], packed[-1])
+        return (
+            packed[None, :width], packed[width: row - 2],
+            packed[row:row_end], key, window,
+        )
 
     @staticmethod
     def _bucket_for(n: int) -> int:
@@ -994,7 +1075,12 @@ class ServingEngine:
                 return False
         return True
 
-    def _admit(self, slot_idx: int, req: Request) -> None:
+    def _admit(self, slot_idx: int, req: Request) -> _Admission:
+        """Lease the prompt's pages and ENQUEUE its prefill (every chunk
+        of it): one packed put and one dispatch a program, no fetch, so
+        the host prepares the next admission while the device runs this
+        one. The first token stays on the device until the step's one
+        fetch (``_step``); ``_complete_admit`` then makes the slot."""
         t_admit = self.clock()
         # Span vocabulary for this admission (obs/serve_trace.py): a
         # first admission is a plain prefill, a preempted request's
@@ -1018,49 +1104,50 @@ class ServingEngine:
             bucket=bucket, kind=admit_kind, prompt_len=plen,
         ):
             with profiling.annotate("serve/admit_prep", req=req.req_id):
-                replayed = max(0, plen - req.orig_prompt_len)
                 need = max(1, self.pool.pages_for(plen))
                 pages = self.pool.alloc(need)
-                row = np.zeros((self.cfg.max_pages_per_slot,), np.int32)
-                row[: len(pages)] = pages
                 n_chunks = -(-plen // chunk) if chunk else 1
-                prompt = np.zeros((1, n_chunks * bucket), np.int32)
-                prompt[0, :plen] = req.prompt
-                # The (request, token-index) stream — a recompute-preempted
+                # The (request, token-index) stream: a recompute-preempted
                 # request's re-prefill samples token index
                 # ``output_tokens`` (the first NOT-yet-produced one) from
                 # the same key a decode step would have used, so replay
-                # reproduces the original tokens at any temperature.
-                key = jax.random.fold_in(
-                    jax.random.fold_in(self._sample_root, req.req_id),
-                    req.output_tokens,
-                )
+                # reproduces the original tokens at any temperature. The
+                # programs fold it from the two integers themselves.
+                tok_idx = req.output_tokens
                 window_pages: list[int] = []
                 window_first = 0
+                row = np.asarray(pages, np.int32)
+                # Rows of the padded prompt past its pages scatter to
+                # trash (a chunked prompt's padding first fills its last
+                # page's tail, which decode overwrites).
+                self._trash_rows += (
+                    max(0, n_chunks * chunk - need * self.cfg.page_size)
+                    if chunk else bucket - plen
+                )
                 if chunk:
                     prefill = self._chunk_fn()
-                    row_dev = jnp.asarray(row)
                 else:
                     prefill = self._prefill_fn(bucket)
-                    args = (
-                        jnp.asarray(prompt), jnp.int32(plen),
-                        jnp.asarray(row),
+                    packed = self._pack_program_arg(
+                        bucket, req.prompt, (plen, req.req_id, tok_idx), row
                     )
             with profiling.annotate(
                 "serve/prefill", req=req.req_id, bucket=bucket
             ):
                 if chunk:
-                    # Every chunk inside this admission, one program and
-                    # one blocking fetch each; the last chunk's token is
-                    # the request's first.
+                    # Every chunk inside this admission, one program each
+                    # and no fetch; the last chunk's token is the
+                    # request's first.
                     for ci in range(n_chunks):
                         off = ci * chunk
                         n = min(chunk, plen - off)
-                        window_args = ()
                         if self.window_pool is not None:
                             # The chunk's queries see back to
                             # off - window + 1: what lies behind goes
-                            # back, what the chunk writes is leased.
+                            # back, what the chunk writes is leased. The
+                            # device runs programs in order, so a page
+                            # given back and leased again is written only
+                            # after the chunks that read it.
                             with profiling.annotate(
                                 "serve/window_free", req=req.req_id
                             ) as free_span:
@@ -1069,13 +1156,6 @@ class ServingEngine:
                                     off - self._window + 1, off + n - 1,
                                 )
                                 self._window_pages_freed += freed
-                                self._set_window_row(
-                                    slot_idx, window_pages, window_first
-                                )
-                                window_args = (
-                                    jnp.asarray(self._window_table[slot_idx]),
-                                    jnp.asarray(self._window_first[slot_idx]),
-                                )
                                 free_span.set_metadata(pages=freed)
                         with profiling.annotate(
                             "serve/prefill_chunk", req=req.req_id, chunk=ci,
@@ -1083,48 +1163,61 @@ class ServingEngine:
                         ):
                             self._pages, first_tok = prefill(
                                 self.params, self._pages,
-                                jnp.asarray(prompt[:, off:off + chunk]),
-                                jnp.int32(off), row_dev, jnp.int32(n - 1),
-                                key, *window_args,
+                                self._pack_program_arg(
+                                    chunk, req.prompt[off:off + n],
+                                    (off, n - 1, req.req_id, tok_idx), row,
+                                    window_pages, window_first,
+                                ),
+                                self._sample_root,
                             )
-                            tok = int(first_tok)
                     self._prefill_chunks += n_chunks
                 else:
                     self._pages, first_tok = prefill(
-                        self.params, self._pages, *args, key
+                        self.params, self._pages, packed, self._sample_root
                     )
-                    tok = int(first_tok)  # blocks — the first token
-            now = self.clock()
-            first = req.first_token_time is None
+        return _Admission(
+            slot_idx=slot_idx, req=req, kind=admit_kind, t_admit=t_admit,
+            bucket=bucket, pages=pages, first_tok=first_tok,
+            window_pages=window_pages, window_first=window_first,
+        )
+
+    def _complete_admit(self, adm: _Admission, tok: int) -> None:
+        """The bookkeeping that needs an admission's first token, once
+        the step's fetch has brought it: stamps, surfacing, the tracer's
+        hooks, the slot and its table rows, retiring a request that is
+        already done."""
+        req, slot_idx = adm.req, adm.slot_idx
+        plen = int(req.prompt.size)
+        now = self.clock()
+        first = req.first_token_time is None
+        if first:
+            req.first_token_time = now
+        if self.tracer is not None:
+            self.tracer.on_admit(
+                req, slot=slot_idx, bucket=adm.bucket, t0=adm.t_admit,
+                t1=now, kind=adm.kind,
+                replayed=max(0, plen - req.orig_prompt_len),
+            )
             if first:
-                req.first_token_time = now
-            # Rows of the padded prompt past its pages scatter to trash
-            # (a chunked prompt's padding first fills its last page's
-            # tail, which decode overwrites).
-            self._trash_rows += (
-                max(0, prompt.shape[1] - need * self.cfg.page_size)
-                if chunk else bucket - plen
-            )
-            if self.tracer is not None:
-                self.tracer.on_admit(
-                    req, slot=slot_idx, bucket=bucket, t0=t_admit, t1=now,
-                    kind=admit_kind, replayed=replayed,
+                self.tracer.sample_ttft(
+                    (now - req.arrival_time) * 1e3, now
                 )
-                if first:
-                    self.tracer.sample_ttft(
-                        (now - req.arrival_time) * 1e3, now
-                    )
-            req.generated.append(tok)
-            self._surface(req, tok, now)
-            self._admit_seq += 1
-            self._slots[slot_idx] = _Slot(
-                req=req, length=plen, pages=pages, last_tok=tok,
-                admit_seq=self._admit_seq, window_pages=window_pages,
-                window_first=window_first,
+        req.generated.append(tok)
+        self._surface(req, tok, now)
+        self._admit_seq += 1
+        self._slots[slot_idx] = _Slot(
+            req=req, length=plen, pages=adm.pages, last_tok=tok,
+            admit_seq=self._admit_seq, window_pages=adm.window_pages,
+            window_first=adm.window_first,
+        )
+        self._page_table[slot_idx, :] = 0
+        self._page_table[slot_idx, : len(adm.pages)] = adm.pages
+        if self.window_pool is not None:
+            self._set_window_row(
+                slot_idx, adm.window_pages, adm.window_first
             )
-            self._page_table[slot_idx, :] = row
-            if self._slot_done(self._slots[slot_idx]):
-                self._retire(slot_idx)
+        if self._slot_done(self._slots[slot_idx]):
+            self._retire(slot_idx)
 
     def _slot_done(self, slot: _Slot) -> bool:
         if len(slot.req.generated) >= slot.req.max_new_tokens:
@@ -1227,16 +1320,18 @@ class ServingEngine:
 
     def step(self) -> list[Request]:
         """One engine iteration: refill free slots from the queue
-        (prefill+commit each), grow page tables for slots crossing a
-        page boundary (preempting LIFO if the pool is dry), then run ONE
+        (prefill+commit each, enqueued back to back; one fetch of their
+        first tokens), grow page tables for slots crossing a page
+        boundary (preempting LIFO if the pool is dry), then run ONE
         fixed-shape decode step over all slots and retire the finished.
         Returns the requests completed during this iteration.
 
         Each phase is a span on the profiler's timeline (``serve/step``
-        and its children; docs/observability.md has the table). Every
-        phase is synchronous, so the spans partition the step's wall time
-        and the device's busy and idle time alike; with no capture
-        running they record nothing."""
+        and its children; docs/observability.md has the table). The
+        spans partition the step's wall time; the device works through
+        the step's prefills from the first ``serve/admit`` to the end of
+        ``serve/admit_fetch``, every later phase is synchronous. With no
+        capture running they record nothing."""
         with profiling.annotate(
             "serve/step", step=self._step_count, queued=len(self._queue),
             active=sum(s is not None for s in self._slots),
@@ -1261,7 +1356,14 @@ class ServingEngine:
         # admit (the queue head is by definition younger than every
         # active request — killing running work for it would invert
         # priority and can livelock with re-queued victims).
-        admits = 0
+        # Every admission the step can take is ENQUEUED first (pages
+        # leased, programs dispatched), and only then does the host wait:
+        # one blocking fetch brings all their first tokens, and the
+        # bookkeeping that needs a token runs in admission order. A slot
+        # exists only from there on, so nothing outside this block ever
+        # sees an admission half-made; pages a request frees by
+        # finishing on its first token serve the NEXT step's admissions.
+        admitted: list[_Admission] = []
         for i in range(self.cfg.num_slots):
             if not self._queue:
                 break
@@ -1269,12 +1371,22 @@ class ServingEngine:
                 continue
             if not self._can_admit(int(self._queue[0].prompt.size)):
                 break
-            self._admit(i, self._queue.popleft())
-            admits += 1
-        if admits:
-            self._admissions += admits
+            admitted.append(self._admit(i, self._queue.popleft()))
+        if admitted:
+            self._admissions += len(admitted)
             self._admit_steps += 1
-            self._max_admits_in_step = max(self._max_admits_in_step, admits)
+            self._max_admits_in_step = max(
+                self._max_admits_in_step, len(admitted)
+            )
+            with profiling.annotate(
+                "serve/admit_fetch", step=step, admits=len(admitted)
+            ):
+                # the step's ONE wait for its admissions: the decode
+                # step is fed these tokens from the host
+                toks = jax.device_get([a.first_tok for a in admitted])
+                self._admit_fetches += 1
+                for adm, tok in zip(admitted, toks):
+                    self._complete_admit(adm, int(tok))
 
         # grow: every active slot needs a page for the KV row its next
         # fed token writes (position slot.length)
@@ -1622,6 +1734,8 @@ class ServingEngine:
             "trash_rows_written": self._trash_rows,
             "admissions": self._admissions,
             "admit_steps": self._admit_steps,
+            # admissions / admit_fetches: admissions a blocking fetch
+            "admit_fetches": self._admit_fetches,
             "max_admits_in_step": self._max_admits_in_step,
             "pages_grown": self._pages_grown,
             "prefill_chunks": self._prefill_chunks,

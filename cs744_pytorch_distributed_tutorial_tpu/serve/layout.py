@@ -92,22 +92,22 @@ def compile_programs(
     key = shapes(engine._sample_root)
     i32 = jnp.int32
     # a model with window layers: the second group's table and the
-    # position of its first row, a slot (decode) or the one slot (chunk)
+    # position of its first row, a slot
     w = engine.window_table_width
     decode = engine._decode_step.lower(
         params, pages, s((b,), i32), s((b,), i32), s((b, p), i32),
         s((b,), jnp.bool_), s((b,), i32), s((b,), i32), key,
         *((s((b, w), i32), s((b,), i32)) if w else ()),
     ).compile()
+    # the prefill programs take one packed int32 vector and the key
     if cfg.prefill_chunk:
         prefill = engine._chunk_fn().lower(
-            params, pages, s((1, cfg.prefill_chunk), i32), s((), i32),
-            s((p,), i32), s((), i32), key,
-            *((s((w,), i32), s((), i32)) if w else ()),
+            params, pages,
+            s((engine._program_arg_len(cfg.prefill_chunk, 4),), i32), key,
         ).compile()
     else:
         prefill = engine._prefill_fn(bucket).lower(
-            params, pages, s((1, bucket), i32), s((), i32), s((p,), i32),
+            params, pages, s((engine._program_arg_len(bucket, 3),), i32),
             key,
         ).compile()
     return {"decode": decode, "prefill": prefill}

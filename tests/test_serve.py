@@ -242,8 +242,9 @@ def test_engine_commit_then_decode_bitwise_matches_dense(
     row[:2] = [9, 3]  # the slot's pages, out of pool order
     empty = jax.tree.map(np.asarray, eng._pages)
     pages, tok = eng._prefill_fn(bucket)(
-        params, eng._pages, jnp.asarray(prompt), jnp.int32(plen),
-        jnp.asarray(row), eng._sample_root,
+        params, eng._pages,
+        eng._pack_program_arg(bucket, prompt[0, :plen], (plen, 0, 0), [9, 3]),
+        eng._sample_root,
     )
 
     # jitted like the engine's program: op-by-op execution rounds the
@@ -479,6 +480,112 @@ def test_engine_sampled_preemption_replays_prng(tiny_lm):
     assert tight.stats()["preemptions"] > 0, "pool was not tight enough"
     assert ample.stats()["preemptions"] == 0
     assert tight_out == ample_out
+
+
+_ADMIT_CASES = [(6, 18), (10, 14), (8, 16), (5, 20), (12, 12)]
+
+
+def _serve_admit_cases(model, params, cfg):
+    eng = ServingEngine(model, params, cfg)
+    rng = np.random.default_rng(13)
+    reqs = [
+        eng.submit(Request(
+            prompt=rng.integers(1, VOCAB, size=plen).astype(np.int32),
+            max_new_tokens=budget,
+        ))
+        for plen, budget in _ADMIT_CASES
+    ]
+    eng.run()
+    # preemption absorbs early generations into the prompt
+    return eng.stats(), [
+        list(r.prompt[r.orig_prompt_len:]) + r.generated for r in reqs
+    ]
+
+
+@pytest.mark.parametrize("chunk", [None, 4], ids=["bucketed", "chunked"])
+@pytest.mark.parametrize(
+    "sample", [dict(), dict(temperature=0.8, top_k=20, seed=3)],
+    ids=["greedy", "sampled"],
+)
+def test_admissions_enqueued_together_serve_the_same_tokens(
+    tiny_lm, sample, chunk
+):
+    """A step's admissions are dispatched back to back and their first
+    tokens fetched once. Token for token that serves what one admission
+    a step serves (``num_slots=1``: never two prefills in flight), with
+    a pool so tight that preempted requests' recomputes are among the
+    admissions; the programs fold each token's key themselves, so the
+    sampled streams agree too. ``admit_fetches`` counts the steps that
+    admitted, and a chunked admission runs the chunks it always ran."""
+    model, params = tiny_lm
+    many, many_out = _serve_admit_cases(model, params, ServeConfig(
+        num_slots=3, page_size=4, num_pages=9, max_pages_per_slot=7,
+        prefill_chunk=chunk, **sample,
+    ))
+    one, one_out = _serve_admit_cases(model, params, ServeConfig(
+        num_slots=1, page_size=4, num_pages=33, max_pages_per_slot=8,
+        prefill_chunk=chunk, **sample,
+    ))
+    assert many["preemptions"] > 0, "pool was not tight enough"
+    assert many["max_admits_in_step"] > 1 and one["max_admits_in_step"] == 1
+    assert many_out == one_out
+    assert [len(o) for o in one_out] == [b for _, b in _ADMIT_CASES]
+    for stats in (many, one):
+        assert stats["admit_fetches"] == stats["admit_steps"] > 0
+    assert many["admissions"] > many["admit_fetches"]
+    assert one["admissions"] == one["admit_fetches"] == len(_ADMIT_CASES)
+    assert one["prefill_chunks"] == (
+        sum(-(-plen // chunk) for plen, _ in _ADMIT_CASES) if chunk else 0
+    )
+
+
+def test_a_chunked_admission_fetches_once(tiny_lm, monkeypatch):
+    """Only the last chunk's token is ever read, and it joins the step's
+    one fetch: the chunk program's tokens are wrapped to count what the
+    host converts, and ``jax.device_get`` to count the blocking calls."""
+    model, params = tiny_lm
+    eng = ServingEngine(model, params, ServeConfig(
+        num_slots=2, page_size=4, num_pages=33, max_pages_per_slot=8,
+        prefill_chunk=4,
+    ))
+    read = []
+
+    class CountedToken:
+        def __init__(self, tok, chunk):
+            self.tok, self.chunk = tok, chunk
+
+        def __array__(self, *args, **kwargs):
+            read.append(self.chunk)
+            return np.asarray(self.tok)
+
+        __int__ = __index__ = lambda self: int(np.asarray(self))
+
+    program, dispatched = eng._chunk_fn(), []
+
+    def counting_program(*args):
+        pages, tok = program(*args)
+        dispatched.append(len(dispatched))
+        return pages, CountedToken(tok, dispatched[-1])
+
+    eng._chunk_program = counting_program
+    fetches = []
+    device_get = jax.device_get
+    monkeypatch.setattr(
+        jax, "device_get", lambda x: fetches.append(len(x)) or device_get(x)
+    )
+    rng = np.random.default_rng(3)
+    reqs = [
+        eng.submit(Request(
+            prompt=rng.integers(1, VOCAB, size=n).astype(np.int32),
+            max_new_tokens=2,
+        ))
+        for n in (17, 6)  # 5 and 2 chunks of 4, both admitted in step one
+    ]
+    eng.step()
+    assert dispatched == list(range(7)) and read == [4, 6]
+    assert fetches == [2] and eng.stats()["admit_fetches"] == 1
+    assert eng.stats()["prefill_chunks"] == 7
+    assert all(len(r.generated) == 2 for r in reqs)
 
 
 def test_engine_streams_tokens(tiny_lm):

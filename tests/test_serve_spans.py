@@ -143,8 +143,8 @@ def capture(tmp_path_factory):
         ],
         "calls": calls, "guarded_calls": guarded_calls,
         "stats": {k: stats1[k] - stats0[k] for k in (
-            "admissions", "admit_steps", "pages_grown", "requests_done",
-            "preemptions", "decode_steps",
+            "admissions", "admit_steps", "admit_fetches", "pages_grown",
+            "requests_done", "preemptions", "decode_steps",
         )},
         "max_admits_in_step": stats1["max_admits_in_step"],
         "traced": traced, "untraced": untraced,
@@ -197,6 +197,29 @@ def check_prep_and_prefill_nest_in_their_admit(c):
         assert prep[0]["req"] == prefill[0]["req"] == a["req"]
         assert prefill[0]["bucket"] == a["bucket"]
         assert prep[0]["hi"] <= prefill[0]["lo"]
+
+
+def check_one_admit_fetch_in_a_step_that_admitted(c):
+    """The step's admissions are enqueued back to back and their first
+    tokens fetched once: one ``serve/admit_fetch`` after the last
+    ``serve/admit`` and before ``serve/grow`` in a step that admitted,
+    none in a step that did not, and ``stats()`` counts the same."""
+    with_fetch = several = 0
+    for s in _named(c["run"], "serve/step"):
+        admits = _children(c["run"], s, "serve/admit")
+        fetches = _children(c["run"], s, "serve/admit_fetch")
+        assert len(fetches) == (1 if admits else 0)
+        if not admits:
+            continue
+        (fetch,), (grow,) = fetches, _children(c["run"], s, "serve/grow")
+        assert fetch["step"] == s["step"] and fetch["admits"] == len(admits)
+        assert all(a["hi"] <= fetch["lo"] for a in admits)
+        assert not any(_inside(fetch, a) for a in admits)
+        assert fetch["hi"] <= grow["lo"]
+        with_fetch += 1
+        several += len(admits) > 1
+    assert with_fetch == c["stats"]["admit_fetches"] == c["stats"]["admit_steps"]
+    assert several, "no step admitted more than one request"
 
 
 def check_decode_phases_in_order(c):
@@ -264,7 +287,7 @@ def check_an_exception_closes_the_spans_it_passes(c):
     )
     assert names == {
         "serve/admit": 1, "serve/admit_prep": 1, "serve/prefill": 1,
-        "serve/grow": 1, "serve/decode_prep": 1, "serve/decode": 1,
+        "serve/admit_fetch": 1, "serve/grow": 1, "serve/decode_prep": 1, "serve/decode": 1,
         "serve/retire": 1,
     }
     # it never got to count its retirements
@@ -284,6 +307,7 @@ CHECKS = [
     check_one_step_span_per_call,
     check_one_admit_span_per_admission,
     check_prep_and_prefill_nest_in_their_admit,
+    check_one_admit_fetch_in_a_step_that_admitted,
     check_decode_phases_in_order,
     check_readmission_is_a_recompute_under_the_same_req,
     check_counters_equal_span_counts,
@@ -310,10 +334,12 @@ def test_annotate_passes_fields_and_late_metadata(tmp_path):
 
 def test_chunked_prefill_spans_and_counters(tmp_path):
     """With ``ServeConfig.prefill_chunk`` set, every chunk program of an
-    admission (and its blocking fetch) is a ``serve/prefill_chunk`` span
-    inside that admission's ``serve/prefill``, in order, carrying the
-    request, the chunk's number, its offset and how many prompt tokens
-    it holds; ``stats()`` counts the same chunks, and for a model with
+    admission (its put and its dispatch; no fetch) is a
+    ``serve/prefill_chunk`` span inside that admission's
+    ``serve/prefill``, in order, carrying the request, the chunk's
+    number, its offset and how many prompt tokens it holds; the step's
+    one ``serve/admit_fetch`` follows its last admission; ``stats()``
+    counts the same chunks and fetches, and for a model with
     an indexer and experts the decode steps' device-side counters
     (behind the step's tokens: one fetch, one compiled step)."""
     model = TransformerLM(
@@ -364,6 +390,13 @@ def test_chunked_prefill_spans_and_counters(tmp_path):
         assert {ch["req"] for ch in chunks} == {req.req_id} and admit["bucket"] == 8
     n_chunks = len(_named(spans, "serve/prefill_chunk"))
     assert n_chunks == 6 == stats1["prefill_chunks"] - stats0["prefill_chunks"]
+    # two slots: the first step admits two requests (4 chunks) behind one
+    # fetch, a later one the third
+    fetches = _named(spans, "serve/admit_fetch")
+    assert [f["admits"] for f in fetches] == [2, 1]
+    assert stats1["admit_fetches"] - stats0["admit_fetches"] == 2
+    assert all(a["hi"] <= fetches[0]["lo"] for a in admits[:2])
+    assert fetches[0]["hi"] <= admits[2]["lo"] <= admits[2]["hi"] <= fetches[1]["lo"]
     # the decode steps' counters: each of a request's 3 decode steps at
     # depth L scores L + 1 tokens a layer and keeps min(L + 1, 8)
     depths = [n + i for n in prompts.values() for i in range(3)]

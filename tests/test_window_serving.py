@@ -234,15 +234,14 @@ def test_a_model_without_window_layers_builds_one_pool_and_one_table():
         jnp.zeros((3,), bool), jnp.zeros((3,), i32), jnp.zeros((3,), i32), engine._sample_root,
     )
     assert _arg_shapes(decode)[n_params:] == [(33, 4, 32)] * 4 + [(3,), (3,), (3, 8), (3,), (3,), (3,), ()]
-    prefill = engine._prefill_fn(8).lower(
-        params, engine._pages, jnp.zeros((1, 8), i32), i32(5), jnp.zeros((8,), i32), engine._sample_root,
-    )
-    assert _arg_shapes(prefill)[n_params:] == [(33, 4, 32)] * 4 + [(1, 8), (), (8,), ()]
+    # the prefill programs: one packed vector (bucket 8 + true_len + 8 pages + the stream's two
+    # integers; chunk 8 + four scalars + 8 pages, and no window row) and the stream's root
+    prefill = engine._prefill_fn(8).lower(params, engine._pages, jnp.zeros((19,), i32), engine._sample_root)
+    assert _arg_shapes(prefill)[n_params:] == [(33, 4, 32)] * 4 + [(19,), ()]
     chunked = ServingEngine(model, params, ServeConfig(**{**cfg.__dict__, "prefill_chunk": 8}))
-    chunk = chunked._chunk_fn().lower(
-        params, chunked._pages, jnp.zeros((1, 8), i32), i32(0), jnp.zeros((8,), i32), i32(4), chunked._sample_root,
-    )
-    assert _arg_shapes(chunk)[n_params:] == [(33, 4, 32)] * 4 + [(1, 8), (), (8,), (), ()]
+    chunk = chunked._chunk_fn().lower(params, chunked._pages, jnp.zeros((20,), i32), chunked._sample_root)
+    assert _arg_shapes(chunk)[n_params:] == [(33, 4, 32)] * 4 + [(20,), ()]
+    assert engine._program_arg_len(8, 3) == 19 and chunked._program_arg_len(8, 4) == 20
     stats = engine.stats()
     assert stats["pages_live_window"] == stats["window_pages_freed"] == stats["window_tokens_read"] == 0
 
