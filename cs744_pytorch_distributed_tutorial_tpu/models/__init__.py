@@ -39,6 +39,7 @@ from cs744_pytorch_distributed_tutorial_tpu.models.hf_interop import (
     gpt2_model_config,
     keye_model_config,
     llama_model_config,
+    longcat_flash_model_config,
     mellum_model_config,
     model_config_from_hf,
     lm_params_from_hf_gpt2,
@@ -135,6 +136,7 @@ __all__ = [
     "tiny_cnn",
     "gpt2_model_config",
     "keye_model_config",
+    "longcat_flash_model_config",
     "mellum_model_config",
     "model_config_from_hf",
     "llama_model_config",

@@ -35,7 +35,7 @@ from a config (no download, zero egress) and pins logits to 1e-4.
 
 from __future__ import annotations
 
-from typing import Any, Mapping
+from typing import Any, Mapping, Sequence
 
 import numpy as np
 
@@ -382,20 +382,105 @@ def mellum_model_config(hf_config: Mapping[str, Any], max_seq_len: int | None = 
     )
 
 
+def longcat_flash_model_config(
+    hf_config: Mapping[str, Any], max_seq_len: int | None = None,
+    held_experts: Sequence[int] | None = None,
+) -> dict:
+    """``TransformerLM`` kwargs for a ``longcat_flash`` ``config.json``
+    (meituan-longcat/LongCat-Flash-Chat; transformers'
+    ``modular_longcat_flash.py`` reads the same keys): every layer two
+    latent attentions (MLA: ``q_lora_rank``, ``kv_lora_rank``, heads of
+    ``qk_nope_head_dim + qk_rope_head_dim`` / ``v_head_dim``, the
+    ``mla_scale_*_lora`` factors ``sqrt(hidden / rank)``, interleaved
+    RoPE on the rope dimensions), two dense SwiGLU MLPs of
+    ``ffn_hidden_size`` and one shortcut MoE: a softmax router over
+    ``n_routed_experts + zero_expert_num`` outputs, ``moe_topk`` chosen
+    by ``scores + e_score_correction_bias``, weighted by the scores
+    times ``routed_scaling_factor``, NOT renormalised; the zero-compute
+    experts are the identity. Read from the published keys alone.
+
+    ``held_experts`` is one chip's share of the experts (the
+    ``model-configs`` guide's section 4): the ids, of the
+    ``n_routed_experts`` the router routes over, whose matrices the model
+    holds; None holds them all. ``max_seq_len`` defaults to
+    ``max_position_embeddings``."""
+    if hf_config.get("attention_bias") or hf_config.get("router_bias"):
+        raise ValueError("attention_bias / router_bias are not supported")
+    if hf_config.get("hidden_act", "silu") != "silu":
+        raise ValueError("hidden_act other than silu is not supported")
+    if hf_config.get("zero_expert_type", "identity") != "identity":
+        raise ValueError(
+            f"zero_expert_type {hf_config['zero_expert_type']!r} is not "
+            "supported: a zero-compute expert is the identity"
+        )
+    if hf_config.get("rope_scaling"):
+        raise ValueError(
+            "rope_scaling on a longcat_flash config is not supported: the "
+            "latent layer rotates by default RoPE"
+        )
+    from cs744_pytorch_distributed_tutorial_tpu.models.latent import LatentDims
+
+    hidden = hf_config["hidden_size"]
+    q_rank, kv_rank = hf_config["q_lora_rank"], hf_config["kv_lora_rank"]
+    return dict(
+        vocab_size=hf_config["vocab_size"],
+        num_layers=hf_config["num_layers"],
+        num_heads=hf_config["num_attention_heads"],
+        d_model=hidden,
+        d_ff=hf_config["expert_ffn_hidden_size"],
+        dense_d_ff=hf_config["ffn_hidden_size"],
+        max_seq_len=max_seq_len or hf_config["max_position_embeddings"],
+        use_rope=True,
+        rope_base=float(hf_config["rope_theta"]),
+        tie_embeddings=False,
+        norm="rmsnorm",
+        norm_eps=hf_config["rms_norm_eps"],
+        mlp="swiglu",
+        latent=LatentDims(
+            q_lora_rank=q_rank,
+            kv_lora_rank=kv_rank,
+            qk_nope_head_dim=hf_config["qk_nope_head_dim"],
+            qk_rope_head_dim=hf_config["qk_rope_head_dim"],
+            v_head_dim=hf_config["v_head_dim"],
+            scale_q=(hidden / q_rank) ** 0.5 if hf_config.get("mla_scale_q_lora") else 1.0,
+            scale_kv=(hidden / kv_rank) ** 0.5 if hf_config.get("mla_scale_kv_lora") else 1.0,
+        ),
+        num_experts=hf_config["n_routed_experts"],
+        moe_top_k=hf_config["moe_topk"],
+        moe_dispatch="dropless",
+        moe_bias=False,
+        moe_held_experts=None if held_experts is None else tuple(held_experts),
+        moe_zero_experts=int(hf_config.get("zero_expert_num") or 0),
+        moe_renormalize=False,
+        moe_routed_scale=float(hf_config.get("routed_scaling_factor", 1.0)),
+        moe_choice_bias=True,
+        attn_bias=False,
+        attention_impl="dense",
+    )
+
+
 # ``model_type`` of a published config.json -> the builder of its kwargs
-CONFIG_BUILDERS = {"KeyeVL2": keye_model_config, "mellum": mellum_model_config}
+CONFIG_BUILDERS = {
+    "KeyeVL2": keye_model_config,
+    "mellum": mellum_model_config,
+    "longcat_flash": longcat_flash_model_config,
+}
 
 
-def model_config_from_hf(hf_config: Mapping[str, Any], max_seq_len: int | None = None) -> dict:
+def model_config_from_hf(
+    hf_config: Mapping[str, Any], max_seq_len: int | None = None, **how_deployed,
+) -> dict:
     """``TransformerLM`` kwargs from a published ``config.json``, by its
-    ``model_type``."""
+    ``model_type``. ``how_deployed`` goes to the builder: what a
+    deployment decides and no published file says (``held_experts`` of
+    ``longcat_flash``); a builder that has no such argument raises."""
     kind = hf_config.get("model_type")
     if kind not in CONFIG_BUILDERS:
         raise ValueError(
             f"no builder for model_type {kind!r}; known: "
             f"{sorted(CONFIG_BUILDERS)}"
         )
-    return CONFIG_BUILDERS[kind](hf_config, max_seq_len=max_seq_len)
+    return CONFIG_BUILDERS[kind](hf_config, max_seq_len=max_seq_len, **how_deployed)
 
 
 def lm_params_from_hf_llama(state_dict: Mapping[str, Any]) -> dict:

@@ -38,6 +38,7 @@ from __future__ import annotations
 from typing import Any
 
 import flax.linen as nn
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -119,12 +120,37 @@ class MoEFFN(nn.Module):
     gated: bool = False
     # Expert biases b_in / b_out; False declares neither.
     use_bias: bool = True
+    # A chip's SHARE of the experts (expert parallelism without its
+    # exchange): the ids, of the ``num_experts`` routed ones, whose
+    # matrices this layer holds. The router keeps its full width and its
+    # ``top_k``; the layer computes the held experts' terms for the
+    # tokens that chose them and leaves out what the absent experts
+    # would add (the shares of all chips sum to the whole layer,
+    # tests/test_latent_moe.py). None = every expert is held. Dropless
+    # path only; under ``expert_axis`` (which shares the experts out
+    # itself) it raises.
+    held_experts: tuple | None = None
+    # Zero-compute experts: this many more router outputs, behind the
+    # routed ones, whose "expert" is the identity: a token that chooses
+    # one gets ``w * x``, with no weights and no matmul. They run where
+    # the token lives, so every share computes them.
+    zero_experts: int = 0
+    # top-k weights divided by their sum (today's behaviour) or left as
+    # the softmax gave them, then times ``routed_scale``.
+    renormalize: bool = True
+    routed_scale: float = 1.0
+    # A bias on the CHOICE only (a ``choice_bias`` parameter, one a
+    # router output): experts are the top-k of ``scores + bias``, their
+    # weights are ``scores`` without it.
+    choice_bias: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         b, t, d = x.shape
-        e = self.num_experts
+        # the router's width: routed experts, then zero-compute ones
+        e = self.num_experts + self.zero_experts
         k = self.top_k
+        shared_out = self.held_experts is not None or self.zero_experts > 0
         if k < 1 or k > e:
             raise ValueError(f"top_k {k} must be in [1, {e}]")
         if self.dispatch_impl not in ("einsum", "scatter", "dropless"):
@@ -153,6 +179,26 @@ class MoEFFN(nn.Module):
                 "defaults (1.25, 1) or use 'scatter'/'einsum' for "
                 "capacity-based routing"
             )
+        if shared_out and not dropless:
+            raise ValueError(
+                "held_experts / zero_experts run on dispatch_impl="
+                f"'dropless' only, got {self.dispatch_impl!r}: a capacity "
+                "slot belongs to an expert that is computed here"
+            )
+        if self.held_experts is not None and self.expert_axis is not None:
+            raise ValueError(
+                "held_experts is one chip's share without the exchange; "
+                "expert_axis shares the experts out itself. Set one"
+            )
+        if self.held_experts is not None and not (
+            self.held_experts
+            and len(set(self.held_experts)) == len(self.held_experts)
+            and all(0 <= i < self.num_experts for i in self.held_experts)
+        ):
+            raise ValueError(
+                f"held_experts {self.held_experts} must be distinct ids of "
+                f"the {self.num_experts} routed experts, at least one"
+            )
         if self.gated and not dropless:
             raise ValueError(
                 "gated experts run on dispatch_impl='dropless' only, got "
@@ -164,6 +210,11 @@ class MoEFFN(nn.Module):
                 f"{self.expert_axis_size}"
             )
         e_local = e // self.expert_axis_size if ep else e
+        if shared_out:  # the matrices held here
+            e_local = (
+                self.num_experts if self.held_experts is None
+                else len(self.held_experts)
+            )
         n_total = b * t
         g = self.num_groups
         if g < 0:
@@ -196,11 +247,20 @@ class MoEFFN(nn.Module):
             name="router",
         )(tokens.astype(jnp.float32))
         gates = jax.nn.softmax(logits, axis=-1)  # [G, N, E]
-        topk_gate, topk_idx = lax.top_k(gates, k)  # [G, N, K]
-        if k > 1:
+        if self.choice_bias:
+            bias = self.param(
+                "choice_bias", nn.initializers.zeros_init(), (e,), jnp.float32
+            )
+            _, topk_idx = lax.top_k(gates + bias, k)
+            topk_gate = jnp.take_along_axis(gates, topk_idx, axis=-1)
+        else:
+            topk_gate, topk_idx = lax.top_k(gates, k)  # [G, N, K]
+        if k > 1 and self.renormalize:
             topk_gate = topk_gate / jnp.maximum(
                 topk_gate.sum(-1, keepdims=True), 1e-9
             )
+        if self.routed_scale != 1.0:
+            topk_gate = topk_gate * self.routed_scale
 
         # Load-balancing aux loss (Switch eq. 4): experts should see equal
         # token fractions f_e and equal mean router mass P_e. Computed
@@ -251,6 +311,7 @@ class MoEFFN(nn.Module):
                 default_interpret,
             )
             from cs744_pytorch_distributed_tutorial_tpu.ops.gmm import (
+                fit_block_n,
                 grouped_matmul,
             )
 
@@ -263,6 +324,86 @@ class MoEFFN(nn.Module):
             if gmm_impl == "auto":
                 gmm_impl = "ragged" if interpret else "pallas"
             p_tot = n_total * k
+
+            def expert_ffn(xs, group_sizes, sorted_e, p_rows, live=None):
+                """The experts' FFN over pair rows sorted by expert: rows
+                of group ``g`` times expert ``g``'s matrices. ``p_rows``
+                is as many rows as a row tile need hold (all the pairs,
+                or what a group is expected to have). ``live [rows, 1]``
+                says that the groups fill only the rows it marks (a
+                chip's share): the kernel then visits no tile past them
+                and leaves their output unwritten."""
+                # A row tile no taller than the pairs there are (a decode
+                # step routes a few dozen); the column tile is the
+                # kernel's to fit (ops/gmm.py).
+                block_m = min(
+                    self.gmm_block_m, max(32, 1 << (p_rows - 1).bit_length())
+                )
+
+                def block_n(rhs):
+                    return fit_block_n(
+                        *rhs.shape[-2:], block_m, self.gmm_block_n,
+                        jnp.dtype(self.dtype).itemsize,
+                    )
+
+                if gmm_impl == "pallas" and self.use_bias and not self.gated:
+                    # Fused-epilogue kernels: the per-group bias (and gelu)
+                    # ride inside the gmm — XLA cannot fuse elementwise
+                    # chains into a Pallas custom call, so the unfused
+                    # kernel pays an extra [P, d_ff] HBM round-trip the
+                    # ragged_dot path does not (ops/gmm.py).
+                    from cs744_pytorch_distributed_tutorial_tpu.ops.gmm import (
+                        grouped_matmul_fused,
+                    )
+
+                    fused = lambda lhs, rhs, b, act: grouped_matmul_fused(
+                        lhs,
+                        rhs,
+                        b,
+                        group_sizes,
+                        activation=act,
+                        block_m=block_m,
+                        block_n=block_n(rhs),
+                        interpret=interpret,
+                    )
+                    h = fused(xs, w_in.astype(self.dtype), b_in, "gelu")
+                    out = fused(
+                        h.astype(self.dtype), w_out.astype(self.dtype),
+                        b_out, "none",
+                    )
+                else:
+                    gmm = lambda lhs, rhs: grouped_matmul(
+                        lhs,
+                        rhs,
+                        group_sizes,
+                        impl=gmm_impl,
+                        block_m=block_m,
+                        block_n=block_n(rhs),
+                        interpret=interpret,
+                        live_only=live is not None,
+                    )
+                    h = gmm(xs, w_in.astype(self.dtype))
+                    if self.use_bias:
+                        h = h + b_in[sorted_e].astype(h.dtype)
+                    if self.gated:
+                        # unfused: the gate's product is an XLA elementwise
+                        # over two [P, d_ff] kernel outputs
+                        h = nn.silu(gmm(xs, w_gate.astype(self.dtype))) * h
+                    else:
+                        h = nn.gelu(h)
+                    if live is not None:
+                        # unwritten rows are no operand of the next matmul
+                        h = jnp.where(live, h, 0)
+                    out = gmm(h.astype(self.dtype), w_out.astype(self.dtype))
+                    if self.use_bias:
+                        out = out + b_out[sorted_e].astype(out.dtype)
+                return out
+
+            if shared_out:
+                return self._shared_out(
+                    tokens.reshape(n_total, d), topk_idx.reshape(n_total, k),
+                    topk_gate.reshape(n_total, k), expert_ffn,
+                ).reshape(b, t, d)
             expert_flat = topk_idx.reshape(p_tot)
             order = jnp.argsort(expert_flat, stable=True)
             sorted_e = expert_flat[order]
@@ -275,68 +416,7 @@ class MoEFFN(nn.Module):
                 self.sow(
                     "serve_stats", "expert_idx", topk_idx.reshape(n_total, k)
                 )
-            # A row tile no taller than the pairs there are (a decode
-            # step routes a few dozen), and a column tile that divides
-            # the width: the kernel pads what does not fit, and padding
-            # the columns copies every expert's matrix.
-            block_m = min(
-                self.gmm_block_m, max(32, 1 << (p_tot - 1).bit_length())
-            )
-
-            def block_n(n_cols):
-                fits = [
-                    c for c in (self.gmm_block_n, 384, 256, 128)
-                    if c <= self.gmm_block_n and n_cols % c == 0
-                ]
-                return fits[0] if fits else self.gmm_block_n
-
-            if gmm_impl == "pallas" and self.use_bias and not self.gated:
-                # Fused-epilogue kernels: the per-group bias (and gelu)
-                # ride inside the gmm — XLA cannot fuse elementwise
-                # chains into a Pallas custom call, so the unfused
-                # kernel pays an extra [P, d_ff] HBM round-trip the
-                # ragged_dot path does not (ops/gmm.py).
-                from cs744_pytorch_distributed_tutorial_tpu.ops.gmm import (
-                    grouped_matmul_fused,
-                )
-
-                fused = lambda lhs, rhs, b, act: grouped_matmul_fused(
-                    lhs,
-                    rhs,
-                    b,
-                    group_sizes,
-                    activation=act,
-                    block_m=block_m,
-                    block_n=block_n(rhs.shape[-1]),
-                    interpret=interpret,
-                )
-                h = fused(xs, w_in.astype(self.dtype), b_in, "gelu")
-                out = fused(
-                    h.astype(self.dtype), w_out.astype(self.dtype),
-                    b_out, "none",
-                )
-            else:
-                gmm = lambda lhs, rhs: grouped_matmul(
-                    lhs,
-                    rhs,
-                    group_sizes,
-                    impl=gmm_impl,
-                    block_m=block_m,
-                    block_n=block_n(rhs.shape[-1]),
-                    interpret=interpret,
-                )
-                h = gmm(xs, w_in.astype(self.dtype))
-                if self.use_bias:
-                    h = h + b_in[sorted_e].astype(h.dtype)
-                if self.gated:
-                    # unfused: the gate's product is an XLA elementwise
-                    # over two [P, d_ff] kernel outputs
-                    h = nn.silu(gmm(xs, w_gate.astype(self.dtype))) * h
-                else:
-                    h = nn.gelu(h)
-                out = gmm(h.astype(self.dtype), w_out.astype(self.dtype))
-                if self.use_bias:
-                    out = out + b_out[sorted_e].astype(out.dtype)
+            out = expert_ffn(xs, group_sizes, sorted_e, p_tot)
             self.sow("metrics", "moe_drop", jnp.float32(0.0))
             gate_flat = topk_gate.reshape(p_tot)[order].astype(out.dtype)
             y = (
@@ -439,6 +519,88 @@ class MoEFFN(nn.Module):
                 "gnec,egcd->gnd", combine.astype(self.dtype), out
             )
         return y.reshape(b, t, d)
+
+
+    def _shared_out(self, x, idx, gate, expert_ffn):
+        """The dropless layer of a chip that holds a SHARE of the routed
+        experts, and of a router with zero-compute experts: ``x [N, D]``,
+        each token's ``idx`` / ``gate`` ``[N, K]`` over the router's whole
+        width -> ``[N, D]``: the held experts' terms for the tokens that
+        chose them, plus ``w * x`` for every zero-compute expert chosen;
+        the terms of routed experts held elsewhere are left out.
+
+        Pairs sort by where their expert is, the held ones first (by
+        local index), and the grouped matmuls visit only the row tiles
+        the held pairs fill (``live_only``, ops/gmm.py): a chunk's other
+        rows cost no matmul and an expert no token chose streams no
+        weights. What XLA does around the kernels (the rows' gather, the
+        gate's product, the scatter back) runs over ``p_cap`` rows: four
+        times the pairs an even router sends to the held experts, the
+        rule of the row tile's height. No pair is ever dropped: a second
+        branch of the same program, over all the pair rows, serves the
+        step in which more were drawn."""
+        n, k = idx.shape
+        routed = self.num_experts
+        held = (
+            tuple(range(routed)) if self.held_experts is None
+            else tuple(self.held_experts)
+        )
+        # router output -> index among the held matrices; -1 = not here
+        local_of = np.full((routed + self.zero_experts,), -1, np.int32)
+        local_of[list(held)] = np.arange(len(held))
+        local = jnp.asarray(local_of)[idx]  # [N, K]
+        is_zero = idx >= routed
+        is_held = local >= 0
+        if not self.is_initializing():
+            # what the serving engine's counters read (a no-op unless
+            # "serve_stats" is asked for): the held experts' hits, and
+            # each token's pairs by where their expert is
+            self.sow("serve_stats", "expert_idx", local)
+            self.sow("serve_stats", "held_expert_pairs", is_held.sum(-1))
+            self.sow("serve_stats", "zero_expert_pairs", is_zero.sum(-1))
+            self.sow(
+                "serve_stats", "absent_expert_pairs",
+                (~is_held & ~is_zero).sum(-1),
+            )
+        p_tot = n * k
+        flat = jnp.where(is_held, local, len(held)).reshape(p_tot)
+        order = jnp.argsort(flat, stable=True)
+        group_sizes = jnp.bincount(flat, length=len(held) + 1)[: len(held)]
+        n_held = group_sizes.sum()
+        gate_flat = gate.reshape(p_tot)
+        # a row tile as tall as four times what an expert expects of an
+        # even router, and as many rows as that for each held expert
+        rows_a_group = max(1, 4 * p_tot // (routed + self.zero_experts))
+
+        def held_terms(rows):
+            take = order[:rows]
+            tok_ids = take // k
+            # rows past the held pairs: the kernel writes none of them
+            live = (jnp.arange(rows) < n_held)[:, None]
+            # inside ``lax.cond`` a call is named after its branch: the
+            # scope gives the grouped matmuls the module's name back
+            with jax.named_scope("moe"):
+                out = expert_ffn(
+                    x[tok_ids].astype(self.dtype), group_sizes,
+                    jnp.minimum(flat[take], len(held) - 1), rows_a_group,
+                    live,
+                )
+            out = jnp.where(live, out * gate_flat[take][:, None].astype(out.dtype), 0)
+            return jnp.zeros((n, x.shape[-1]), out.dtype).at[tok_ids].add(out)
+
+        p_cap = min(p_tot, max(32, -(-rows_a_group * len(held) // 32) * 32))
+        if p_cap < p_tot:
+            y = lax.cond(
+                n_held <= p_cap, lambda: held_terms(p_cap),
+                lambda: held_terms(p_tot),
+            )
+        else:
+            y = held_terms(p_tot)
+        self.sow("metrics", "moe_drop", jnp.float32(0.0))
+        if self.zero_experts:
+            w_zero = jnp.sum(jnp.where(is_zero, gate, 0.0), axis=-1)
+            y = y + w_zero[:, None].astype(y.dtype) * x.astype(y.dtype)
+        return y.astype(self.dtype)
 
 
 def moe_aux_loss(mutated_variables) -> jnp.ndarray:

@@ -1315,10 +1315,87 @@ class TransformerLM(nn.Module):
     window: int | None = None
     window_rope_base: float | None = None
     window_num_pages: int | None = None
+    # Latent attention (models/latent.py::LatentDims: the low-rank query
+    # and key-value paths' sizes) in the two-attention shortcut-MoE layer
+    # (``ShortcutMoEBlock``): two (latent attention, dense SwiGLU MLP of
+    # ``dense_d_ff``) pairs and one MoE of ``d_ff``-wide experts beside
+    # them, every layer. Served, a sublayer keeps ONE pool of latents
+    # (``latent_pages``) where another layer keeps keys and values.
+    # None = the model as it always was, and models/latent.py is never
+    # imported.
+    latent: Any = None
+    dense_d_ff: int | None = None
+    # MoEFFN's share and router options (models/moe.py): the ids of the
+    # routed experts held here (None = all), zero-compute experts behind
+    # the routed ones, top-k weights renormalised or not and their
+    # scale, a bias on the choice alone.
+    moe_held_experts: tuple | None = None
+    moe_zero_experts: int = 0
+    moe_renormalize: bool = True
+    moe_routed_scale: float = 1.0
+    moe_choice_bias: bool = False
 
     def window_layers(self) -> int:
         """How many layers are sliding-window layers."""
         return sum(k == "sliding_attention" for k in self.layer_types or ())
+
+    def _check_latent(self, mode: str) -> None:
+        """A model with latent attention: raise, with its reason, for
+        each combination that is not built."""
+
+        def no(what: str, why: str):
+            raise ValueError(f"latent attention with {what} is not built: {why}")
+
+        if self.attention_impl != "dense":
+            no(
+                f"attention_impl={self.attention_impl!r}",
+                "the flash kernels take one head width for q, k and v (here "
+                "192 / 192 / 128) and the ring and ulysses variants shard "
+                "keys a head; it trains with attention_impl='dense'",
+            )
+        if mode in ("prefill", "decode"):
+            no(
+                f"mode={mode!r}",
+                "the dense cache holds keys and values a head, which the "
+                "latent exists to avoid; serve through the paged latent "
+                "pools (ServeConfig.prefill_chunk)",
+            )
+        if self.quant_kv_cache:
+            no("quant_kv_cache", "the latent pool is float; no int8 rows")
+        if self.quant_dense:
+            no("quant_dense", "the low-rank projections are float kernels")
+        if (self.tensor_axis is not None and self.tensor_axis_size > 1) or (
+            self.seq_axis is not None and self.seq_axis_size > 1
+        ):
+            no(
+                "a tensor or sequence axis",
+                "every head reads the ONE latent row of a token, so heads "
+                "do not shard the pool; it runs on one device",
+            )
+        if self.scan_layers:
+            no("scan_layers", "the two-attention layer is built unrolled")
+        if self.layer_types is not None or self.window is not None:
+            no("a window (layer_types)", "the latent walk has no window")
+        if self.indexer_heads:
+            no("the sparse-attention indexer", "no indexer over latents")
+        if self.expert_axis is not None:
+            no(
+                "expert_axis",
+                "the shortcut MoE is dropless; a chip's share is "
+                "moe_held_experts, without the exchange",
+            )
+        if self.num_experts < 1 or self.dense_d_ff is None:
+            no(
+                "no experts or no dense_d_ff",
+                "the layer is two dense MLPs (dense_d_ff) and one MoE "
+                "(num_experts of d_ff)",
+            )
+        if not self.use_rope or self.tie_embeddings or self.norm != "rmsnorm":
+            no(
+                "learned positions, tied embeddings or LayerNorm",
+                "the layer rotates its rope dimensions and normalises by "
+                "RMSNorm; use_rope=True, norm='rmsnorm', untied head",
+            )
 
     @nn.compact
     def __call__(
@@ -1338,6 +1415,18 @@ class TransformerLM(nn.Module):
         final norm and the head then run on that row alone (a prefill
         chunk needs one token's logits, not a chunk's)."""
         b, t_local = tokens.shape
+        if self.latent is not None:
+            self._check_latent(mode)
+        elif (
+            self.moe_held_experts is not None or self.moe_zero_experts
+            or not self.moe_renormalize or self.moe_routed_scale != 1.0
+            or self.moe_choice_bias
+        ):
+            raise ValueError(
+                "moe_held_experts, moe_zero_experts, moe_renormalize, "
+                "moe_routed_scale and moe_choice_bias are built in the "
+                "shortcut-MoE layer (latent set); Block's MoE takes none"
+            )
         if self.layer_types is not None:
             kinds = set(self.layer_types)
             if len(self.layer_types) != self.num_layers or not kinds <= {
@@ -1447,7 +1536,52 @@ class TransformerLM(nn.Module):
             moe_bias=self.moe_bias,
             rope_scaling=self.rope_scaling,
         )
-        if self.scan_layers:
+        if self.latent is not None:
+            from cs744_pytorch_distributed_tutorial_tpu.models.latent import (
+                ShortcutMoEBlock,
+            )
+
+            for i in range(self.num_layers):
+                x = ShortcutMoEBlock(
+                    num_heads=self.num_heads,
+                    dims=self.latent,
+                    dense_d_ff=self.dense_d_ff,
+                    moe=(
+                        ("num_experts", self.num_experts),
+                        ("d_ff", self.d_ff),
+                        ("top_k", self.moe_top_k),
+                        ("dispatch_impl", "dropless"),
+                        ("gmm_impl", self.moe_gmm_impl),
+                        ("gmm_interpret", self.flash_interpret),
+                        ("gated", True),
+                        ("use_bias", False),
+                        ("held_experts", self.moe_held_experts),
+                        ("zero_experts", self.moe_zero_experts),
+                        ("renormalize", self.moe_renormalize),
+                        ("routed_scale", self.moe_routed_scale),
+                        ("choice_bias", self.moe_choice_bias),
+                    ),
+                    dtype=self.dtype,
+                    rope_base=self.rope_base,
+                    norm_eps=self.norm_eps,
+                    page_size=self.page_size,
+                    num_pages=self.num_pages,
+                    paged_attention_impl=self.paged_attention_impl,
+                    flash_interpret=self.flash_interpret,
+                    name=f"block_{i}",
+                )(
+                    x, deterministic, mode=mode, decode_pos=decode_pos,
+                    page_table=page_table,
+                )
+            if mode == "paged_decode" and not self.is_initializing():
+                # Latent rows each slot's step attended, over the two
+                # sublayers of every layer (the engine's counters; a
+                # no-op unless "serve_stats" is asked for).
+                self.sow(
+                    "serve_stats", "latent_tokens_read",
+                    2 * self.num_layers * (decode_pos + 1),
+                )
+        elif self.scan_layers:
             if self.num_experts > 0:
                 raise ValueError(
                     "scan_layers does not compose with MoE "

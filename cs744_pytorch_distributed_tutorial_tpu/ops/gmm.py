@@ -112,6 +112,31 @@ def _gmm_kernel(block_m: int, sg, sm, first, valid, start, end,
         out_ref[...] += partial_
 
 
+def _gmm_live_kernel(block_m: int, sg, sm, first, valid, start, end,
+                     lhs_ref, rhs_ref, out_ref):
+    """``_gmm_kernel`` where the groups need not fill the rows: a
+    trailing dummy step (it repeats the last real step's blocks, so it
+    moves nothing) multiplies nothing either."""
+    s = pl.program_id(1)
+
+    @pl.when(valid[s] == 1)
+    def _visit():
+        g = sg[s]
+        mask = _row_mask(sm[s] * block_m, start[g], end[g], block_m)
+        x = jnp.where(mask, lhs_ref[...], jnp.zeros_like(lhs_ref[...]))
+        partial_ = jnp.dot(
+            x, rhs_ref[0], preferred_element_type=jnp.float32
+        )
+
+        @pl.when(first[s] == 1)
+        def _init():
+            out_ref[...] = partial_
+
+        @pl.when(first[s] == 0)
+        def _acc():
+            out_ref[...] += partial_
+
+
 def _tgmm_kernel(block_m: int, sg, sm, first_g, valid, start, end,
                  lhs_ref, dout_ref, out_ref):
     s = pl.program_id(1)
@@ -139,22 +164,27 @@ def _pad_rows(x, m_padded: int):
     return jnp.pad(x, ((0, m_padded - m), (0, 0)))
 
 
-def _prep(lhs, group_sizes, block_m: int, num_experts: int):
+def _prep(lhs, group_sizes, block_m: int, num_experts: int,
+          fold: bool = True):
     """Pad rows to a tile multiple and fold the padding into the LAST
     group (padded rows compute garbage that the caller's row count
-    slices away; zero lhs rows keep the garbage finite)."""
+    slices away; zero lhs rows keep the garbage finite). Without
+    ``fold`` the rows past the groups belong to none: no step visits a
+    tile that holds only such rows."""
     m = lhs.shape[0]
     m_padded = max(_ceil_to(m, block_m), block_m)
     lhs = _pad_rows(lhs, m_padded)
     gs = group_sizes.astype(jnp.int32)
-    gs = gs.at[num_experts - 1].add(m_padded - jnp.sum(gs))
+    if fold:
+        gs = gs.at[num_experts - 1].add(m_padded - jnp.sum(gs))
     return lhs, gs, m_padded
 
 
-def _gmm_fwd_impl(lhs, rhs, group_sizes, block_m, block_n, interpret):
+def _gmm_fwd_impl(lhs, rhs, group_sizes, block_m, block_n, interpret,
+                  live_only=False):
     m, k = lhs.shape
     e, _, n = rhs.shape
-    lhs_p, gs, m_padded = _prep(lhs, group_sizes, block_m, e)
+    lhs_p, gs, m_padded = _prep(lhs, group_sizes, block_m, e, not live_only)
     bn = min(block_n, n)
     num_steps = m_padded // block_m + e - 1
     sg, sm, first, valid, start, end = _step_maps(
@@ -176,7 +206,7 @@ def _gmm_fwd_impl(lhs, rhs, group_sizes, block_m, block_n, interpret):
         ),
     )
     out = pl.pallas_call(
-        partial(_gmm_kernel, block_m),
+        partial(_gmm_live_kernel if live_only else _gmm_kernel, block_m),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_padded, n_padded), jnp.float32),
         interpret=interpret,
@@ -228,17 +258,25 @@ def _tgmm_impl(lhs, dout, group_sizes, num_experts, block_m, block_n,
     return jnp.where((group_sizes > 0)[:, None, None], dw, 0.0)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _gmm_pallas(lhs, rhs, group_sizes, block_m, block_n, interpret):
-    return _gmm_fwd_impl(lhs, rhs, group_sizes, block_m, block_n, interpret)
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _gmm_pallas(lhs, rhs, group_sizes, block_m, block_n, interpret,
+                live_only):
+    return _gmm_fwd_impl(
+        lhs, rhs, group_sizes, block_m, block_n, interpret, live_only
+    )
 
 
-def _gmm_pallas_fwd(lhs, rhs, group_sizes, block_m, block_n, interpret):
-    out = _gmm_fwd_impl(lhs, rhs, group_sizes, block_m, block_n, interpret)
+def _gmm_pallas_fwd(lhs, rhs, group_sizes, block_m, block_n, interpret,
+                    live_only):
+    out = _gmm_fwd_impl(
+        lhs, rhs, group_sizes, block_m, block_n, interpret, live_only
+    )
     return out, (lhs, rhs, group_sizes)
 
 
-def _gmm_pallas_bwd(block_m, block_n, interpret, res, dout):
+def _gmm_pallas_bwd(block_m, block_n, interpret, live_only, res, dout):
+    # the folded kernels: a cotangent's rows past the groups are the
+    # caller's to zero, as the forward's are the caller's to drop
     lhs, rhs, group_sizes = res
     return _gmm_bwd_core(
         lhs, rhs, group_sizes, dout, block_m, block_n, interpret,
@@ -497,6 +535,7 @@ def grouped_matmul(
     block_m: int = 256,
     block_n: int = 512,
     interpret: bool = False,
+    live_only: bool = False,
 ):
     """``out[r] = lhs[r] @ rhs[g(r)]`` where row ``r`` belongs to group
     ``g(r)`` under the contiguous-group layout (``group_sizes[e]`` rows
@@ -506,6 +545,15 @@ def grouped_matmul(
     lhs ``[M, K]``, rhs ``[E, K, N]``, group_sizes int ``[E]`` (traced —
     dynamic values, static shapes) → ``[M, N]``. Differentiable in lhs
     and rhs with both impls.
+
+    The Pallas kernel counts the rows past the groups into the last
+    group and multiplies them too: nothing, where the groups fill
+    nearly all rows. ``live_only`` is for a caller whose groups fill a
+    small and varying part of them (a chip's share of the experts,
+    ``MoEFFN._shared_out``): only the tiles that hold a group's rows are
+    visited, so the rest cost no step's matmul and no expert's weight
+    stream, and their output rows are never written (mask them with
+    ``where``, not by a product).
     """
     _check_gmm_shapes(lhs, rhs, group_sizes)
     if impl == "ragged":
@@ -514,6 +562,44 @@ def grouped_matmul(
         )
     if impl == "pallas":
         return _gmm_pallas(
-            lhs, rhs, group_sizes, block_m, block_n, interpret
+            lhs, rhs, group_sizes, block_m, block_n, interpret,
+            bool(live_only),
         ).astype(lhs.dtype)
     raise ValueError(f"unknown grouped_matmul impl {impl!r}")
+
+
+# What a kernel call's buffers may take of a TensorCore's VMEM: 14 MiB of
+# the v5e's 16 (the smallest of the chips in perfbench/peaks.py), the rest
+# left to Mosaic's own scratch.
+VMEM_BUDGET_BYTES = 14 * 2**20
+
+
+def fit_block_n(k_rows: int, n_cols: int, block_m: int, block_n: int,
+                itemsize: int) -> int:
+    """The column tile for ``grouped_matmul`` / ``grouped_matmul_fused``
+    over ``rhs [E, k_rows, n_cols]``: the widest of ``block_n``, 384, 256,
+    128 that is no wider than ``block_n``, divides ``n_cols`` (the kernel
+    pads what does not divide, and padding the columns copies every
+    expert's matrix) and whose ``[k_rows, tile]`` block of an expert's
+    matrix, double-buffered beside the ``[block_m, k_rows]`` row tile and
+    the float32 output tile, fits ``VMEM_BUDGET_BYTES`` (K = 6144 at 512
+    columns and 256 rows does not). Where none divides, ``block_n`` and
+    the padding, as ever; where some divide and none fits, it raises."""
+
+    def vmem(c):
+        return 2 * itemsize * k_rows * (c + block_m) + 8 * block_m * c
+
+    divide = [
+        c for c in (block_n, 384, 256, 128)
+        if c <= block_n and n_cols % c == 0
+    ]
+    if not divide:
+        return block_n
+    fits = [c for c in divide if vmem(c) <= VMEM_BUDGET_BYTES]
+    if not fits:
+        raise ValueError(
+            f"grouped matmul over [{k_rows}, {n_cols}] matrices: no column "
+            f"tile of {divide} beside a row tile of {block_m} fits "
+            f"{VMEM_BUDGET_BYTES} bytes of VMEM; lower block_m"
+        )
+    return fits[0]

@@ -100,6 +100,15 @@ is tolerance-level (tests/test_paged_attention.py), not bitwise — the
 gather path remains the reference implementation and the engine's
 bitwise dense-parity story stays on it.
 
+**A latent pool** (``value_pages`` None; models/latent.py). Latent
+attention caches one row a token, ``[c | k_rope | 0]`` in whole lane
+tiles (576 -> 640), shared by every head; absorbed, a head's query lies
+over the same lanes, so ``Hq`` rows of ``q [Hq, lanes]`` against a
+block's rows is the ``_attend`` body as it is with ``Hkv = 1``. What is
+new is ONE pool serving as keys (the whole row) and values (its first
+``value_lanes`` lanes): a block is copied once, and the accumulator
+``[Hq, value_lanes]`` is written as it lies (no diagonal to pick).
+
 ``pages_per_slot`` statically narrows the page table to its first N
 columns (the walk's capacity, the other path's grid).
 
@@ -183,6 +192,7 @@ def _walk_kernel(
     capacity: int,
     scale: float,
     window: int | None,
+    value_lanes: int | None,
     lens_ref,
     pt_ref,
     *refs,
@@ -195,7 +205,14 @@ def _walk_kernel(
     first_ref = None
     if window is not None:
         first_ref, *refs = refs
-    q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, wide_ref, sem, first_buf = refs
+    if value_lanes is None:
+        q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, wide_ref, sem, first_buf = refs
+        pools = ((k_hbm, kbuf), (v_hbm, vbuf))
+    else:
+        # A latent pool: ONE pool serves as keys (the whole row) and as
+        # values (its first ``value_lanes`` lanes), copied once a block.
+        q_ref, k_hbm, o_ref, kbuf, sem, first_buf = refs
+        vbuf, pools = kbuf, ((k_hbm, kbuf),)
     hq, folded = q_ref.shape[1:]
     tokens = block_pages * page_size
     b = pl.program_id(0)
@@ -230,7 +247,7 @@ def _walk_kernel(
             page = pt_ref[slot, at + j]
         return [
             pltpu.make_async_copy(src.at[page], dst.at[buf, j], sem.at[buf])
-            for src, dst in ((k_hbm, kbuf), (v_hbm, vbuf))
+            for src, dst in pools
         ]
 
     def live_in_block(slot, blk):
@@ -287,6 +304,11 @@ def _walk_kernel(
 
         wait(b, blk, buf)
         k = kbuf[buf].reshape(tokens, folded)
+        if value_lanes is not None:
+            return _attend(
+                q_ref[0], k, k[:, :value_lanes], blk * tokens, pos, scale,
+                carry,
+            )
         v = vbuf[buf].reshape(tokens, folded)
         if window is None:
             return _attend(q_ref[0], k, v, blk * tokens, pos, scale, carry)
@@ -299,9 +321,13 @@ def _walk_kernel(
     # Position 0 is always visible (pos >= 0), so l > 0 — no NaN rows
     # even for freshly-admitted or parked slots.
     _, l, acc = jax.lax.fori_loop(
-        0, num_blocks, body, _init_carry(hq, folded)
+        0, num_blocks, body,
+        _init_carry(hq, folded if value_lanes is None else value_lanes),
     )
     first_buf[0] = (base + num_blocks) % 2
+    if value_lanes is not None:  # every head's p . c, as it lies
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
+        return
     wide_ref[...] = acc / l
     _write_heads(o_ref, wide_ref)
 
@@ -367,7 +393,7 @@ def _page_step_kernel(
 def paged_attention(
     q: jax.Array,
     key_pages: jax.Array,
-    value_pages: jax.Array,
+    value_pages: jax.Array | None,
     page_table: jax.Array,
     pos: jax.Array,
     *,
@@ -377,9 +403,19 @@ def paged_attention(
     pages_per_slot: int | None = None,
     first_pos: jax.Array | None = None,
     window: int | None = None,
+    value_lanes: int | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """One decode step of ``q`` [B, 1, Hq, D] against paged KV pools,
     reading only each slot's live pages (module docstring).
+
+    **A latent pool** (``value_pages`` None, ``value_lanes`` and
+    ``scale`` given; models/latent.py): ``key_pages`` is ``[num_pages,
+    page_size, lanes]``, one row a token shared by every head, and ``q``
+    ``[B, 1, Hq, lanes]`` holds each head's absorbed query over the same
+    lanes. Scores are ``q . row * scale`` over the whole row, values the
+    row's first ``value_lanes`` lanes: the same walk with ONE pool,
+    copied once a block; returns ``[B, 1, Hq, value_lanes]``.
 
     ``key_pages``/``value_pages`` are ``[num_pages, page_size, Hkv*D]``
     pools (``D`` is ``q``'s; the int8 variant's scale pools stay
@@ -405,6 +441,17 @@ def paged_attention(
     b, t, hq, d = q.shape
     if t != 1:
         raise ValueError(f"paged decode steps one token at a time, got t={t}")
+    if interpret is None:
+        from cs744_pytorch_distributed_tutorial_tpu.ops._backend import (
+            default_interpret,
+        )
+
+        interpret = default_interpret()
+    if value_pages is None:
+        return _latent_walk(
+            q, key_pages, page_table, pos, value_lanes, scale, interpret,
+            pages_per_slot,
+        )
     if key_pages.ndim != 3 or key_pages.shape[-1] % d:
         raise ValueError(
             f"pools are [num_pages, page_size, Hkv*D] with D={d}, "
@@ -425,12 +472,6 @@ def paged_attention(
             f"multiple of 128 lanes (got {folded}, int8={quant}): the "
             "page-a-step path has no window"
         )
-    if interpret is None:
-        from cs744_pytorch_distributed_tutorial_tpu.ops._backend import (
-            default_interpret,
-        )
-
-        interpret = default_interpret()
 
     group = hq // hkv
     pt = page_table
@@ -468,7 +509,8 @@ def paged_attention(
             pltpu.SMEM((1,), jnp.int32),
         ]
         kernel = partial(
-            _walk_kernel, page_size, block_pages, capacity, d**-0.5, window
+            _walk_kernel, page_size, block_pages, capacity, d**-0.5, window,
+            None,
         )
     else:
         def live_page(bi, i, lens, table):
@@ -507,3 +549,59 @@ def paged_attention(
         interpret=interpret,
     )(*prefetched, *operands)
     return out.reshape(b, 1, hq, d)
+
+
+def _latent_walk(
+    q, latent_pages, page_table, pos, value_lanes, scale, interpret,
+    pages_per_slot,
+):
+    """``paged_attention`` over one latent pool (its docstring): the
+    walk of ``_walk_kernel`` with every head on the pool's one row."""
+    b, _, hq, lanes = q.shape
+    if (
+        value_lanes is None or scale is None or latent_pages.ndim != 3
+        or latent_pages.shape[-1] != lanes or lanes % 128
+        or value_lanes > lanes
+    ):
+        raise ValueError(
+            "a latent pool is [num_pages, page_size, lanes] with lanes whole "
+            "128-lane tiles (the walk DMAs lane-aligned slices), q [B, 1, "
+            "Hq, lanes], and needs value_lanes <= lanes and scale; got pool "
+            f"{latent_pages.shape}, "
+            f"q {q.shape}, value_lanes {value_lanes}, scale {scale}"
+        )
+    page_size = latent_pages.shape[1]
+    pt = page_table if pages_per_slot is None else page_table[:, :pages_per_slot]
+    capacity = pt.shape[1]
+    block_pages = _pages_per_block(
+        capacity, page_size, lanes * latent_pages.dtype.itemsize
+    )
+    out = pl.pallas_call(
+        partial(
+            _walk_kernel, page_size, block_pages, capacity, float(scale),
+            None, value_lanes,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, hq, lanes), lambda bi, *_: (bi, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, hq, value_lanes), lambda bi, *_: (bi, 0, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM(
+                    (2, block_pages, page_size, lanes), latent_pages.dtype
+                ),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (b, hq, value_lanes), latent_pages.dtype
+        ),
+        interpret=interpret,
+    )(pos.astype(jnp.int32), pt.astype(jnp.int32), q[:, 0], latent_pages)
+    return out[:, None]

@@ -40,6 +40,13 @@ given back as the window passes them, in prefill and in decode
 (``window_pool``, ``_window_advance``; docs/serving.md). A model without
 window layers has one pool, one table and the programs it always had.
 
+The pools are the model's own: whatever leaves its ``pages`` collection
+declares (``_pages_shape_tree``). A layer with latent attention
+(``TransformerLM.latent``, models/latent.py) declares ONE pool a
+sublayer, ``latent_pages [num_pages, page_size, lanes]``, where another
+declares ``key_pages`` and ``value_pages``; the engine builds, donates,
+leases and frees it under the same page table (docs/serving.md).
+
 Decode attention has two implementations (``paged_attention_impl``):
 the "gather" reference is BITWISE-identical to the dense-cache path (the
 gathered page view reproduces the cache layout exactly and runs the same
@@ -86,9 +93,12 @@ from cs744_pytorch_distributed_tutorial_tpu.utils.failure import (
     DecodeNanError,
 )
 
-# cache leaf -> pages leaf: the prefill commit scatters the dense cache
-# rows a prefill pass wrote into the slot's pages. Names mirror the
-# cache's on purpose (models/transformer.py keeps them mechanical).
+# cache leaf -> pages leaf: the one-shot prefill's commit scatters the
+# dense cache rows a prefill pass wrote into the slot's pages. Names
+# mirror the cache's on purpose (models/transformer.py keeps them
+# mechanical). A pool with no dense-cache counterpart (a window layer's
+# group, a latent layer's ``latent_pages``) is filled by the chunk
+# program alone, and the engine refuses such a model the one-shot path.
 _CACHE_TO_PAGES = {
     "cached_key": "key_pages",
     "cached_value": "value_pages",
@@ -107,29 +117,47 @@ def _named_leaves(tree: Any, name: str) -> list[Any]:
     ]
 
 
+# The counters a decode step can carry behind its tokens, by the group
+# of the model's mechanism that sows them; ``_step_counters`` returns the
+# names of those a model has, in this order.
+_ROUTING_COUNTERS = (
+    "selected_tokens", "scored_tokens", "experts_hit", "expert_ratio_milli",
+)
+_WINDOW_COUNTERS = ("full_tokens_read", "window_tokens_read")
+_LATENT_COUNTERS = ("latent_tokens_read",)
+_SHARE_COUNTERS = (
+    "held_expert_pairs", "zero_expert_pairs", "absent_expert_pairs",
+)
+
+
 def _step_counters(stats: Any, active: jnp.ndarray, num_experts: int):
     """What the model sowed into "serve_stats" in one decode step, over
-    the active slots and summed over the layers, as four int32 that ride
-    behind the step's tokens: tokens the selection kept, tokens the
-    indexer scored, experts that received a token, and a thousand times
-    the layers' mean of (most tokens on one expert / mean tokens an
-    expert); behind them, from a model with window layers, two more:
-    keys attended on its full layers and on its window layers. None
-    where the model sowed nothing."""
+    the active slots and summed over the layers, as int32 that ride
+    behind the step's tokens, and their names. First four: tokens the
+    selection kept, tokens the indexer scored, experts (of the
+    ``num_experts`` computed here) that received a token, and a thousand
+    times the layers' mean of (most tokens on one expert / mean tokens
+    an expert). Behind them, from a model with window layers, the keys
+    attended on its full and on its window layers; from a model with
+    latent attention, the latent rows attended; from an expert layer
+    that holds a share or has zero-compute experts, its (token, expert)
+    pairs by where the expert is. (None, ()) where the model sowed
+    nothing."""
     selected = _named_leaves(stats, "selected_tokens")
     scored = _named_leaves(stats, "scored_tokens")
     routed = _named_leaves(stats, "expert_idx")
     full_read = _named_leaves(stats, "full_tokens_read")
     window_read = _named_leaves(stats, "window_tokens_read")
-    if not (selected or routed or full_read):
-        return None
+    latent_read = _named_leaves(stats, "latent_tokens_read")
+    if not (selected or routed or full_read or latent_read):
+        return None, ()
     zero = jnp.int32(0)
 
     def over_active(leaves):
         return sum((jnp.sum(jnp.where(active, x, 0)) for x in leaves), zero)
 
     hit, ratio = zero, jnp.float32(0.0)
-    for idx in routed:  # [B, K] expert ids of one layer
+    for idx in routed:  # [B, K] expert ids of one layer (-1: not here)
         counts = jnp.sum(
             jax.nn.one_hot(idx, num_experts, dtype=jnp.int32)
             * active[:, None, None],
@@ -141,9 +169,19 @@ def _step_counters(stats: Any, active: jnp.ndarray, num_experts: int):
         )
     milli = jnp.round(1e3 * ratio / max(len(routed), 1))
     counters = [over_active(selected), over_active(scored), hit, milli]
+    names = _ROUTING_COUNTERS
     if full_read:
         counters += [over_active(full_read), over_active(window_read)]
-    return jnp.stack(counters).astype(jnp.int32)
+        names += _WINDOW_COUNTERS
+    if latent_read:
+        counters.append(over_active(latent_read))
+        names += _LATENT_COUNTERS
+    if _named_leaves(stats, _SHARE_COUNTERS[0]):
+        counters += [
+            over_active(_named_leaves(stats, name)) for name in _SHARE_COUNTERS
+        ]
+        names += _SHARE_COUNTERS
+    return jnp.stack(counters).astype(jnp.int32), names
 
 
 @dataclass
@@ -406,6 +444,13 @@ class ServingEngine:
                 cfg.num_slots * self.window_table_width + 1, cfg.page_size
             )
             geometry["window_num_pages"] = self.window_pool.num_pages
+        if getattr(model, "latent", None) is not None and not cfg.prefill_chunk:
+            raise ValueError(
+                "a model with latent attention is served by chunks: the "
+                "one-shot prefill keeps a dense cache of keys and values a "
+                "head, which the latent pool exists to avoid. Set "
+                "ServeConfig.prefill_chunk"
+            )
         self.model = model.clone(
             page_size=cfg.page_size,
             num_pages=cfg.num_pages,
@@ -422,8 +467,6 @@ class ServingEngine:
         self._window_table = np.zeros((b, self.window_table_width), np.int32)
         self._window_first = np.zeros((b,), np.int32)  # first row's position
         self._window_pages_freed = 0  # given back as the window passed
-        self._full_tokens_read = 0  # keys attended, by layer kind
-        self._window_tokens_read = 0
         self._next_id = 0
         self._admit_seq = 0
         self._step_count = 0
@@ -455,10 +498,11 @@ class ServingEngine:
         # over steps and layers: sparse attention's kept and scored
         # tokens, experts that received a token, and the running sum of
         # the steps' expert-load ratio.
-        self._selected_tokens = 0
-        self._scored_tokens = 0
-        self._experts_hit = 0
-        self._expert_ratio_sum = 0.0
+        self._counter_names: tuple[str, ...] = ()  # set when the step traces
+        self._counts = dict.fromkeys(
+            _ROUTING_COUNTERS + _WINDOW_COUNTERS + _LATENT_COUNTERS
+            + _SHARE_COUNTERS, 0,
+        )
         if cfg.prefill_chunk is not None and cfg.prefill_chunk < 1:
             raise ValueError(
                 f"prefill_chunk must be >= 1 or None, got {cfg.prefill_chunk}"
@@ -622,10 +666,14 @@ class ServingEngine:
             # Device-side counters ride behind the tokens, in the one
             # array the host fetches a step; a model that sows none
             # compiles the step it always did.
-            counters = _step_counters(
+            held = getattr(model, "moe_held_experts", None)
+            counters, names = _step_counters(
                 mutated.get("serve_stats", {}), active,
-                getattr(model, "num_experts", 0),
+                getattr(model, "num_experts", 0) if held is None else len(held),
             )
+            # which counters this model's step carries is known once it
+            # is traced, before any step's result is read
+            self._counter_names = names
             if counters is not None:
                 tok = jnp.concatenate([tok, counters])
             return mutated["pages"], tok
@@ -1469,14 +1517,9 @@ class ServingEngine:
                 )
             self._step_count += 1
             self._active_slot_steps += n_active
-            if counters.size:  # _step_counters, behind the tokens
-                self._selected_tokens += int(counters[0])
-                self._scored_tokens += int(counters[1])
-                self._experts_hit += int(counters[2])
-                self._expert_ratio_sum += float(counters[3]) / 1e3
-                if counters.size > 4:
-                    self._full_tokens_read += int(counters[4])
-                    self._window_tokens_read += int(counters[5])
+            # _step_counters, behind the tokens
+            for name, value in zip(self._counter_names, counters):
+                self._counts[name] += int(value)
             # Inactive slots still write one KV row per step — to the
             # trash page (fixed-shape contract).
             self._trash_rows += cfg.num_slots - n_active
@@ -1741,12 +1784,14 @@ class ServingEngine:
             "prefill_chunks": self._prefill_chunks,
             # summed over decode steps and layers (``_step_counters``);
             # selected / scored is the sparsity served
-            "selected_tokens": self._selected_tokens,
-            "scored_tokens": self._scored_tokens,
-            "experts_hit": self._experts_hit,
+            "selected_tokens": self._counts["selected_tokens"],
+            "scored_tokens": self._counts["scored_tokens"],
+            # of the experts whose matrices are here (a share: the held)
+            "experts_hit": self._counts["experts_hit"],
             # mean over decode steps of the layers' mean of (most tokens
             # on one expert / mean tokens an expert)
-            "expert_tokens_max_over_mean": self._expert_ratio_sum / steps,
+            "expert_tokens_max_over_mean": self._counts["expert_ratio_milli"]
+            / 1e3 / steps,
             # the page groups: live pages of each now, window pages given
             # back as the window passed them, and the keys the decode
             # steps attended on the layers of each kind (a model with
@@ -1756,8 +1801,17 @@ class ServingEngine:
                 self.window_pool.allocated_pages if self.window_pool else 0
             ),
             "window_pages_freed": self._window_pages_freed,
-            "full_tokens_read": self._full_tokens_read,
-            "window_tokens_read": self._window_tokens_read,
+            "full_tokens_read": self._counts["full_tokens_read"],
+            "window_tokens_read": self._counts["window_tokens_read"],
+            # a model with latent attention: latent rows the decode steps
+            # attended, summed over active slots and sublayers; an expert
+            # layer that holds a share or has zero-compute experts: its
+            # (token, expert) pairs by where the expert is (the three sum
+            # to top_k a token a layer). 0 otherwise
+            "latent_tokens_read": self._counts["latent_tokens_read"],
+            "held_expert_pairs": self._counts["held_expert_pairs"],
+            "zero_expert_pairs": self._counts["zero_expert_pairs"],
+            "absent_expert_pairs": self._counts["absent_expert_pairs"],
         }
 
 

@@ -62,12 +62,22 @@ def build_parser() -> argparse.ArgumentParser:
                         "hf_interop.py::model_config_from_hf: KeyeVL2 = gated "
                         "experts, q/k norm, the sparse-attention indexer; "
                         "mellum = gated experts, q/k norm, sliding-window "
-                        "and full layers mixed, YaRN on the full ones) in "
+                        "and full layers mixed, YaRN on the full ones; "
+                        "longcat_flash = latent attention (MLA) over one "
+                        "paged latent pool a sublayer, the two-attention "
+                        "shortcut-MoE layer, zero-compute experts, a chip's "
+                        "share of the routed ones) in "
                         "place of the size flags above; --max-seq-len still "
                         "caps a request. Random params, as ever; no "
                         "--parity-check (the dense-cache generator has no "
-                        "indexer and no window); window layers need "
-                        "--prefill-chunk")
+                        "indexer, no window and no latent); window and "
+                        "latent layers need --prefill-chunk")
+    p.add_argument("--held-experts", type=int, default=None, metavar="N",
+                   help="with --model-config longcat_flash: serve one "
+                        "chip's share of the experts, routed experts "
+                        "0..N-1 of the config's n_routed_experts; the "
+                        "router keeps its width, the other routed "
+                        "experts' terms are left out (docs/serving.md)")
     # engine geometry
     p.add_argument("--num-slots", type=int, default=8,
                    help="decode slots B in the fixed-shape jitted step")
@@ -235,9 +245,15 @@ def main(argv: list[str] | None = None) -> None:
             model_config_from_hf,
         )
 
+        how_deployed = (
+            {} if args.held_experts is None
+            else {"held_experts": range(args.held_experts)}
+        )
         with open(args.model_config, encoding="utf-8") as f:
             model = TransformerLM(
-                **model_config_from_hf(json.load(f), max_seq_len=args.max_seq_len)
+                **model_config_from_hf(
+                    json.load(f), max_seq_len=args.max_seq_len, **how_deployed
+                )
             )
         args.vocab_size = model.vocab_size
     else:

@@ -185,3 +185,70 @@ def test_no_program_copies_any_of_three_pools(sparse_compiled, program):
     # layer's three pools
     most = k_pool_bytes if program == "decode" else 2 * k_pool_bytes + got.pool_bytes
     assert got.temp_bytes < most, got
+
+
+@pytest.fixture(scope="module")
+def latent_compiled(one_chip):
+    """A model with latent attention: ONE pool a sublayer, at the
+    published lane widths (a latent of 512 and a rope key of 64 in a
+    row of 640 lanes), few heads and small matrices."""
+    from cs744_pytorch_distributed_tutorial_tpu.models import (
+        longcat_flash_model_config,
+    )
+
+    hf = dict(
+        vocab_size=512, hidden_size=256, ffn_hidden_size=512,
+        expert_ffn_hidden_size=256, num_layers=1, num_attention_heads=8,
+        kv_lora_rank=512, q_lora_rank=128, qk_rope_head_dim=64,
+        v_head_dim=128, qk_nope_head_dim=128, mla_scale_q_lora=True,
+        mla_scale_kv_lora=True, routed_scaling_factor=6, n_routed_experts=16,
+        max_position_embeddings=512,
+        rms_norm_eps=1e-5, rope_theta=1e7, zero_expert_num=8, moe_topk=4,
+    )
+    model = TransformerLM(
+        **longcat_flash_model_config(hf, held_experts=range(4)),
+        dtype=jnp.bfloat16, flash_interpret=False,
+    )
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+    )["params"]
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16), params
+    )
+    engine = ServingEngine(
+        model, params,
+        ServeConfig(
+            num_slots=4, page_size=16, num_pages=513, max_pages_per_slot=32,
+            prefill_chunk=128, paged_attention_impl="kernel",
+        ),
+    )
+    return engine, compile_programs(engine, 0, one_chip)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_no_program_copies_a_latent_pool(latent_compiled, program):
+    """One pool a sublayer, ``[num_pages, page_size, 640]``: no
+    pool-sized copy in either program, the pools row-major as they
+    enter, and the decode step's walk one ``attn_latent`` call a
+    sublayer over that one pool (the chunk program has none)."""
+    engine, programs = latent_compiled
+    pools = jax.tree_util.tree_leaves_with_path(engine._pages)
+    assert {path[-1].key for path, _ in pools} == {"latent_pages"}
+    assert [leaf.shape for _, leaf in pools] == [(513, 16, 640)] * 2
+    got = audit(programs[program], engine)
+    assert len(got.entry_layouts) == 2 and got.row_major, got.entry_layouts
+    assert got.pool_copies == []
+    assert got.pool_bytes == 513 * 16 * 640 * 2
+    # (b): the chunk's temporaries are its scores over the view, which
+    # grow with the slot's capacity and not with the pool
+    assert got.temp_bytes < got.pool_bytes, got
+    text = programs[program].as_text()
+    walks = {
+        line.split(" = ")[0].strip() for line in text.splitlines()
+        if "tpu_custom_call" in line and "/attn_latent/" in line
+    }
+    assert len(walks) == (2 if program == "decode" else 0), walks
+    for line in text.splitlines():
+        if "tpu_custom_call" in line and "/attn_latent/" in line:
+            # pos, table, q, ONE pool
+            assert line.count("bf16[513,16,640]") == 1, line

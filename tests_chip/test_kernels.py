@@ -309,6 +309,50 @@ def test_paged_attention_mixed_cell_shapes():
         assert_close(got[b:b + 1], want, 3e-2)
 
 
+
+def test_paged_attention_chat_cell_shape():
+    """The chat cell's call over ONE latent pool: 64 slots of up to 288
+    pages of 16, bf16, 64 heads' absorbed queries on a 640-lane row
+    (512 latent + 64 rope + 64 zero), values the row's first 512 lanes;
+    depths from a fresh slot to a full one, block edges of the walk (16
+    pages) among them."""
+    from cs744_pytorch_distributed_tutorial_tpu.models.latent import (
+        attend_by_position,
+    )
+    from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
+        gather_pages,
+    )
+
+    slots, capacity, num_pages, lanes, r = 64, 288, 18433, 640, 512
+    scale = 192 ** -0.5
+    q = _normal(0, (slots, 1, 64, lanes), jnp.bfloat16).at[..., 576:].set(0)
+    pool = _normal(1, (num_pages, PAGE, lanes), jnp.bfloat16).at[..., 576:].set(0)
+    rng = np.random.default_rng(2)
+    table = 1 + rng.permutation(num_pages - 1)[: slots * capacity]
+    table = jnp.asarray(table.reshape(slots, capacity), jnp.int32)
+    pos = rng.integers(0, capacity * PAGE, slots)
+    pos[:8] = [0, 15, 255, 256, 257, 511, 4095, 4607]
+    pos = jnp.asarray(pos, jnp.int32)
+    got = jax.jit(
+        lambda q, pool, table, pos: paged_attention(
+            q, pool, None, table, pos, value_lanes=r, scale=scale,
+            interpret=False,
+        )
+    )(q, pool, table, pos)
+
+    def reference(q, pool, table, pos):
+        view = gather_pages(pool, table).astype(jnp.float32)
+        return attend_by_position(
+            q.astype(jnp.float32), view[:, :, None, :], view[:, :, None, :r],
+            pos[:, None], scale,
+        )
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(reference)(q, pool, table, pos)
+    assert got.shape == (slots, 1, 64, r) and got.dtype == jnp.bfloat16
+    assert_close(got, want, 3e-2)
+
+
 @pytest.fixture(scope="module")
 def serve_cell_programs():
     from cs744_pytorch_distributed_tutorial_tpu.models import TransformerLM
