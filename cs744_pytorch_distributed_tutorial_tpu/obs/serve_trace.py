@@ -746,8 +746,6 @@ def profile_serve_programs(
     from ..utils.profiling import capture_device_profile, compiled_costs
     from .flops import roofline_classify
 
-    cfg = engine.cfg
-    b, p = cfg.num_slots, cfg.max_pages_per_slot
     device_kind = getattr(jax.devices()[0], "device_kind", None)
 
     def fresh_pages():
@@ -766,15 +764,7 @@ def profile_serve_programs(
         decode_step = decode_step.__wrapped__
 
     key = engine._sample_root
-    dec_args = (
-        jnp.zeros((b,), jnp.int32),
-        jnp.ones((b,), jnp.int32),
-        jnp.zeros((b, p), jnp.int32),
-        jnp.ones((b,), jnp.bool_),
-        jnp.arange(b, dtype=jnp.int32),
-        jnp.zeros((b,), jnp.int32),
-        key,
-    )
+    dec_args = (jnp.asarray(engine._probe_decode_arg()), key)
 
     def _runner(fn, args, state):
         def run():

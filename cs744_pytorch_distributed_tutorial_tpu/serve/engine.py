@@ -6,10 +6,12 @@ one, and the dense ``[B, max_seq_len, H, D]`` cache spends HBM on
 padding. This engine serves at REQUEST granularity instead:
 
 - decode runs a fixed-shape jitted step over ``num_slots`` slots —
-  ``(params, pages, tokens[B], lengths[B], page_table[B,P], active[B],
-  key) -> (pages, next_tokens[B])`` — so batch membership changes
-  (retire, refill, preempt) without retracing (graftlint GL002; the
-  0-retrace contract is pinned by tests/test_serve.py);
+  ``(params, pages, packed, key) -> (pages, next_tokens[B])``, where
+  ``packed`` is ONE int32 vector (tokens, lengths, actives, request ids,
+  token indices, the page table ``[B,P]``: one put a step) — so batch
+  membership changes (retire, refill, preempt) without retracing
+  (graftlint GL002; the 0-retrace contract is pinned by
+  tests/test_serve.py);
 - KV lives in per-layer page POOLS (``[num_pages, page_size, Hkv*D]``,
   the "pages" variable collection of ``mode="paged_decode"``; heads
   folded so that no program copies a pool, ``serve/layout.py``),
@@ -460,12 +462,17 @@ class ServingEngine:
         self.max_seq_len = model.max_seq_len
         self._scanned = bool(getattr(model, "scan_layers", False))
 
-        b, p = cfg.num_slots, cfg.max_pages_per_slot
         self._queue: deque[Request] = deque()
-        self._slots: list[_Slot | None] = [None] * b
-        self._page_table = np.zeros((b, p), np.int32)  # 0 = trash page
-        self._window_table = np.zeros((b, self.window_table_width), np.int32)
-        self._window_first = np.zeros((b,), np.int32)  # first row's position
+        self._slots: list[_Slot | None] = [None] * cfg.num_slots
+        # The decode step's ONE host argument, allocated once; the tables
+        # (0 = trash page) are views of it, so the bookkeeping that edits
+        # a table row edits the next step's argument in place. The window
+        # group's two are empty for a model without window layers.
+        self._decode_arg = np.zeros((self._decode_arg_len(),), np.int32)
+        (
+            self._decode_head, self._page_table, self._window_table,
+            self._window_first,
+        ) = self._unpack_decode_arg(self._decode_arg)
         self._window_pages_freed = 0  # given back as the window passed
         self._next_id = 0
         self._admit_seq = 0
@@ -494,6 +501,8 @@ class ServingEngine:
         self._max_admits_in_step = 0
         self._pages_grown = 0  # pages the grow loop allocated
         self._prefill_chunks = 0  # chunk programs run (prefill_chunk set)
+        self._decode_puts = 0  # host-to-device puts made in decode_prep
+        self._pool_audits = 0  # whole-pool audits run (``_audit_pools``)
         # What the decode steps' models sowed (``_step_counters``), summed
         # over steps and layers: sparse attention's kept and scored
         # tokens, experts that received a token, and the running sum of
@@ -625,19 +634,20 @@ class ServingEngine:
         )
 
     def _build_decode_step(self):
-        """ONE jitted fixed-shape step for the engine's lifetime: every
-        argument is an array of static shape, so slot churn (retire /
-        refill / preempt — different page tables, lengths, actives,
-        request ids, token indices) re-runs the SAME executable. Pages
-        are donated: XLA aliases the pool buffers in place, the step
-        allocates no new pool."""
+        """ONE jitted fixed-shape step for the engine's lifetime: what
+        varies from step to step arrives in one traced vector of static
+        shape (``_unpack_decode_arg``), so slot churn (retire / refill /
+        preempt — different page tables, lengths, actives, request ids,
+        token indices) re-runs the SAME executable. Pages are donated:
+        XLA aliases the pool buffers in place, the step allocates no new
+        pool."""
         cfg = self.cfg
         model = self.model
 
-        def step(
-            params, pages, tokens, lengths, page_table, active, req_ids,
-            tok_idx, key, *window,
-        ):
+        def step(params, pages, packed, key):
+            head, page_table, *window = self._unpack_decode_arg(packed)
+            tokens, lengths, active, req_ids, tok_idx = head
+            active = active != 0
             logits, mutated = model.apply(
                 {"params": params, "pages": pages},
                 tokens[:, None],
@@ -678,7 +688,46 @@ class ServingEngine:
                 tok = jnp.concatenate([tok, counters])
             return mutated["pages"], tok
 
-        return self._jit_pages_program(step, 7)
+        return self._jit_pages_program(step, 2)
+
+    # The decode step takes ONE int32 vector beside the pools and the
+    # constant stream root, as the prefill programs do, so a step is one
+    # put:
+    #   [tokens | lengths | active (0/1) | req_ids | tok_idx |
+    #    page table | window table | window first pos]
+    # (five rows of ``num_slots``, the head; then the tables row-major,
+    # the last two of no width without window layers). ``req_ids`` and
+    # ``tok_idx`` are the sampling stream's (see _sample_root).
+
+    def _decode_arg_len(self) -> int:
+        b, w = self.cfg.num_slots, self.window_table_width
+        return b * (5 + self.cfg.max_pages_per_slot + (w + 1 if w else 0))
+
+    def _unpack_decode_arg(self, packed):
+        """(head [5, B], page table [B, P], the window group's table
+        [B, P_w] and first positions [B] or [0]) of the decode step's
+        argument, by slices and reshapes alone: views of a host buffer,
+        static slices of a traced vector."""
+        b, p = self.cfg.num_slots, self.cfg.max_pages_per_slot
+        w = self.window_table_width
+        table_end = 5 * b + b * p
+        window_end = table_end + b * w
+        return (
+            packed[: 5 * b].reshape(5, b),
+            packed[5 * b: table_end].reshape(b, p),
+            packed[table_end:window_end].reshape(b, w),
+            packed[window_end:],
+        )
+
+    def _probe_decode_arg(self) -> np.ndarray:
+        """A decode argument for tools that lower or time the program off
+        the serving path: every slot active at length 1, request ids
+        0..B-1, every table row on the trash page."""
+        packed = np.zeros_like(self._decode_arg)
+        head = self._unpack_decode_arg(packed)[0]
+        head[1:3] = 1
+        head[3] = np.arange(self.cfg.num_slots)
+        return packed
 
     def _prefill_fn(self, bucket: int):
         """Jitted prefill+commit for one prompt-length bucket: dense
@@ -1075,13 +1124,18 @@ class ServingEngine:
             self._window_table[i, :] = 0
             self._window_first[i] = 0
         self._slots[i] = None
-        if __debug__:
-            # Every page-freeing path (retire, preempt, deadline expiry)
-            # funnels through here — audit the free-list/live accounting
-            # at the moment a leak or double-lease would be introduced.
-            self.pool.check_invariants()
-            if self.window_pool is not None:
-                self.window_pool.check_invariants()
+
+    def _audit_pools(self) -> None:
+        """The whole-pool audit (``PagePool.check_invariants``), which
+        costs a walk of every page: run where the engine is off the
+        clock, never on the path of a step. Every page-freeing path
+        (retire, preempt, deadline expiry) funnels through
+        ``_free_slot``, and ``PagePool.free`` checks there what a free
+        can break, at the cost of the pages freed."""
+        self._pool_audits += 1
+        self.pool.check_invariants()
+        if self.window_pool is not None:
+            self.window_pool.check_invariants()
 
     def _window_advance(
         self, pages: list[int], first: int, oldest: int, newest: int
@@ -1347,9 +1401,9 @@ class ServingEngine:
     def _expire_request(self, req: Request, slot: int | None,
                         reason: str) -> None:
         """Retire ``req`` with terminal status ``timed_out``: an active
-        slot's pages free immediately (the invariant check in
-        ``_free_slot`` audits the reclamation), a queued request just
-        resolves. ``reason`` is the budget that expired (``deadline`` or
+        slot's pages free immediately (``PagePool.free`` checks each
+        page of the reclamation), a queued request just resolves.
+        ``reason`` is the budget that expired (``deadline`` or
         ``queue_wait``)."""
         self._emit({
             "kind": "serve",
@@ -1467,39 +1521,33 @@ class ServingEngine:
         # decode one token for every active slot
         cfg = self.cfg
         t_d0 = self.clock()
-        with profiling.annotate("serve/decode_prep", step=step):
-            window_args = ()
+        with profiling.annotate("serve/decode_prep", step=step) as prep_span:
             if self.window_pool is not None:
-                window_args = self._window_step(step)
-            tokens = np.full((cfg.num_slots,), cfg.pad_id, np.int32)
-            lengths = np.zeros((cfg.num_slots,), np.int32)
-            active = np.zeros((cfg.num_slots,), bool)
-            req_ids = np.zeros((cfg.num_slots,), np.int32)
-            tok_idx = np.zeros((cfg.num_slots,), np.int32)
+                self._window_step(step)
+            # The five rows at the head of the packed argument; the
+            # tables behind them are kept current as slots change.
+            tokens, lengths, is_active, req_ids, tok_idx = self._decode_head
+            self._decode_head[:] = 0
+            tokens[:] = cfg.pad_id
             for i, slot in enumerate(self._slots):
                 if slot is None:
                     continue
                 tokens[i] = slot.last_tok
                 lengths[i] = slot.length
-                active[i] = True
+                is_active[i] = 1
                 req_ids[i] = slot.req.req_id
                 # Absolute output-token index this step produces for the
                 # request — the per-request PRNG stream position (see
                 # _sample_root; replay-exact across preemptions).
                 tok_idx[i] = slot.req.output_tokens
+            active = is_active != 0
             n_active = int(active.sum())
-            args = (
-                jnp.asarray(tokens),
-                jnp.asarray(lengths),
-                jnp.asarray(self._page_table),
-                jnp.asarray(active),
-                jnp.asarray(req_ids),
-                jnp.asarray(tok_idx),
-            )
+            packed = jax.device_put(self._decode_arg)
+            self._decode_puts += 1
+            prep_span.set_metadata(puts=1)
         with profiling.annotate("serve/decode", step=step, active=n_active):
             self._pages, toks = self._decode_step(
-                self.params, self._pages, *args, self._sample_root,
-                *window_args,
+                self.params, self._pages, packed, self._sample_root
             )
             toks = np.asarray(toks)  # graftlint: disable=GL001 -- the scheduler NEEDS this sync: retire/refill decisions read the sampled tokens; one fetch per engine step, outside any jit
             toks, counters = toks[: cfg.num_slots], toks[cfg.num_slots:]
@@ -1518,8 +1566,8 @@ class ServingEngine:
             self._step_count += 1
             self._active_slot_steps += n_active
             # _step_counters, behind the tokens
-            for name, value in zip(self._counter_names, counters):
-                self._counts[name] += int(value)
+            for name, value in zip(self._counter_names, counters.tolist()):
+                self._counts[name] += value
             # Inactive slots still write one KV row per step — to the
             # trash page (fixed-shape contract).
             self._trash_rows += cfg.num_slots - n_active
@@ -1542,12 +1590,12 @@ class ServingEngine:
                     len(self._queue),
                 )
             done_at_fetch = len(self._completed)
-            for i, slot in enumerate(self._slots):
+            for i, (slot, tok) in enumerate(zip(self._slots, toks.tolist())):
                 if slot is None:
                     continue
                 slot.length += 1
-                slot.last_tok = int(toks[i])
-                slot.req.generated.append(slot.last_tok)
+                slot.last_tok = tok
+                slot.req.generated.append(tok)
                 self._surface(slot.req, slot.last_tok, now)
                 if self._slot_done(slot):
                     self._retire(i)
@@ -1566,11 +1614,12 @@ class ServingEngine:
         row[: len(pages)] = pages
         self._window_first[i] = first * self.cfg.page_size
 
-    def _window_step(self, step: int) -> tuple[Any, Any]:
+    def _window_step(self, step: int) -> None:
         """Before a decode step of a model with window layers: every
         active slot gives back the window pages its next query no longer
-        sees and leases the page its next row needs; returns the group's
-        table and first positions on the device."""
+        sees and leases the page its next row needs. The group's table
+        and first positions are views of the step's packed argument
+        (``_set_window_row`` writes there): nothing is put here."""
         with profiling.annotate("serve/window_free", step=step) as span:
             freed = 0
             for i, slot in enumerate(self._slots):
@@ -1588,15 +1637,13 @@ class ServingEngine:
                 freed += dropped
             self._window_pages_freed += freed
             span.set_metadata(pages=freed)
-            return (
-                jnp.asarray(self._window_table),
-                jnp.asarray(self._window_first),
-            )
 
     def run(self) -> list[Request]:
         """Drain: step until the queue and every slot are empty."""
         while self.busy:
             self.step()
+        # drained, so off the clock and every page must be back
+        self._audit_pools()
         return self._completed
 
     # ------------------------------------------------------- streaming
@@ -1684,6 +1731,8 @@ class ServingEngine:
                 "in_flight": in_flight,
             }
 
+        # off the clock, and what is captured must rest on a whole pool
+        self._audit_pools()
         active = sorted(
             (s for s in self._slots if s is not None),
             key=lambda s: s.admit_seq,
@@ -1782,6 +1831,11 @@ class ServingEngine:
             "max_admits_in_step": self._max_admits_in_step,
             "pages_grown": self._pages_grown,
             "prefill_chunks": self._prefill_chunks,
+            # decode_puts / decode_steps: host-to-device puts a decode
+            # step (one packed vector); whole-pool audits run (none on
+            # the path of a step: ``_audit_pools``)
+            "decode_puts": self._decode_puts,
+            "pool_audits": self._pool_audits,
             # summed over decode steps and layers (``_step_counters``);
             # selected / scored is the sparsity served
             "selected_tokens": self._counts["selected_tokens"],
@@ -1851,16 +1905,8 @@ def make_serve_trace_entry(_impl: str = "gather", **overrides):
         paged_attention_impl=_impl,
     )
     eng = ServingEngine(model, params, cfg)
-    b, p = cfg.num_slots, cfg.max_pages_per_slot
     args = (
-        params,
-        eng._pages,
-        jnp.zeros((b,), jnp.int32),
-        jnp.zeros((b,), jnp.int32),
-        jnp.zeros((b, p), jnp.int32),
-        jnp.ones((b,), jnp.bool_),
-        jnp.arange(b, dtype=jnp.int32),
-        jnp.zeros((b,), jnp.int32),
+        params, eng._pages, jnp.asarray(eng._probe_decode_arg()),
         jax.random.key(0),
     )
     return TracedStep(

@@ -12,8 +12,8 @@ never retraces (GL002):
   before first admission. Swept at the top of every ``step()`` —
   equivalently, checked at admission (an expired queue head is removed
   before refill) and per decode step (an expired active slot retires
-  and its pages free immediately; ``PagePool.check_invariants`` audits
-  the reclamation). Expired requests resolve terminally as
+  and its pages free immediately; ``PagePool.free`` checks each page
+  of the reclamation). Expired requests resolve terminally as
   ``timed_out`` — never silently dropped, never leaked.
 - **Admission control + shedding** (``ServeGuard.admit``, called from
   ``submit()``): a bounded queue rejects at ``max_queue_depth``
@@ -331,6 +331,7 @@ def run_serve_with_recovery(
         engine._trash_rows = 0
         engine._admissions = engine._admit_steps = 0
         engine._admit_fetches = 0
+        engine._decode_puts = engine._pool_audits = 0
         engine._max_admits_in_step = engine._pages_grown = 0
         engine._decode_walls.clear()
         engine._event_ring.clear()

@@ -80,7 +80,6 @@ def compile_programs(
     from shapes (no array is made or donated). ``sharding`` places every
     argument; None is the default device."""
     cfg = engine.cfg
-    b, p = cfg.num_slots, cfg.max_pages_per_slot
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
@@ -91,15 +90,10 @@ def compile_programs(
     params, pages = shapes(engine.params), shapes(engine._pages)
     key = shapes(engine._sample_root)
     i32 = jnp.int32
-    # a model with window layers: the second group's table and the
-    # position of its first row, a slot
-    w = engine.window_table_width
+    # every program takes one packed int32 vector and the key
     decode = engine._decode_step.lower(
-        params, pages, s((b,), i32), s((b,), i32), s((b, p), i32),
-        s((b,), jnp.bool_), s((b,), i32), s((b,), i32), key,
-        *((s((b, w), i32), s((b,), i32)) if w else ()),
+        params, pages, s((engine._decode_arg_len(),), i32), key
     ).compile()
-    # the prefill programs take one packed int32 vector and the key
     if cfg.prefill_chunk:
         prefill = engine._chunk_fn().lower(
             params, pages,

@@ -252,6 +252,7 @@ def run_poisson(
         engine._trash_rows = 0
         engine._admissions = engine._admit_steps = 0
         engine._admit_fetches = 0
+        engine._decode_puts = engine._pool_audits = 0
         engine._max_admits_in_step = engine._pages_grown = 0
         engine._decode_walls.clear()
         engine._event_ring.clear()

@@ -79,7 +79,24 @@ class PagePool:
         self._allocated += n
         self.total_allocs += n
         self.high_water = max(self.high_water, self._allocated)
+        self._check_counts()
         return out
+
+    def _check_counts(self) -> None:
+        """What ``alloc`` and ``free`` can check of the whole pool at no
+        cost in its size: every allocatable page is counted once, free
+        or live. With ``free``'s per-page checks (a page freed was live,
+        in range, not the trash page, not twice in the call) this holds
+        the identities ``check_invariants`` audits by induction: a pool
+        that satisfied them before the call satisfies them after it."""
+        assert len(self._free) + len(self._live) == self.num_pages - 1, (
+            f"{len(self._free)} free + {len(self._live)} live pages of "
+            f"{self.num_pages - 1} allocatable"
+        )
+        assert self._allocated == len(self._live), (
+            f"allocated counter {self._allocated} != "
+            f"{len(self._live)} live pages"
+        )
 
     def free(self, pages: list[int]) -> None:
         # Validate against the LIVE set, not just the free list: the old
@@ -102,14 +119,17 @@ class PagePool:
         self._live.difference_update(seen)
         self._allocated -= len(pages)
         self.total_frees += len(pages)
+        self._check_counts()
 
     def check_invariants(self) -> bool:
         """Debug audit of the page accounting; raises AssertionError on
         any violation, returns True when clean (so tests can assert it).
 
-        The engine calls this under ``__debug__`` at every retire /
-        preempt / deadline-expiry free — the paths where a bookkeeping
-        bug would silently leak (or double-lease) pages:
+        It walks every page of the pool, so the engine calls it where it
+        is off the clock (``snapshot()``, the drain of ``run()``) and
+        not at every free: there ``free`` and ``alloc`` check what one
+        call can break (``_check_counts``). What a bookkeeping bug would
+        silently leak or double-lease:
 
         - free-list ∪ live pages == every allocatable page (none leaked),
         - free-list ∩ live pages == ∅ (no page both free and leased),

@@ -270,13 +270,10 @@ def test_a_model_without_latent_layers_builds_the_pools_it_built():
     assert sorted(path[-1].key for path, _ in pools) == ["key_pages", "key_pages", "value_pages", "value_pages"]
     assert {leaf.shape for _, leaf in pools} == {(33, 4, 32)}
     i32 = jnp.int32
-    decode = engine._decode_step.lower(
-        params, engine._pages, jnp.zeros((3,), i32), jnp.zeros((3,), i32), jnp.zeros((3, 8), i32),
-        jnp.zeros((3,), bool), jnp.zeros((3,), i32), jnp.zeros((3,), i32), engine._sample_root,
-    )
+    decode = engine._decode_step.lower(params, engine._pages, jnp.zeros((39,), i32), engine._sample_root)
     n_params = len(jax.tree.leaves(params))
     shapes = [tuple(a.shape) for a in jax.tree.leaves(decode.in_avals)]
-    assert shapes[n_params:] == [(33, 4, 32)] * 4 + [(3,), (3,), (3, 8), (3,), (3,), (3,), ()]
+    assert shapes[n_params:] == [(33, 4, 32)] * 4 + [(39,), ()]  # the packed argument: 3 x (5 + 8)
     assert [tuple(o.shape) for o in jax.tree.leaves(decode.out_info)][-1] == (3,)  # tokens, nothing behind them
     assert engine._counter_names == ()
     engine.submit(Request(prompt=np.arange(1, 6, dtype=np.int32), max_new_tokens=3))

@@ -588,6 +588,111 @@ def test_a_chunked_admission_fetches_once(tiny_lm, monkeypatch):
     assert all(len(r.generated) == 2 for r in reqs)
 
 
+# What the engine served for these cases BEFORE the decode step took one
+# packed argument (the six-argument program, on this CPU backend): the
+# existing parity cases of this file and of the window, latent and
+# sparse serving tests, token for token.
+_RECORDED = {
+    "plain-greedy": [
+        [32, 28, 36, 28, 36, 28, 36, 32, 32, 32, 32, 32, 32, 36, 28, 49, 32, 36],
+        [9, 14, 46, 40, 32, 40, 32, 14, 40, 32, 40, 46, 40, 40],
+        [55, 14, 17, 17, 17, 17, 17, 17, 17, 17, 17, 17, 46, 40, 32, 20],
+        [40, 40, 40, 40, 40, 40, 40, 3, 40, 3, 40, 32, 32, 32, 32, 32, 32, 32, 32, 32],
+        [45, 42, 34, 17, 32, 34, 46, 46, 46, 28, 60, 34],
+    ],
+    "plain-sampled": [
+        [35, 8, 27, 53, 53, 17, 30, 44, 32, 49, 36, 14, 42, 46, 49, 20, 14, 1],
+        [48, 49, 1, 14, 46, 28, 14, 28, 14, 8, 32, 40, 14, 40],
+        [53, 20, 17, 3, 20, 17, 9, 38, 47, 44, 41, 28, 20, 14, 46, 46],
+        [55, 32, 14, 9, 46, 44, 44, 44, 8, 14, 46, 32, 30, 40, 9, 49, 44, 28, 60, 46],
+        [36, 22, 8, 30, 34, 45, 60, 53, 7, 46, 27, 50],
+    ],
+    "window": [
+        [222, 132, 232, 200, 226, 216, 155, 2, 9, 163, 173, 240],
+        [74, 33, 33, 198, 46, 203, 33, 9, 9],
+        [221, 177, 138, 169, 183, 206, 147, 225, 6, 133, 206, 174, 98, 53, 87, 99, 151, 179, 143, 38],
+        [118, 138, 212, 240, 97],
+        [35, 171, 221, 202, 158, 141, 43, 105],
+    ],
+    "latent": [
+        [53, 219, 232, 3, 112, 50, 169, 127, 85, 254, 201, 216],
+        [82, 165, 128, 183, 27, 107, 193, 247, 200],
+        [237, 90, 232, 238, 98, 90, 232, 238, 62, 23, 179, 29, 74, 233, 194, 245, 172, 3, 48, 248],
+        [163, 35, 74, 233, 2],
+        [159, 91, 233, 129, 225, 55, 193, 177],
+    ],
+    "sparse": [
+        [60, 180, 110, 217, 100, 183, 205, 110, 92, 236, 25, 42],
+        [176, 123, 4, 51, 252, 151, 155, 167, 254],
+        [141, 109, 253, 250, 107, 39, 118, 150, 186, 93, 234, 149, 54, 195, 234, 45, 194, 120, 253, 209],
+        [172, 25, 253, 152, 25],
+        [110, 39, 17, 215, 189, 24, 39, 17],
+    ],
+}
+
+_TINY_LENGTHS = ((70, 12), (23, 9), (40, 20), (9, 5), (64, 8))
+
+
+def _packed_step_engine(kind, tiny_lm):
+    """(engine, requests) of one of the existing parity cases: the plain
+    engine on ``_ADMIT_CASES`` with a pool so tight that it preempts,
+    greedy and sampled; the small window-group, latent and sparse models
+    (tests/*_tiny.py) on their files' lengths, by chunks."""
+    if kind.startswith("plain"):
+        model, params = tiny_lm
+        sample = dict(temperature=0.8, top_k=20, seed=3) if kind == "plain-sampled" else {}
+        cfg = ServeConfig(num_slots=3, page_size=4, num_pages=9, max_pages_per_slot=7, **sample)
+        rng = np.random.default_rng(13)
+        prompts = [(rng.integers(1, VOCAB, size=n).astype(np.int32), m) for n, m in _ADMIT_CASES]
+    else:
+        import importlib
+
+        tiny = importlib.import_module({"window": "mellum_tiny", "latent": "longcat_tiny", "sparse": "keye_tiny"}[kind])
+        model, params, _ = tiny.build(tiny.tiny_config())
+        cfg = ServeConfig(num_slots=3, page_size=8, num_pages=49, max_pages_per_slot=14, prefill_chunk=12)
+        rng = np.random.default_rng(0)
+        prompts = [(rng.integers(0, 256, n).astype(np.int32), m) for n, m in _TINY_LENGTHS]
+    eng = ServingEngine(model, params, cfg)
+    return eng, [eng.submit(Request(prompt=p, max_new_tokens=m)) for p, m in prompts]
+
+
+@pytest.mark.parametrize("kind", ["plain-greedy", "plain-sampled", "window", "latent", "sparse"])
+def test_a_decode_step_is_one_packed_put(tiny_lm, kind, monkeypatch):
+    """The decode program takes (params, pages, ONE int32 vector, key),
+    whatever the model keeps beside the page table (a window group's
+    table and first positions ride in the same vector), and the step
+    makes exactly one host-to-device put for it: ``jax.device_put`` is
+    counted, the program's arguments are looked at, and ``stats()``
+    counts the same. The tokens are those the six-argument program
+    served, greedy and sampled."""
+    eng, reqs = _packed_step_engine(kind, tiny_lm)
+    assert (eng.window_pool is not None) == (kind == "window")
+    puts, seen = [], []
+    device_put = jax.device_put
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **kw: puts.append(x.shape) or device_put(x, *a, **kw))
+    program = eng._decode_step
+
+    def looked_at(*args):
+        _, _, packed, _ = args
+        seen.append((isinstance(packed, jax.Array), packed.shape, packed.dtype))
+        return program(*args)
+
+    eng._decode_step = looked_at
+    eng.run()
+    stats = eng.stats()
+    n = eng._decode_arg_len()
+    b, p, w = eng.cfg.num_slots, eng.cfg.max_pages_per_slot, eng.window_table_width
+    assert n == b * (5 + p) + (b * (w + 1) if kind == "window" else 0)
+    assert stats["decode_puts"] == stats["decode_steps"] == len(puts) == len(seen) > 0
+    assert set(puts) == {(n,)}
+    assert set(seen) == {(True, (n,), np.dtype(np.int32))}  # already on the device
+    assert program._cache_size() == 1
+    if kind.startswith("plain"):
+        assert stats["preemptions"] > 0, "pool was not tight enough"
+    served = [[int(t) for t in r.prompt[r.orig_prompt_len:]] + r.generated for r in reqs]
+    assert served == _RECORDED[kind]
+
+
 def test_engine_streams_tokens(tiny_lm):
     """Tokens surface as they decode, not at retire: the on_token
     callback sees every token in order, token_times stamps each one,

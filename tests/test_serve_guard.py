@@ -3,9 +3,12 @@
 Five pin groups:
 
 1. **Pool accounting is un-corruptible.** ``PagePool.free`` rejects
-   double frees (the silent-corruption bug class behind leaked pages),
-   and ``check_invariants`` — called under ``__debug__`` at every
-   retire/preempt/expiry — proves free ∪ live partitions the pool.
+   double frees and foreign pages (the silent-corruption bug class
+   behind leaked pages) and, with ``alloc``, checks the pool's counts
+   at the cost of the call; ``check_invariants`` — the walk of every
+   page, called by the engine off the clock only (``snapshot()``, the
+   drain of ``run()``), never by a retire/preempt/expiry — proves
+   free ∪ live partitions the pool.
 2. **Deadlines resolve terminally.** ``deadline_s`` / ``max_queue_s``
    expiry retires a request as ``timed_out`` — active slots free their
    pages immediately, queued requests resolve with honestly-absent
@@ -146,6 +149,88 @@ def test_pool_check_invariants_catches_corruption():
     pool._free.append(pool._free[0])  # a double-free that slipped through
     with pytest.raises(AssertionError):
         pool.check_invariants()
+
+
+def test_pool_rejects_a_foreign_page():
+    """A page the pool never leased, in range or not, is refused at
+    ``free()`` and nothing is mutated."""
+    pool = PagePool(num_pages=9, page_size=4)
+    mine = pool.alloc(2)
+    for foreign, match in ((7, "double free"), (9, "out of range"), (0, "trash page")):
+        with pytest.raises(ValueError, match=match):
+            pool.free([mine[0], foreign])
+        assert pool.allocated_pages == 2 and pool.check_invariants()
+
+
+@pytest.mark.parametrize("call", ["alloc", "free"])
+def test_pool_counts_are_checked_at_every_alloc_and_free(call):
+    """What ``alloc`` and ``free`` check of the whole pool costs nothing
+    in its size, and catches a free list that gained or lost a page
+    behind their back at the next call."""
+    pool = PagePool(num_pages=9, page_size=4)
+    pages = pool.alloc(2)
+    pool._free.append(pool._free[0])  # a double-free that slipped through
+    with pytest.raises(AssertionError, match="allocatable"):
+        pool.alloc(1) if call == "alloc" else pool.free(pages)
+
+
+def test_no_step_walks_the_pool_and_the_drain_does(tiny_lm, monkeypatch):
+    """Retiring, preempting and expiring free pages through
+    ``PagePool.free`` alone: no ``check_invariants`` inside a step
+    (counted here, and by ``stats()["pool_audits"]``), one at
+    ``snapshot()`` and one when ``run()`` has drained."""
+    model, params = tiny_lm
+    walks = []
+    audit = PagePool.check_invariants
+    monkeypatch.setattr(
+        PagePool, "check_invariants",
+        lambda self: walks.append(self.num_pages) or audit(self),
+    )
+    clock = _Clock()
+    # 8 allocatable pages, slots want up to 7 each: the grow loop preempts
+    eng = ServingEngine(
+        model, params, _cfg(num_slots=3, num_pages=9, max_pages_per_slot=7),
+        clock=clock, guard=ServeGuard(),
+    )
+    rng = np.random.default_rng(13)
+    reqs = [
+        eng.submit(Request(prompt=_prompt(rng, plen), max_new_tokens=budget))
+        for plen, budget in [(6, 18), (10, 14), (8, 16), (5, 20), (12, 12)]
+    ]
+    reqs[1].deadline_s = 3.0
+    while eng.busy:
+        eng.step()
+        clock.advance(0.25)
+    stats = eng.stats()
+    assert stats["preemptions"] > 0 and stats["timed_out_requests"] == 1
+    assert stats["requests_done"] == len(reqs)
+    assert walks == [] and stats["pool_audits"] == 0
+    assert eng.pool.free_pages == eng.pool.num_pages - 1
+    eng.snapshot()
+    assert walks == [9] and eng.stats()["pool_audits"] == 1
+    eng.submit(Request(prompt=_prompt(rng, 6), max_new_tokens=4))
+    eng.run()
+    assert walks == [9, 9] and eng.stats()["pool_audits"] == 2
+
+
+def test_a_corrupted_pool_fails_at_the_drain_of_run(tiny_lm):
+    """A duplicate on the free list that no ``free`` or ``alloc`` of the
+    run meets (it lies at the bottom of the stack) is what the whole
+    walk is for: ``run()`` ends in it, and ``snapshot()`` starts with
+    it."""
+    model, params = tiny_lm
+    eng = ServingEngine(model, params, _cfg())
+    # bottom of the LIFO stack: swapped for a duplicate, so the counts
+    # still add up and only the walk can tell
+    eng.pool._free[0] = eng.pool._free[1]
+    eng.submit(Request(
+        prompt=_prompt(np.random.default_rng(2), 6), max_new_tokens=4
+    ))
+    with pytest.raises(AssertionError, match="duplicate"):
+        eng.run()
+    assert not eng.busy  # the drain itself finished
+    with pytest.raises(AssertionError, match="duplicate"):
+        eng.snapshot()
 
 
 # ---------------------------------------------------------------------------

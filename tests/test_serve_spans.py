@@ -144,7 +144,8 @@ def capture(tmp_path_factory):
         "calls": calls, "guarded_calls": guarded_calls,
         "stats": {k: stats1[k] - stats0[k] for k in (
             "admissions", "admit_steps", "admit_fetches", "pages_grown",
-            "requests_done", "preemptions", "decode_steps",
+            "requests_done", "preemptions", "decode_steps", "decode_puts",
+            "pool_audits",
         )},
         "max_admits_in_step": stats1["max_admits_in_step"],
         "traced": traced, "untraced": untraced,
@@ -245,6 +246,17 @@ def check_decode_phases_in_order(c):
     assert not _named(c["run"], "serve/expire")  # no guard, no sweep
 
 
+def check_decode_prep_makes_one_put_and_no_step_audits_the_pool(c):
+    """``serve/decode_prep`` says how many host-to-device puts it made
+    (one packed vector, where six arrays went up one by one), and
+    ``stats()`` counts the same; the requests retired and preempted
+    under the capture cost no walk of the page pool."""
+    preps = _named(c["run"], "serve/decode_prep")
+    assert preps and all(p["puts"] == 1 for p in preps)
+    assert c["stats"]["decode_puts"] == c["stats"]["decode_steps"] == len(preps)
+    assert c["stats"]["preemptions"] > 0 and c["stats"]["pool_audits"] == 0
+
+
 def check_readmission_is_a_recompute_under_the_same_req(c):
     assert c["stats"]["preemptions"] > 0, "pool was not tight enough"
     by_req: dict[int, list[str]] = {}
@@ -309,6 +321,7 @@ CHECKS = [
     check_prep_and_prefill_nest_in_their_admit,
     check_one_admit_fetch_in_a_step_that_admitted,
     check_decode_phases_in_order,
+    check_decode_prep_makes_one_put_and_no_step_audits_the_pool,
     check_readmission_is_a_recompute_under_the_same_req,
     check_counters_equal_span_counts,
     check_expire_span_only_under_a_guard,

@@ -229,11 +229,11 @@ def test_a_model_without_window_layers_builds_one_pool_and_one_table():
     assert sorted(jax.tree.leaves(jax.tree.map(lambda x: x.shape, engine._pages), is_leaf=lambda x: isinstance(x, tuple))) == [(33, 4, 32)] * 4
     n_params = len(jax.tree.leaves(params))
     i32 = jnp.int32
-    decode = engine._decode_step.lower(
-        params, engine._pages, jnp.zeros((3,), i32), jnp.zeros((3,), i32), jnp.zeros((3, 8), i32),
-        jnp.zeros((3,), bool), jnp.zeros((3,), i32), jnp.zeros((3,), i32), engine._sample_root,
-    )
-    assert _arg_shapes(decode)[n_params:] == [(33, 4, 32)] * 4 + [(3,), (3,), (3, 8), (3,), (3,), (3,), ()]
+    # the decode step: one packed vector (five rows of 3 slots + the 3 x 8 table, and no window
+    # rows) and the stream's root
+    decode = engine._decode_step.lower(params, engine._pages, jnp.zeros((39,), i32), engine._sample_root)
+    assert _arg_shapes(decode)[n_params:] == [(33, 4, 32)] * 4 + [(39,), ()]
+    assert engine._decode_arg_len() == 39 and engine._window_first.shape == (0,)
     # the prefill programs: one packed vector (bucket 8 + true_len + 8 pages + the stream's two
     # integers; chunk 8 + four scalars + 8 pages, and no window row) and the stream's root
     prefill = engine._prefill_fn(8).lower(params, engine._pages, jnp.zeros((19,), i32), engine._sample_root)
