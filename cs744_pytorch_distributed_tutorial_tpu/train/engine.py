@@ -556,9 +556,10 @@ class Trainer:
 
                 def body(carry, mb):
                     g_sum, l_sum, ll_sum, stats = carry
-                    loss, ll, g, stats = microbatch_grads(
-                        state.params, stats, mb[0], mb[1], mb[2]
-                    )
+                    with jax.named_scope("graftscope/fwd_bwd"):
+                        loss, ll, g, stats = microbatch_grads(
+                            state.params, stats, mb[0], mb[1], mb[2]
+                        )
                     return (
                         jax.tree.map(jnp.add, g_sum, g),
                         l_sum + loss.astype(jnp.float32),
@@ -698,8 +699,9 @@ class Trainer:
                 # cadence fetch. grads here are the post-sync (globally
                 # averaged) gradients, so the norm is the true global
                 # gradient norm; new_params are replicated.
-                metrics["grad_norm"] = tree_l2_norm(grads)
-                metrics["param_norm"] = tree_l2_norm(new_params)
+                with jax.named_scope("graftscope/telemetry"):
+                    metrics["grad_norm"] = tree_l2_norm(grads)
+                    metrics["param_norm"] = tree_l2_norm(new_params)
             new_state = TrainState(
                 step=state.step + 1,
                 params=new_params,
@@ -1067,20 +1069,13 @@ class Trainer:
         # timing window excludes step 0 (utils/timing.py, SURVEY §7d).
         compile_pending = True
 
-        profiling_active = False
+        capture = None
         if cfg.profile_dir:
             from cs744_pytorch_distributed_tutorial_tpu.utils import profiling
 
-        def stop_profile(fence_metrics) -> None:
-            """Close an open capture; fence on the last step's loss so the
-            traced window contains its async device work."""
-            nonlocal profiling_active
-            if not profiling_active:
-                return
-            if fence_metrics is not None:
-                float(fence_metrics["loss"])
-            jax.profiler.stop_trace()
-            profiling_active = False
+            capture = profiling.StepCapture(
+                cfg.profile_dir, cfg.profile_start_step, cfg.profile_num_steps, "train"
+            )
 
         prev_mono = None  # per-step wall clock for the straggler ring
         try:
@@ -1102,11 +1097,7 @@ class Trainer:
                     arm_now = watchdog is not None and not compile_pending
                     if arm_now:
                         watchdog.arm()
-                    fetch_ctx = (
-                        profiling.annotate("graftscope/input_fetch")
-                        if profiling_active
-                        else contextlib.nullcontext()
-                    )
+                    fetch_ctx = capture.fetch() if capture else contextlib.nullcontext()
                     try:
                         with fetch_ctx:
                             batch_idx, (x, y) = next(batch_iter)
@@ -1114,37 +1105,21 @@ class Trainer:
                         if arm_now:
                             watchdog.disarm()
                         # A window still open at epoch end closes HERE so
-                        # the capture never swallows eval/checkpointing.
-                        stop_profile(metrics)
+                        # the capture never swallows eval/checkpointing;
+                        # the last step's loss fences its device work.
+                        if capture:
+                            capture.close(None if metrics is None else metrics["loss"])
                         break
-                    # Range check (not ==): a resume that lands inside the
-                    # window still traces its remainder; landing past it
-                    # skips cleanly; profile_num_steps=0 never starts.
-                    if (
-                        cfg.profile_dir
-                        and not profiling_active
-                        and cfg.profile_start_step
-                        <= steps_done
-                        < cfg.profile_start_step + cfg.profile_num_steps
-                    ):
-                        jax.profiler.start_trace(cfg.profile_dir)
-                        profiling_active = True
-                    step_ctx = (
-                        profiling.step_annotation("train", steps_done)
-                        if profiling_active
-                        else contextlib.nullcontext()
-                    )
+                    if capture:
+                        capture.open_if_due(steps_done, self.train_step, state, x, y, base_key)
+                    step_ctx = capture.step(steps_done) if capture else contextlib.nullcontext()
                     with step_ctx:
                         state, metrics = self.train_step(state, x, y, base_key)
                     # jit's first call traced+compiled synchronously above,
                     # so every later iteration runs under the watchdog.
                     compile_pending = False
-                    if (
-                        profiling_active
-                        and steps_done + 1
-                        >= cfg.profile_start_step + cfg.profile_num_steps
-                    ):
-                        stop_profile(metrics)
+                    if capture:
+                        capture.close_if_done(steps_done, metrics["loss"])
                     # Fetch the loss value only while timing or logging needs
                     # it — otherwise leave dispatch fully async so the host
                     # stages batch N+1 while the device runs batch N.
@@ -1332,7 +1307,8 @@ class Trainer:
             flight.dump("exception", error=repr(e), step=steps_done)
             raise
         finally:
-            stop_profile(None)  # exception path: close without a fence
+            if capture:
+                capture.close()  # exception path: close without a fence
             flight.uninstall()
             if watchdog is not None:
                 watchdog.close()

@@ -1042,9 +1042,10 @@ class LMTrainer:
             # Equal token counts per shard make pmean of local means the
             # exact global mean.
             if accum == 1:
-                (local_loss, (aux, drop, ent)), grads = jax.value_and_grad(
-                    diff_loss, has_aux=True
-                )(params, tokens, targets, drop_base)
+                with jax.named_scope("graftscope/fwd_bwd"):
+                    (local_loss, (aux, drop, ent)), grads = jax.value_and_grad(
+                        diff_loss, has_aux=True
+                    )(params, tokens, targets, drop_base)
             else:
                 # Gradient accumulation: scan over microbatches so only
                 # one microbatch's activations are live at a time; the
@@ -1055,9 +1056,10 @@ class LMTrainer:
 
                 def body(carry, mb):
                     g_sum, l_sum, a_sum, d_sum, e_sum = carry
-                    (l, (a, dr, en)), g = jax.value_and_grad(
-                        diff_loss, has_aux=True
-                    )(params, mb[0], mb[1], mb[2])
+                    with jax.named_scope("graftscope/fwd_bwd"):
+                        (l, (a, dr, en)), g = jax.value_and_grad(
+                            diff_loss, has_aux=True
+                        )(params, mb[0], mb[1], mb[2])
                     return (
                         jax.tree.map(jnp.add, g_sum, g),
                         l_sum + l,
@@ -1163,21 +1165,22 @@ class LMTrainer:
             if compress:
                 opt_state = (opt_state, ef)
             metrics = {"loss": loss}
-            if zero1_opt is None:
-                # Telemetry norms, on device at the trees' native
-                # sharding: spec-aware psums give the GLOBAL norms
-                # (tensor/expert-sharded leaves summed over their axes,
-                # replicated leaves counted once). zero1/fsdp omit them —
-                # the synced gradient tree never materializes there.
-                metrics["grad_norm"] = tree_l2_norm(grads, param_specs)
-                metrics["param_norm"] = tree_l2_norm(params, param_specs)
-            if moe_on:
-                # MoE observability (VERDICT r3 #6): the load-balancing
-                # aux term, the capacity-overflow drop rate, and the
-                # expert-load entropy, averaged over replicas like the loss.
-                metrics["moe_aux"] = mean_over_replicas(aux)
-                metrics["moe_drop"] = mean_over_replicas(drop)
-                metrics["moe_load_entropy"] = mean_over_replicas(ent)
+            with jax.named_scope("graftscope/telemetry"):
+                if zero1_opt is None:
+                    # Telemetry norms, on device at the trees' native
+                    # sharding: spec-aware psums give the GLOBAL norms
+                    # (tensor/expert-sharded leaves summed over their axes,
+                    # replicated leaves counted once). zero1/fsdp omit them —
+                    # the synced gradient tree never materializes there.
+                    metrics["grad_norm"] = tree_l2_norm(grads, param_specs)
+                    metrics["param_norm"] = tree_l2_norm(params, param_specs)
+                if moe_on:
+                    # MoE observability (VERDICT r3 #6): the load-balancing
+                    # aux term, the capacity-overflow drop rate, and the
+                    # expert-load entropy, averaged over replicas like the loss.
+                    metrics["moe_aux"] = mean_over_replicas(aux)
+                    metrics["moe_drop"] = mean_over_replicas(drop)
+                    metrics["moe_load_entropy"] = mean_over_replicas(ent)
             return params, opt_state, metrics
 
         metric_specs = {"loss": P()}
@@ -1463,15 +1466,15 @@ class LMTrainer:
             from cs744_pytorch_distributed_tutorial_tpu.utils.failure import (
                 NonFiniteLossError,
             )
-        profiling_active = False
+        # fit() fetches every loss, so the traced steps' device work is
+        # already fenced when the capture closes.
+        capture = None
+        if cfg.profile_dir:
+            from cs744_pytorch_distributed_tutorial_tpu.utils import profiling
 
-        def stop_profile() -> None:
-            nonlocal profiling_active
-            if profiling_active:
-                # fit() fetches every loss, so the traced steps' device
-                # work is already fenced when we get here.
-                jax.profiler.stop_trace()
-                profiling_active = False
+            capture = profiling.StepCapture(
+                cfg.profile_dir, cfg.profile_start_step, cfg.profile_num_steps, "lm"
+            )
 
         # Divergence-safe checkpointing (the CIFAR engine's ordering,
         # train/engine.py): the loss fetched at step k is the forward
@@ -1488,32 +1491,22 @@ class LMTrainer:
         try:
             for step in range(start_step, steps):
                 lo = (step * b) % max(n - b + 1, 1)
-                fetch_ctx = (
-                    jax.profiler.TraceAnnotation("graftscope/input_fetch")
-                    if profiling_active
-                    else contextlib.nullcontext()
-                )
+                fetch_ctx = capture.fetch() if capture else contextlib.nullcontext()
                 with fetch_ctx:
                     x, y = self.shard_batch(tokens[lo : lo + b])
-                if (
-                    cfg.profile_dir
-                    and not profiling_active
-                    and cfg.profile_start_step
-                    <= step
-                    < cfg.profile_start_step + cfg.profile_num_steps
-                ):
-                    jax.profiler.start_trace(cfg.profile_dir)
-                    profiling_active = True
+                if capture:
+                    # The step index only by its type: lowering needs no
+                    # value, and a device scalar a step would cost a put.
+                    capture.open_if_due(
+                        step, self.jitted_train_step, params, opt_state, x, y,
+                        jax.ShapeDtypeStruct((), jnp.int32),
+                    )
                 # First executed step blocks on XLA compilation — exempt
                 # it from the watchdog (same policy as the CIFAR engine).
                 arm_now = watchdog is not None and step > start_step
                 if arm_now:
                     watchdog.arm()
-                step_ctx = (
-                    jax.profiler.StepTraceAnnotation("lm", step_num=step)
-                    if profiling_active
-                    else contextlib.nullcontext()
-                )
+                step_ctx = capture.step(step) if capture else contextlib.nullcontext()
                 try:
                     with step_ctx:
                         params, opt_state, m = self.train_step(
@@ -1539,11 +1532,8 @@ class LMTrainer:
                     if outlier is not None:
                         telemetry.emit_event("straggler", **outlier)
                 prev_mono = now_mono
-                if (
-                    profiling_active
-                    and step + 1 >= cfg.profile_start_step + cfg.profile_num_steps
-                ):
-                    stop_profile()
+                if capture:
+                    capture.close_if_done(step)
                 if cfg.halt_on_nonfinite and not math.isfinite(loss):
                     telemetry.emit_event(
                         "non_finite_loss", step=step, loss=loss
@@ -1630,7 +1620,8 @@ class LMTrainer:
             flight.dump("exception", error=repr(e), step=step)
             raise
         finally:
-            stop_profile()  # exception path: close any open capture
+            if capture:
+                capture.close()  # exception path: close any open capture
             flight.uninstall()
             if watchdog is not None:
                 watchdog.close()

@@ -16,13 +16,26 @@ import dataclasses
 import glob
 import gzip
 import json
+import logging
 import os
+import re
 import shutil
 import tempfile
 import time
 from typing import Any, Callable, Iterator
 
 import jax
+
+log = logging.getLogger(__name__)
+
+
+def _start_quiet(log_dir: str) -> None:
+    """Start the profiler with the Python tracer off: the ``annotate``
+    spans and the device lines are what a capture is read for, and a
+    record per Python call slows the host loop under measurement."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=options)
 
 
 @contextlib.contextmanager
@@ -36,13 +49,9 @@ def trace(log_dir: str) -> Iterator[None]:
             jax.block_until_ready(state.params)
 
     View with TensorBoard's profile plugin or ui.perfetto.dev. Python
-    frames are left out of the capture: the ``annotate`` spans and the
-    device lines are what it is read for, and a record per Python call
-    slows the host loop under measurement.
+    frames are left out of the capture (``_start_quiet``).
     """
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    jax.profiler.start_trace(log_dir, profiler_options=options)
+    _start_quiet(log_dir)
     try:
         yield
     finally:
@@ -67,6 +76,230 @@ def annotate(name: str, **fields):
 def step_annotation(name: str, step: int):
     """Step marker used by TensorBoard's per-step analysis."""
     return jax.profiler.StepTraceAnnotation(name, step_num=step)
+
+
+# ---- the train step's phases ------------------------------------------
+#
+# The trace names each device op by its instruction in the compiled
+# module and carries no ``op_name``, so the step's ``graftscope/*``
+# named scopes never reach it. The compiled module's text does carry
+# them (``metadata={op_name=...}``): one map from instruction name to
+# phase, built from the step's own executable, joins the two.
+
+PHASES = ("augment", "sync", "optimizer", "telemetry", "bwd", "fwd", "unscoped")
+_COLLECTIVE = re.compile(
+    r"^(all-reduce|all-gather|reduce-scatter|collective-permute|collective-broadcast"
+    r"|all-to-all|ragged-all-to-all)(-start|-done)?$"
+)
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_OPCODE = re.compile(r"\s*([a-z][a-z0-9\-]*)\(")
+_OP_NAME = re.compile(r'metadata=\{[^{}]*?op_name="((?:[^"\\]|\\.)*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_TO_APPLY = re.compile(r"\bto_apply=%?([\w.\-]+)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_ARRAY = re.compile(r"\b([a-z]+\d*(?:e\d+m\d+\w*)?)\[([\d,]*)\]")
+_STEP_PHASES: dict[str, dict[str, str]] = {}
+
+
+def phase_of(op_name: str, opcode: str = "") -> str:
+    """The phase of one instruction: the first rule that matches its
+    ``op_name`` (or, for ``sync``, its opcode) in this order."""
+    if "graftscope/input_augment" in op_name:
+        return "augment"
+    if "graftscope/sync" in op_name or _COLLECTIVE.match(opcode):
+        return "sync"
+    if "graftscope/optimizer" in op_name:
+        return "optimizer"
+    if "graftscope/telemetry" in op_name:
+        return "telemetry"
+    if "graftscope/fwd_bwd" in op_name:
+        # Autodiff names the backward pass ``transpose(...)``: the
+        # cotangent of every forward op, a custom_vjp's bwd rule (the
+        # flash kernels' dq and dk/dv) and rematerialised forward ops.
+        return "bwd" if "transpose(" in op_name else "fwd"
+    return "unscoped"
+
+
+def _shape_and_opcode(rest: str) -> tuple[str, str]:
+    """An instruction's result shape and opcode from what follows its
+    ``=``: the shape (a tuple's nested parentheses skipped), then
+    ``opcode(``."""
+    i = 0
+    if rest.startswith("("):
+        depth = 0
+        for i, c in enumerate(rest):
+            depth += (c == "(") - (c == ")")
+            if depth == 0:
+                break
+        i += 1
+    else:
+        i = max(rest.find(" "), 0)
+    m = _OPCODE.match(rest, i)
+    return rest[:i], (m.group(1) if m else "")
+
+
+def _bytes(shape: str) -> int:
+    """Bytes of the arrays in a result shape (``f32[3,8]{1,0}``: 96)."""
+    total = 0
+    for dtype, dims in _ARRAY.findall(shape):
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        bits = re.search(r"\d+", dtype)
+        total += n * max(int(bits.group()) // 8 if bits else 1, 1)
+    return total
+
+
+def phase_map(hlo_text: str) -> tuple[str, dict[str, str]]:
+    """(module name, {instruction name: phase}) of a compiled module's
+    text (``Compiled.as_text()``): every instruction of every
+    computation that runs as ops of its own (the entry, while bodies and
+    conditions, branches, async computations); the bodies of fusions and
+    of reductions' ``to_apply`` run inside their caller and are left
+    out. An async wrapper (``async-start``) takes its wrapped root's
+    opcode.
+
+    A fusion holds work of several scopes, and XLA records one of them
+    on it. It takes the phase of what dominates its time: its largest
+    matmul (``convolution``, ``dot``), where it holds one (the weight
+    gradient into which XLA fuses the optimizer's update and the
+    telemetry norm); else its root's ``op_name`` as XLA records it, and
+    where the root is a tuple (a multi-output fusion: the norm fused into
+    the update) its largest output's; ties go to the earlier rule."""
+    module = ""
+    # computation -> {instruction: (opcode, op_name, called, bytes, tuple operands)}
+    comps: dict[str, dict[str, tuple[str, str, str, int, list[str]]]] = {}
+    roots: dict[str, str] = {}
+    inlined: set[str] = set()
+    current = None
+    for line in hlo_text.splitlines():
+        if line.startswith("HloModule "):
+            module = line.split()[1].rstrip(",")
+            continue
+        m = _INSTRUCTION.match(line)
+        if m and current is not None:
+            name, rest = m.groups()
+            shape, opcode = _shape_and_opcode(rest)
+            op = _OP_NAME.search(rest)
+            calls = _CALLS.search(rest)
+            called = calls.group(1) if calls else ""
+            operands = _OPERAND.findall(rest.split("(", 1)[1]) if opcode == "tuple" else []
+            comps[current][name] = (opcode, op.group(1) if op else "", called, _bytes(shape), operands)
+            if line.lstrip().startswith("ROOT "):
+                roots[current] = name
+            if opcode == "fusion" and called:
+                inlined.add(called)
+            inlined.update(_TO_APPLY.findall(rest))
+            continue
+        m = _COMPUTATION.match(line)
+        if m:
+            current = m.group(1)
+            comps[current] = {}
+        elif line.strip() == "}":
+            current = None
+
+    def dominant(records) -> tuple[str, str] | None:
+        """(opcode, op_name) of the largest record that has an op_name."""
+        named = [r for r in records if r[1]]
+        if not named:
+            return None
+        top = max(r[3] for r in named)
+        best = min((r for r in named if r[3] == top), key=lambda r: PHASES.index(phase_of(r[1], r[0])))
+        return best[0], best[1]
+
+    phases: dict[str, str] = {}
+    for comp, instructions in comps.items():
+        if comp in inlined:
+            continue
+        for name, (opcode, op_name, called, _, _) in instructions.items():
+            body = comps.get(called, {})
+            root = body.get(roots.get(called, ""), ("", "", "", 0, []))
+            if opcode.startswith("async-") and root[0]:
+                opcode = root[0]
+            if opcode == "fusion":
+                matmuls = [r for r in body.values() if r[0] in ("convolution", "dot")]
+                members = [body[n] for n in root[4] if n in body]
+                pick = dominant(matmuls) or (dominant(members) if root[0] == "tuple" else None)
+                op_name = pick[1] if pick else (op_name or root[1])
+            phases[name] = phase_of(op_name or root[1], opcode)
+    return module, phases
+
+
+def record_step_phases(step_fn: Callable, *args: Any) -> str:
+    """Compile the jitted ``step_fn`` for ``args`` ahead of time (for a
+    step the loop has run, JAX's in-memory cache answers: no backend
+    compile), keep its phase map under its module's name for
+    ``step_phases()``, and return that name. Lowering reads the
+    arguments' types and shardings only: nothing runs, nothing is
+    donated."""
+    t0 = time.perf_counter()
+    module, phases = phase_map(step_fn.lower(*args).compile().as_text())
+    _STEP_PHASES[module] = phases
+    log.info(
+        "step phase map of %s: %d instructions in %.2f s",
+        module, len(phases), time.perf_counter() - t0,
+    )
+    return module
+
+
+def step_phases() -> dict[str, dict[str, str]]:
+    """{module name: {instruction name: phase}} of every step a fit
+    loop's capture has mapped in this process (``StepCapture``)."""
+    return _STEP_PHASES
+
+
+class StepCapture:
+    """The profiler capture of a fit loop: steps ``[start, start + num)``
+    traced to ``log_dir``, each step inside a step span named ``span``
+    and each batch fetch inside ``graftscope/input_fetch``.
+
+    Before the capture first opens, the step's phase map is built from
+    its compiled module (outside the capture) and written beside it as
+    ``step_phases.json`` ({module: {instruction: phase}}), so the same
+    trace can be split into forward, backward, optimizer, ... A loop
+    with no ``profile_dir`` makes none of this: no map, no compile.
+    """
+
+    def __init__(self, log_dir: str, start: int, num: int, span: str):
+        self.log_dir, self.start, self.end, self.span = log_dir, start, start + num, span
+        self.active = False
+        self._mapped = False
+
+    def open_if_due(self, step: int, step_fn: Callable, *args: Any) -> None:
+        """Open the capture if ``step`` lies in its range (a resume that
+        lands inside it traces the rest; ``num=0`` never opens).
+        ``step_fn`` and ``args`` are the step the loop is about to run."""
+        if self.active or not self.start <= step < self.end:
+            return
+        if not self._mapped:
+            module = record_step_phases(step_fn, *args)
+            os.makedirs(self.log_dir, exist_ok=True)
+            with open(os.path.join(self.log_dir, "step_phases.json"), "w") as f:
+                json.dump({module: _STEP_PHASES[module]}, f)
+            self._mapped = True
+        _start_quiet(self.log_dir)
+        self.active = True
+
+    def fetch(self):
+        return annotate("graftscope/input_fetch") if self.active else contextlib.nullcontext()
+
+    def step(self, step: int):
+        return step_annotation(self.span, step) if self.active else contextlib.nullcontext()
+
+    def close_if_done(self, step: int, fence: Any = None) -> None:
+        if self.active and step + 1 >= self.end:
+            self.close(fence)
+
+    def close(self, fence: Any = None) -> None:
+        """Close an open capture; ``fence`` (an output of the last traced
+        step) is waited for first, so the trace holds its device work."""
+        if not self.active:
+            return
+        if fence is not None:
+            jax.block_until_ready(fence)
+        jax.profiler.stop_trace()
+        self.active = False
 
 
 @dataclasses.dataclass
