@@ -20,8 +20,9 @@ padding. This engine serves at REQUEST granularity instead:
   retired slot's pages recycle immediately (``pool.PagePool``);
 - prefill is its own jitted program per prompt-length bucket: a dense
   causal pass over the padded prompt, the first sampled token, and a
-  scatter of the prompt's KV rows into the slot's pages — all one
-  program, so the hand-off to the decode pool is a device-side commit.
+  scatter of the prompt's KV into the slot's pages, one index a whole
+  page — all one program, so the hand-off to the decode pool is a
+  device-side commit.
   Because it is a separate program from decode, running it on separate
   mesh slices (prefill/decode disaggregation) is a deployment choice,
   not a code change. An admission never waits for the device: it costs
@@ -501,6 +502,8 @@ class ServingEngine:
         self._max_admits_in_step = 0
         self._pages_grown = 0  # pages the grow loop allocated
         self._prefill_chunks = 0  # chunk programs run (prefill_chunk set)
+        # pages the one-shot commits wrote, trash pages included
+        self._commit_pages = 0
         self._decode_puts = 0  # host-to-device puts made in decode_prep
         self._pool_audits = 0  # whole-pool audits run (``_audit_pools``)
         # What the decode steps' models sowed (``_step_counters``), summed
@@ -732,12 +735,12 @@ class ServingEngine:
     def _prefill_fn(self, bucket: int):
         """Jitted prefill+commit for one prompt-length bucket: dense
         causal pass over the padded prompt, sample the first token from
-        the true last position, scatter the prompt's KV rows into the
-        slot's pages. One trace per bucket (buckets are powers of two —
-        a bounded set); prompt, true length, page row and the sampling
-        stream's two integers arrive in one traced vector
-        (``_pack_program_arg``), so every prompt in the bucket reuses
-        the executable."""
+        the true last position, scatter the prompt's KV into the slot's
+        pages a whole page an index (``commit``). One trace per bucket
+        (buckets are powers of two — a bounded set); prompt, true
+        length, page row and the sampling stream's two integers arrive
+        in one traced vector (``_pack_program_arg``), so every prompt in
+        the bucket reuses the executable."""
         cached = self._prefill_cache.get(bucket)
         if cached is not None:
             return cached
@@ -746,40 +749,52 @@ class ServingEngine:
         page_size = cfg.page_size
         scanned = self._scanned
 
-        def commit(pages, cache, page_row, true_len):
-            idx = jnp.arange(bucket)
-            # Rows past the true prompt land on the trash page: junk KV
-            # written where no live slot ever gathers.
-            pidx = jnp.where(idx < true_len, page_row[idx // page_size], 0)
-            off = idx % page_size
+        # The padded bucket in whole pages: a prompt shorter than a page
+        # still writes one.
+        n_pages = -(-bucket // page_size)
 
-            def put(p, c):
+        def commit(pages, cache, page_row, true_len):
+            # One index per PAGE, not per row (XLA's scatter pays per
+            # index): the prompt's pages go to the slot's, every page
+            # past it to the trash page 0. Rows at or past the true
+            # length take the pool's fresh value, so a page holds what a
+            # row-wise commit would have left on a fresh pool.
+            j = jnp.arange(n_pages)
+            pidx = jnp.where(j * page_size < true_len, page_row[j], 0)
+            live = jnp.arange(n_pages * page_size)[:, None] < true_len
+
+            def put(pname, p, c):
                 # The cache's rows are [bucket, Hkv, D] (scales
                 # [bucket, Hkv]); the pools fold the heads into the last
                 # dimension, so each row reshapes to the pool's own.
+                fresh = 1 if "scale" in pname else 0
+                lanes = p.shape[-1]
                 if scanned:
                     # scan_layers stacks both collections with a leading
                     # [num_layers] axis (one "blocks" subtree). Layers
-                    # and pages merge into one axis of rows (free: the
-                    # tiled dimensions are the last two), so one scatter
-                    # commits every layer at layer * num_pages + page;
-                    # a batched ``p.at[:, pidx, off]`` makes the TPU
-                    # compiler re-lay the whole stack out for it.
+                    # and pages merge into one axis (free: the tiled
+                    # dimensions are the last two), so one scatter
+                    # commits every layer at layer * num_pages + page.
                     layers, num_pages = p.shape[:2]
                     flat = p.reshape(layers * num_pages, *p.shape[2:])
                     lidx = jnp.arange(layers)[:, None] * num_pages + pidx
-                    rows = c[:, 0, :bucket].reshape(
-                        layers, bucket, p.shape[-1]
+                    rows = c[:, 0, :bucket].reshape(layers, -1, lanes)
+                else:
+                    flat, lidx = p, pidx
+                    rows = c[0, :bucket].reshape(-1, lanes)
+                pad = n_pages * page_size - rows.shape[-2]
+                if pad:
+                    rows = jnp.pad(
+                        rows, ((0, 0),) * (rows.ndim - 2) + ((0, pad), (0, 0))
                     )
-                    return flat.at[lidx, off].set(rows).reshape(p.shape)
-                return p.at[pidx, off].set(
-                    c[0, :bucket].reshape(bucket, p.shape[-1])
-                )
+                rows = jnp.where(live, rows, jnp.asarray(fresh, rows.dtype))
+                rows = rows.reshape(*lidx.shape, page_size, lanes)
+                return flat.at[lidx].set(rows).reshape(p.shape)
 
             def walk(p, c):
                 if any(k in p for k in _CACHE_TO_PAGES.values()):
                     return {
-                        pname: put(p[pname], c[cname])
+                        pname: put(pname, p[pname], c[cname])
                         for cname, pname in _CACHE_TO_PAGES.items()
                         if pname in p
                     }
@@ -1219,16 +1234,18 @@ class ServingEngine:
                 window_pages: list[int] = []
                 window_first = 0
                 row = np.asarray(pages, np.int32)
-                # Rows of the padded prompt past its pages scatter to
-                # trash (a chunked prompt's padding first fills its last
-                # page's tail, which decode overwrites).
-                self._trash_rows += (
-                    max(0, n_chunks * chunk - need * self.cfg.page_size)
-                    if chunk else bucket - plen
-                )
+                # Rows of the padded prompt past its pages go to trash
+                # (the padding first fills the last page's tail, which
+                # decode overwrites); the one-shot commit writes whole
+                # pages, the bucket's ``n_pages`` of them.
+                size = self.cfg.page_size
                 if chunk:
+                    self._trash_rows += max(0, n_chunks * chunk - need * size)
                     prefill = self._chunk_fn()
                 else:
+                    n_pages = -(-bucket // size)
+                    self._trash_rows += (n_pages - need) * size
+                    self._commit_pages += n_pages
                     prefill = self._prefill_fn(bucket)
                     packed = self._pack_program_arg(
                         bucket, req.prompt, (plen, req.req_id, tok_idx), row
@@ -1831,6 +1848,9 @@ class ServingEngine:
             "max_admits_in_step": self._max_admits_in_step,
             "pages_grown": self._pages_grown,
             "prefill_chunks": self._prefill_chunks,
+            # commit_pages / admissions: pages a one-shot commit writes,
+            # ceil(bucket / page_size) (0 with prefill_chunk set)
+            "commit_pages": self._commit_pages,
             # decode_puts / decode_steps: host-to-device puts a decode
             # step (one packed vector); whole-pool audits run (none on
             # the path of a step: ``_audit_pools``)

@@ -40,6 +40,11 @@ import jax
 import jax.numpy as jnp
 
 _COPY = re.compile(r"= \w+\[([\d,]+)\]\{([\d,]*)[^}]*\} copy\(")
+# a scatter's result dims and its updates operand
+_SCATTER = re.compile(
+    r"= \w+\[([\d,]+)\]\{[^}]*\} scatter\(%[\w.-]+, %[\w.-]+, "
+    r"%([\w.-]+)\)"
+)
 
 
 @dataclass
@@ -55,12 +60,20 @@ class PoolLayout:
     pool_bytes: int
     # major_to_minor of each data pool as the program takes it
     entry_layouts: list[tuple[int, ...]]
+    # (dims, updates' dims) of every ``scatter`` into a data pool, or a
+    # scanned stack of them: the updates' leading dims count the indices,
+    # the rest are the window each index writes (a row or a page)
+    pool_writes: list[tuple[tuple[int, ...], tuple[int, ...]]]
 
     @property
     def row_major(self) -> bool:
         return all(
             lay == tuple(range(len(lay))) for lay in self.entry_layouts
         )
+
+
+def _dims(text: str) -> tuple[int, ...]:
+    return tuple(int(d) for d in text.split(",") if d)
 
 
 def _data_pools(tree: Any) -> list[Any]:
@@ -118,12 +131,20 @@ def audit(compiled: Any, engine: Any) -> PoolLayout:
     sizes = {math.prod(layer_shape(p)) for p in pools} | {
         math.prod(p.shape) for p in pools
     }
+    text = compiled.as_text()
     copies = []
-    for dims, minor_to_major in _COPY.findall(compiled.as_text()):
-        shape = tuple(int(d) for d in dims.split(","))
+    for dims, minor_to_major in _COPY.findall(text):
+        shape = _dims(dims)
         if math.prod(shape) in sizes:
-            order = tuple(int(d) for d in minor_to_major.split(",") if d)
-            copies.append((shape, order))
+            copies.append((shape, _dims(minor_to_major)))
+    writes = []
+    for dims, updates in _SCATTER.findall(text):
+        shape = _dims(dims)
+        if math.prod(shape) in sizes:
+            found = re.search(
+                rf"%{re.escape(updates)} = \w+\[([\d,]*)\]", text
+            )
+            writes.append((shape, _dims(found.group(1))))
     formats = _data_pools(compiled.input_formats[0][1])
     return PoolLayout(
         pool_copies=copies,
@@ -132,4 +153,5 @@ def audit(compiled: Any, engine: Any) -> PoolLayout:
             math.prod(layer_shape(p)) * p.dtype.itemsize for p in pools
         ),
         entry_layouts=[tuple(f.layout.major_to_minor) for f in formats],
+        pool_writes=writes,
     )

@@ -143,13 +143,18 @@ def _commit_cache_to_pages(pages, cache, page_tables, true_len):
                 if pname not in p:
                     continue
                 pool = np.asarray(p[pname]).copy()
-                rows = np.asarray(c[cname])
-                page_size = pool.shape[1]
+                # a scanned stack leads both with [L]; the batch first
+                lead = pool.shape[:-3]
+                rows = np.moveaxis(np.asarray(c[cname]), len(lead), 0)
+                page_size = pool.shape[-2]
                 for b in range(rows.shape[0]):
                     for i in range(true_len):
                         # pools fold the heads: [Hkv, D] -> [Hkv*D]
-                        pool[page_tables[b, i // page_size], i % page_size] = (
-                            rows[b, i].reshape(pool.shape[2:])
+                        pool[
+                            ..., page_tables[b, i // page_size],
+                            i % page_size, :,
+                        ] = np.take(rows[b], i, axis=len(lead)).reshape(
+                            lead + pool.shape[-1:]
                         )
                 out[pname] = jnp.asarray(pool)
             return out
@@ -212,16 +217,25 @@ def test_paged_decode_logits_bitwise_match_dense(tiny_lm, quant_kv):
         )
 
 
+@pytest.mark.parametrize(
+    "page_size,plen,bucket",
+    [(4, 6, 8), (4, 8, 8), (4, 8, 16), (16, 5, 8)],
+    ids=["mid-page", "plen-is-bucket", "page-boundary", "bucket-under-page"],
+)
 @pytest.mark.parametrize("scan", [False, True], ids=["unrolled", "scan"])
 @pytest.mark.parametrize("quant_kv", [False, True], ids=["float", "int8"])
 def test_engine_commit_then_decode_bitwise_matches_dense(
-    tiny_lm, quant_kv, scan
+    tiny_lm, quant_kv, scan, page_size, plen, bucket
 ):
     """The engine's OWN device-side commit (its prefill program's
-    scatter of the dense cache rows into folded pools, one flattened
-    scatter for every layer under ``scan_layers``) and then a decode
-    step over those pools equal the dense cache path to the bit, on the
-    gather path: first token, committed rows, next-step logits."""
+    scatter of the dense cache into folded pools a whole page an index,
+    one flattened scatter for every layer under ``scan_layers``) and
+    then a decode step over those pools equal the dense cache path to
+    the bit, on the gather path: first token, committed pages, next-step
+    logits. The slot's pages hold what a row-wise commit leaves on a
+    fresh pool (rows past the prompt at the pool's fresh value), and no
+    other page but the trash page is written, not even a page of the
+    slot's row past the prompt's."""
     from cs744_pytorch_distributed_tutorial_tpu.models import (
         stack_block_params,
     )
@@ -230,20 +244,22 @@ def test_engine_commit_then_decode_bitwise_matches_dense(
     dense = model.clone(quant_kv_cache=quant_kv, scan_layers=scan)
     if scan:
         params = stack_block_params(params)
+    num_pages = 17
     eng = ServingEngine(
         dense, params,
-        ServeConfig(num_slots=2, page_size=4, num_pages=17,
+        ServeConfig(num_slots=2, page_size=page_size, num_pages=num_pages,
                     max_pages_per_slot=8, paged_attention_impl="gather"),
     )
-    plen, bucket = 6, 8
     prompt = np.zeros((1, bucket), np.int32)
     prompt[0, :plen] = np.random.default_rng(5).integers(1, VOCAB, plen)
+    need = -(-plen // page_size)
     row = np.zeros((8,), np.int32)
-    row[:2] = [9, 3]  # the slot's pages, out of pool order
-    empty = jax.tree.map(np.asarray, eng._pages)
+    row[:4] = [9, 3, 12, 5]  # out of pool order; the slot owns row[:need]
+    fresh = jax.tree.map(np.asarray, eng._pages)
+    sentinel = 7
     pages, tok = eng._prefill_fn(bucket)(
-        params, eng._pages,
-        eng._pack_program_arg(bucket, prompt[0, :plen], (plen, 0, 0), [9, 3]),
+        params, jax.tree.map(lambda x: jnp.full_like(x, sentinel), eng._pages),
+        eng._pack_program_arg(bucket, prompt[0, :plen], (plen, 0, 0), row),
         eng._sample_root,
     )
 
@@ -256,14 +272,21 @@ def test_engine_commit_then_decode_bitwise_matches_dense(
     )(params, jnp.asarray(prompt))
     cache = variables["cache"]
     assert int(tok) == int(jnp.argmax(logits[0, plen - 1]))
-    if not scan:  # the host-side walk knows the unrolled tree only
-        want = _commit_cache_to_pages(empty, cache, row[None], plen)
-        for got_leaf, want_leaf in zip(
-            jax.tree.leaves(pages), jax.tree.leaves(want)
-        ):
-            np.testing.assert_array_equal(
-                np.asarray(got_leaf)[row[:2]], np.asarray(want_leaf)[row[:2]]
-            )
+    want = _commit_cache_to_pages(fresh, cache, row[None], plen)
+    owned = list(row[:need])
+    others = sorted(set(range(1, num_pages)) - set(owned))
+    leaves, want_leaves = jax.tree.leaves(pages), jax.tree.leaves(want)
+    # K and V (and their scales) a layer, or a scanned stack's
+    assert len(leaves) == len(want_leaves) == (
+        (4 if quant_kv else 2) * (1 if scan else 2)
+    )
+    for got_leaf, want_leaf in zip(leaves, want_leaves):
+        got_leaf, want_leaf = np.asarray(got_leaf), np.asarray(want_leaf)
+        if scan:  # [L, num_pages, ...] -> [num_pages, L, ...]
+            got_leaf = np.moveaxis(got_leaf, 0, 1)
+            want_leaf = np.moveaxis(want_leaf, 0, 1)
+        np.testing.assert_array_equal(got_leaf[owned], want_leaf[owned])
+        assert (got_leaf[others] == sentinel).all()
 
     step = jnp.asarray([[int(tok)]], jnp.int32)
     dense_logits, _ = dense.apply(

@@ -489,6 +489,29 @@ def test_pool_counts_churn(tiny_lm):
     assert stats["trash_rows_written"] == eng._trash_rows > 0
 
 
+@pytest.mark.parametrize("chunk", [None, 8], ids=["one-shot", "chunked"])
+def test_commit_counts_trash_and_pages(tiny_lm, chunk):
+    """The commit's counters on known buckets. One slot, so every decode
+    step is full and writes no trash row: ``trash_rows_written`` is the
+    prompts' alone. A one-shot commit writes ceil(bucket / page_size)
+    whole pages (``commit_pages``), the slot's and the rest to trash:
+    prompts 3, 9, 6 at pages of 4 are buckets 8, 16, 8, so 2 + 4 + 2
+    pages, 1 + 3 + 2 of them the slot's, 4 * (1 + 1 + 0) trash rows. A
+    chunked prompt writes rows, and only its padding past its pages is
+    trash: chunks of 8 write 8, 16, 8 rows into 4, 12, 8 rows of pages,
+    and no commit page."""
+    model, params = tiny_lm
+    cfg = ServeConfig(num_slots=1, page_size=4, num_pages=17,
+                      max_pages_per_slot=8, prefill_chunk=chunk)
+    eng = ServingEngine(model, params, cfg)
+    _submit(eng, [(3, 4), (9, 3), (6, 5)], data_seed=19)
+    eng.run()
+    stats = eng.stats()
+    assert stats["admissions"] == 3 and stats["preemptions"] == 0
+    assert stats["trash_rows_written"] == 8
+    assert stats["commit_pages"] == (0 if chunk else 8)
+
+
 def test_metrics_summary_renders_serve_window_rows(tmp_path, capsys):
     """summarize() aggregates serve_window records and serve_phase rows
     next to the existing serve rows, and main() renders them."""
