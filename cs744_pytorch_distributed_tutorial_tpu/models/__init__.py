@@ -36,6 +36,7 @@ from cs744_pytorch_distributed_tutorial_tpu.models.vgg import (
     vgg19,
 )
 from cs744_pytorch_distributed_tutorial_tpu.models.hf_interop import (
+    deepseek_v2_model_config,
     gpt2_model_config,
     keye_model_config,
     llama_model_config,
@@ -134,6 +135,7 @@ __all__ = [
     "resnet34",
     "resnet50",
     "tiny_cnn",
+    "deepseek_v2_model_config",
     "gpt2_model_config",
     "keye_model_config",
     "longcat_flash_model_config",

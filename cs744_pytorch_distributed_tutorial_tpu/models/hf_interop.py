@@ -459,11 +459,151 @@ def longcat_flash_model_config(
     )
 
 
+def _deepseek_yarn(rope_scaling: Mapping[str, Any] | None):
+    """DeepSeek-V2's ``rope_scaling`` (keys ``type``, ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``mscale``, ``mscale_all_dim``) as (``RopeScaling`` whose
+    ``attention_factor`` is the factor on cos and sin, the factor on the
+    whole score): with ``m(a) = 0.1 a ln(factor) + 1`` (1 where factor
+    <= 1), cos and sin carry ``m(mscale) / m(mscale_all_dim)`` and the
+    score ``m(mscale_all_dim)^2`` (1 where ``mscale_all_dim`` is 0 or
+    absent), as the published ``DeepseekV2YarnRotaryEmbedding`` and
+    ``softmax_scale`` have it. (None, 1.0) without scaling."""
+    if not rope_scaling:
+        return None, 1.0
+    kind = rope_scaling.get("type", rope_scaling.get("rope_type"))
+    if kind != "yarn":
+        raise ValueError(
+            f"rope_scaling of type {kind!r} is not supported: the latent "
+            "layer knows the plain rotation and DeepSeek's YaRN"
+        )
+    import math
+
+    from cs744_pytorch_distributed_tutorial_tpu.models.transformer import (
+        RopeScaling,
+    )
+
+    factor = float(rope_scaling["factor"])
+
+    def m(a: float) -> float:
+        return 0.1 * a * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    mscale = float(rope_scaling.get("mscale", 1))
+    all_dim = float(rope_scaling.get("mscale_all_dim", 0))
+    return RopeScaling(
+        factor=factor,
+        original_max_position=int(rope_scaling["original_max_position_embeddings"]),
+        beta_fast=float(rope_scaling.get("beta_fast", 32)),
+        beta_slow=float(rope_scaling.get("beta_slow", 1)),
+        attention_factor=m(mscale) / m(all_dim),
+    ), (m(all_dim) ** 2 if all_dim else 1.0)
+
+
+def deepseek_v2_model_config(
+    hf_config: Mapping[str, Any], max_seq_len: int | None = None,
+    held_experts: Sequence[int] | None = None,
+) -> dict:
+    """``TransformerLM`` kwargs for a ``deepseek_v2`` ``config.json``
+    (deepseek-ai/DeepSeek-V2; its ``modeling_deepseek.py`` reads the same
+    keys): every layer the plain pre-norm block over latent attention
+    (MLA: ``q_lora_rank``, ``kv_lora_rank``, heads of ``qk_nope_head_dim +
+    qk_rope_head_dim`` / ``v_head_dim``, interleaved RoPE on the rope
+    dimensions, DeepSeek's YaRN from ``rope_scaling``); the FFN a dense
+    SwiGLU of ``intermediate_size`` on the first ``first_k_dense_replace``
+    layers, on the others the MoE: a softmax router over
+    ``n_routed_experts``, ``num_experts_per_tok`` chosen by
+    ``topk_method`` (``greedy``, or ``group_limited_greedy`` within the
+    ``topk_group`` best of ``n_group`` groups), weighted by the scores
+    times ``routed_scaling_factor`` (``norm_topk_prob: false``) or
+    renormalised (true, unscaled, as the published code does), plus
+    ``n_shared_experts`` shared experts of ``moe_intermediate_size``
+    every token goes through. Read from the published keys alone.
+
+    ``held_experts`` is one chip's share of the routed experts (the
+    ``model-configs`` guide's section 4), as ``longcat_flash_model_config``
+    takes it; None holds them all. ``max_seq_len`` defaults to
+    ``max_position_embeddings``."""
+    if hf_config.get("attention_bias"):
+        raise ValueError("attention_bias is not supported")
+    if hf_config.get("hidden_act", "silu") != "silu":
+        raise ValueError("hidden_act other than silu is not supported")
+    if hf_config.get("tie_word_embeddings"):
+        raise ValueError("tied embeddings are not supported: the head is untied")
+    if hf_config.get("scoring_func", "softmax") != "softmax":
+        raise ValueError(
+            f"scoring_func {hf_config['scoring_func']!r} is not supported: "
+            "the router is a softmax"
+        )
+    method = hf_config.get("topk_method", "greedy")
+    if method not in ("greedy", "group_limited_greedy"):
+        raise ValueError(
+            f"topk_method {method!r} is not supported: 'greedy' and "
+            "'group_limited_greedy' are built"
+        )
+    if hf_config.get("moe_layer_freq", 1) != 1:
+        raise ValueError(
+            "moe_layer_freq other than 1 is not supported: every layer past "
+            "first_k_dense_replace is routed"
+        )
+    if not hf_config.get("q_lora_rank"):
+        raise ValueError(
+            "q_lora_rank null (a full-rank query) is not supported: the "
+            "latent layer's query goes through the low-rank path"
+        )
+    from cs744_pytorch_distributed_tutorial_tpu.models.latent import LatentDims
+
+    scaling, score_factor = _deepseek_yarn(hf_config.get("rope_scaling"))
+    renormalize = bool(hf_config.get("norm_topk_prob", False))
+    grouped = method == "group_limited_greedy"
+    return dict(
+        vocab_size=hf_config["vocab_size"],
+        num_layers=hf_config["num_hidden_layers"],
+        num_heads=hf_config["num_attention_heads"],
+        d_model=hf_config["hidden_size"],
+        d_ff=hf_config["moe_intermediate_size"],
+        dense_d_ff=hf_config["intermediate_size"],
+        max_seq_len=max_seq_len or hf_config["max_position_embeddings"],
+        use_rope=True,
+        rope_base=float(hf_config["rope_theta"]),
+        tie_embeddings=False,
+        norm="rmsnorm",
+        norm_eps=hf_config["rms_norm_eps"],
+        mlp="swiglu",
+        latent=LatentDims(
+            q_lora_rank=hf_config["q_lora_rank"],
+            kv_lora_rank=hf_config["kv_lora_rank"],
+            qk_nope_head_dim=hf_config["qk_nope_head_dim"],
+            qk_rope_head_dim=hf_config["qk_rope_head_dim"],
+            v_head_dim=hf_config["v_head_dim"],
+            rope_scaling=scaling,
+            score_factor=score_factor,
+        ),
+        latent_block="plain",
+        dense_layers=int(hf_config.get("first_k_dense_replace", 0)),
+        num_experts=hf_config["n_routed_experts"],
+        moe_top_k=hf_config["num_experts_per_tok"],
+        moe_dispatch="dropless",
+        moe_bias=False,
+        moe_held_experts=None if held_experts is None else tuple(held_experts),
+        moe_renormalize=renormalize,
+        moe_routed_scale=(
+            1.0 if renormalize else float(hf_config.get("routed_scaling_factor", 1.0))
+        ),
+        moe_n_group=int(hf_config["n_group"]) if grouped else 1,
+        moe_topk_group=int(hf_config["topk_group"]) if grouped else 1,
+        moe_shared_d_ff=int(hf_config.get("n_shared_experts") or 0)
+        * hf_config["moe_intermediate_size"],
+        attn_bias=False,
+        attention_impl="dense",
+    )
+
+
 # ``model_type`` of a published config.json -> the builder of its kwargs
 CONFIG_BUILDERS = {
     "KeyeVL2": keye_model_config,
     "mellum": mellum_model_config,
     "longcat_flash": longcat_flash_model_config,
+    "deepseek_v2": deepseek_v2_model_config,
 }
 
 
@@ -473,7 +613,8 @@ def model_config_from_hf(
     """``TransformerLM`` kwargs from a published ``config.json``, by its
     ``model_type``. ``how_deployed`` goes to the builder: what a
     deployment decides and no published file says (``held_experts`` of
-    ``longcat_flash``); a builder that has no such argument raises."""
+    ``longcat_flash`` and ``deepseek_v2``); a builder that has no such
+    argument raises."""
     kind = hf_config.get("model_type")
     if kind not in CONFIG_BUILDERS:
         raise ValueError(

@@ -1,4 +1,5 @@
-"""Latent attention (MLA) and the two-attention shortcut-MoE layer.
+"""Latent attention (MLA) and the two layers built on it: the
+two-attention shortcut-MoE layer and the plain pre-norm block.
 
 Imported where such a layer is built (``TransformerLM`` with ``latent``
 set), so a model without one pays nothing for it.
@@ -30,14 +31,25 @@ kept DE-INTERLEAVED (even dimensions first, then odd: ``apply_rope``'s
 permuted alike, so every dot product is the interleaved one's, and the
 de-interleaved ``k_rope`` is what the pool stores.
 
-**The layer** (``ShortcutMoEBlock``): two (attention, dense MLP) pairs
-and one MoE whose input leaves the stream inside the first pair and
-whose output joins it after the second::
+**YaRN on the rope dimensions** (DeepSeek-V2's form, ``LatentDims.
+rope_scaling`` / ``score_factor``): the frequencies are
+``rope_inv_freq``'s, cos and sin carry ``attention_factor`` (``m(s,
+mscale) / m(s, mscale_all_dim)``, ``m(s, a) = 0.1 a ln s + 1``), and the
+WHOLE score, no-rope and rope dimensions together, carries
+``score_factor`` (``m(s, mscale_all_dim)^2``): a factor on cos and sin
+alone would scale the rope dimensions' part of a score only.
+
+**The shortcut-MoE layer** (``ShortcutMoEBlock``): two (attention, dense
+MLP) pairs and one MoE whose input leaves the stream inside the first
+pair and whose output joins it after the second::
 
     x1 = x  + A_0(N_a0 x);   m = N_p0 x1;   s = MoE(m)
     x2 = x1 + MLP_0(m)
     x3 = x2 + A_1(N_a1 x2)
     out = x3 + MLP_1(N_p1 x3) + s
+
+**The plain block** (``LatentBlock``): ``x + A(N_a x)``, then ``x +
+FFN(N_f x)``, the FFN a dense SwiGLU MLP or the MoE; one pool a layer.
 """
 
 from __future__ import annotations
@@ -63,6 +75,11 @@ class LatentDims(NamedTuple):
     v_head_dim: int
     scale_q: float = 1.0  # on q (both parts), after the query norm
     scale_kv: float = 1.0  # on the normalised latent
+    # YaRN on the rope dimensions (models/transformer.py::RopeScaling;
+    # its attention_factor on cos and sin), and a factor on the whole
+    # score (module docstring). None and 1: the plain rotation and scale.
+    rope_scaling: Any = None
+    score_factor: float = 1.0
 
     @property
     def row(self) -> int:
@@ -75,12 +92,13 @@ class LatentDims(NamedTuple):
         return -(-self.row // 128) * 128
 
 
-def rope_interleaved(x, positions, base: float):
+def rope_interleaved(x, positions, base: float, scaling=None):
     """RoPE on pairs ``(2j, 2j+1)`` of ``x [B, T, H, D]``; returns the
-    rotated vector de-interleaved (module docstring)."""
+    rotated vector de-interleaved (module docstring). ``scaling``:
+    ``apply_rope``'s."""
     return apply_rope(
         jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1),
-        positions, base,
+        positions, base, scaling,
     )
 
 
@@ -180,11 +198,13 @@ class LatentAttention(nn.Module):
                 )
             positions = jnp.asarray(decode_pos)[:, None] + jnp.arange(t)
         q_nope = q[..., :dn]
-        q_rope = rope_interleaved(q[..., dn:], positions, self.rope_base)
+        q_rope = rope_interleaved(
+            q[..., dn:], positions, self.rope_base, dm.rope_scaling
+        )
         k_rope = rope_interleaved(
-            ckv[..., None, r:], positions, self.rope_base
+            ckv[..., None, r:], positions, self.rope_base, dm.rope_scaling
         )[:, :, 0]
-        scale = float(dn + dr) ** -0.5
+        scale = dm.score_factor * float(dn + dr) ** -0.5
         out_proj = dense(d_model, name="attn_out")
 
         if mode == "train":
@@ -362,3 +382,48 @@ class ShortcutMoEBlock(nn.Module):
         x = x + mlp(0, m)
         x = x + attention(1, norm(name="ln_a1")(x))
         return x + mlp(1, norm(name="ln_p1")(x)) + shortcut
+
+
+class LatentBlock(nn.Module):
+    """The plain pre-norm block over latent attention (module
+    docstring): its FFN is a dense SwiGLU MLP of ``dense_d_ff`` where
+    ``moe`` is None, else the MoE those keywords build."""
+
+    num_heads: int
+    dims: LatentDims
+    dense_d_ff: int | None = None
+    moe: tuple | None = None  # MoEFFN's keywords, as (name, value) pairs
+    dtype: Any = jnp.float32
+    rope_base: float = 10000.0
+    norm_eps: float = 1e-6
+    page_size: int | None = None
+    num_pages: int | None = None
+    paged_attention_impl: str = "gather"
+    flash_interpret: bool | None = None
+
+    @nn.compact
+    def __call__(
+        self, x, deterministic: bool = True, *, mode="train",
+        decode_pos=None, page_table=None,
+    ):
+        del deterministic  # no dropout in this layer
+        norm = partial(nn.RMSNorm, epsilon=self.norm_eps, dtype=self.dtype)
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        x = x + LatentAttention(
+            num_heads=self.num_heads, dims=self.dims, dtype=self.dtype,
+            rope_base=self.rope_base, norm_eps=self.norm_eps,
+            page_size=self.page_size, num_pages=self.num_pages,
+            paged_attention_impl=self.paged_attention_impl,
+            flash_interpret=self.flash_interpret, name="attn",
+        )(
+            norm(name="ln_attn")(x), mode=mode, decode_pos=decode_pos,
+            page_table=page_table,
+        )
+        h = norm(name="ln_ffn")(x)
+        if self.moe is not None:
+            from cs744_pytorch_distributed_tutorial_tpu.models.moe import MoEFFN
+
+            return x + MoEFFN(**dict(self.moe), dtype=self.dtype, name="moe")(h)
+        gate = dense(self.dense_d_ff, name="mlp_gate")(h)
+        up = dense(self.dense_d_ff, name="mlp_in")(h)
+        return x + dense(x.shape[-1], name="mlp_out")(nn.silu(gate) * up)

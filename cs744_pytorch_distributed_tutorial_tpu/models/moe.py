@@ -143,6 +143,18 @@ class MoEFFN(nn.Module):
     # router output): experts are the top-k of ``scores + bias``, their
     # weights are ``scores`` without it.
     choice_bias: bool = False
+    # Group-limited choice (DeepSeek-V2's ``group_limited_greedy``): the
+    # routed experts form ``n_group`` contiguous groups, a group scores
+    # its best expert's score, a token keeps its ``topk_group`` best
+    # groups and takes its ``top_k`` experts among theirs; the weights
+    # are the experts' own scores. 1 = one group, plain top-k.
+    n_group: int = 1
+    topk_group: int = 1
+    # Shared experts: a SwiGLU of this width (``n_shared_experts`` times
+    # the experts' width, as one) that every token goes through, its
+    # output added to the routed sum; every share computes it, where the
+    # token lives. 0 = none. Dropless gated path only.
+    shared_d_ff: int = 0
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -204,6 +216,31 @@ class MoEFFN(nn.Module):
                 "gated experts run on dispatch_impl='dropless' only, got "
                 f"{self.dispatch_impl!r}"
             )
+        if self.shared_d_ff and not (dropless and self.gated):
+            raise ValueError(
+                "shared experts are a SwiGLU beside gated experts on "
+                f"dispatch_impl='dropless' (got {self.dispatch_impl!r}, "
+                f"gated={self.gated})"
+            )
+        if self.n_group != 1 or self.topk_group != 1:
+            per = self.num_experts // max(self.n_group, 1)
+            if (
+                self.n_group < 1 or self.num_experts % self.n_group
+                or not 1 <= self.topk_group <= self.n_group
+                or k > self.topk_group * per
+            ):
+                raise ValueError(
+                    f"group-limited choice needs n_group ({self.n_group}) "
+                    f"to divide the {self.num_experts} routed experts and "
+                    f"1 <= topk_group ({self.topk_group}) <= n_group, with "
+                    f"top_k ({k}) no more than the kept groups' experts"
+                )
+            if self.zero_experts or self.choice_bias:
+                raise ValueError(
+                    "group-limited choice is built over routed experts "
+                    "alone, by their scores: no zero-compute experts and "
+                    "no choice_bias"
+                )
         if e % (self.expert_axis_size if ep else 1):
             raise ValueError(
                 f"num_experts {e} not divisible by expert axis "
@@ -247,7 +284,21 @@ class MoEFFN(nn.Module):
             name="router",
         )(tokens.astype(jnp.float32))
         gates = jax.nn.softmax(logits, axis=-1)  # [G, N, E]
-        if self.choice_bias:
+        kept_groups = None
+        if self.n_group > 1:
+            # a group's score is its best expert's; the experts of the
+            # groups a token does not keep score 0 and are not chosen
+            per = e // self.n_group
+            _, top_groups = lax.top_k(
+                gates.reshape(g, n, self.n_group, per).max(-1), self.topk_group
+            )
+            kept_groups = jnp.sum(
+                jax.nn.one_hot(top_groups, self.n_group, dtype=jnp.int32), -2
+            ) > 0  # [G, N, n_group]
+            topk_gate, topk_idx = lax.top_k(
+                jnp.where(jnp.repeat(kept_groups, per, axis=-1), gates, 0.0), k
+            )
+        elif self.choice_bias:
             bias = self.param(
                 "choice_bias", nn.initializers.zeros_init(), (e,), jnp.float32
             )
@@ -400,10 +451,13 @@ class MoEFFN(nn.Module):
                 return out
 
             if shared_out:
-                return self._shared_out(
+                y = self._shared_out(
                     tokens.reshape(n_total, d), topk_idx.reshape(n_total, k),
                     topk_gate.reshape(n_total, k), expert_ffn,
+                    None if kept_groups is None
+                    else kept_groups.reshape(n_total, self.n_group),
                 ).reshape(b, t, d)
+                return self._with_shared_experts(y, x)
             expert_flat = topk_idx.reshape(p_tot)
             order = jnp.argsort(expert_flat, stable=True)
             sorted_e = expert_flat[order]
@@ -424,7 +478,9 @@ class MoEFFN(nn.Module):
                 .at[tok_ids]
                 .add(out * gate_flat[:, None])
             )
-            return y.reshape(b, t, d).astype(self.dtype)
+            return self._with_shared_experts(
+                y.reshape(b, t, d).astype(self.dtype), x
+            )
 
         # ---- capacity-slot assignment (static shapes, per group) --------
         # Priority: rank-0 choices of every token beat rank-1 choices
@@ -521,13 +577,28 @@ class MoEFFN(nn.Module):
         return y.reshape(b, t, d)
 
 
-    def _shared_out(self, x, idx, gate, expert_ffn):
+    def _with_shared_experts(self, y, x):
+        """``y`` plus the shared experts' SwiGLU of ``x`` (``shared_d_ff``
+        wide); ``y`` itself where there are none."""
+        if not self.shared_d_ff:
+            return y
+        dense = lambda f, name: nn.Dense(
+            f, use_bias=False, dtype=self.dtype, name=name
+        )
+        h = nn.silu(dense(self.shared_d_ff, "shared_gate")(x)) * dense(
+            self.shared_d_ff, "shared_in"
+        )(x)
+        return y + dense(x.shape[-1], "shared_out")(h).astype(y.dtype)
+
+    def _shared_out(self, x, idx, gate, expert_ffn, kept_groups=None):
         """The dropless layer of a chip that holds a SHARE of the routed
         experts, and of a router with zero-compute experts: ``x [N, D]``,
         each token's ``idx`` / ``gate`` ``[N, K]`` over the router's whole
         width -> ``[N, D]``: the held experts' terms for the tokens that
         chose them, plus ``w * x`` for every zero-compute expert chosen;
         the terms of routed experts held elsewhere are left out.
+        ``kept_groups [N, n_group]`` (group-limited choice) is what the
+        counter of tokens whose kept groups hold a held expert reads.
 
         Pairs sort by where their expert is, the held ones first (by
         local index), and the grouped matmuls visit only the row tiles
@@ -562,6 +633,15 @@ class MoEFFN(nn.Module):
                 "serve_stats", "absent_expert_pairs",
                 (~is_held & ~is_zero).sum(-1),
             )
+            if kept_groups is not None:
+                # tokens whose kept groups include one that holds a held
+                # expert (3 of 8 groups kept: 3/8 under an even router)
+                held_group = np.zeros((self.n_group,), bool)
+                held_group[[i // (routed // self.n_group) for i in held]] = True
+                self.sow(
+                    "serve_stats", "held_group_tokens",
+                    jnp.any(kept_groups & held_group, axis=-1).astype(jnp.int32),
+                )
         p_tot = n * k
         flat = jnp.where(is_held, local, len(held)).reshape(p_tot)
         order = jnp.argsort(flat, stable=True)

@@ -1322,18 +1322,27 @@ class TransformerLM(nn.Module):
     # them, every layer. Served, a sublayer keeps ONE pool of latents
     # (``latent_pages``) where another layer keeps keys and values.
     # None = the model as it always was, and models/latent.py is never
-    # imported.
+    # imported. ``latent_block="plain"`` builds the plain pre-norm block
+    # (``LatentBlock``) instead: one latent attention and one FFN a
+    # layer, one pool a layer; the FFN of the first ``dense_layers``
+    # layers is a dense SwiGLU MLP of ``dense_d_ff``, the others' the MoE.
     latent: Any = None
     dense_d_ff: int | None = None
+    latent_block: str = "shortcut"
+    dense_layers: int = 0
     # MoEFFN's share and router options (models/moe.py): the ids of the
     # routed experts held here (None = all), zero-compute experts behind
     # the routed ones, top-k weights renormalised or not and their
-    # scale, a bias on the choice alone.
+    # scale, a bias on the choice alone, group-limited choice, shared
+    # experts' width.
     moe_held_experts: tuple | None = None
     moe_zero_experts: int = 0
     moe_renormalize: bool = True
     moe_routed_scale: float = 1.0
     moe_choice_bias: bool = False
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_shared_d_ff: int = 0
 
     def window_layers(self) -> int:
         """How many layers are sliding-window layers."""
@@ -1373,7 +1382,7 @@ class TransformerLM(nn.Module):
                 "do not shard the pool; it runs on one device",
             )
         if self.scan_layers:
-            no("scan_layers", "the two-attention layer is built unrolled")
+            no("scan_layers", "the latent layers are built unrolled")
         if self.layer_types is not None or self.window is not None:
             no("a window (layer_types)", "the latent walk has no window")
         if self.indexer_heads:
@@ -1381,14 +1390,34 @@ class TransformerLM(nn.Module):
         if self.expert_axis is not None:
             no(
                 "expert_axis",
-                "the shortcut MoE is dropless; a chip's share is "
+                "the latent layers' MoE is dropless; a chip's share is "
                 "moe_held_experts, without the exchange",
             )
-        if self.num_experts < 1 or self.dense_d_ff is None:
+        if self.latent_block not in ("shortcut", "plain"):
             no(
-                "no experts or no dense_d_ff",
+                f"latent_block={self.latent_block!r}",
+                "the layers built are 'shortcut' (ShortcutMoEBlock) and "
+                "'plain' (LatentBlock)",
+            )
+        if self.latent_block == "shortcut" and (
+            self.num_experts < 1 or self.dense_d_ff is None or self.dense_layers
+        ):
+            no(
+                "no experts, no dense_d_ff or dense_layers",
                 "the layer is two dense MLPs (dense_d_ff) and one MoE "
-                "(num_experts of d_ff)",
+                "(num_experts of d_ff), every layer",
+            )
+        if self.latent_block == "plain" and not (
+            0 <= self.dense_layers <= self.num_layers
+            and (self.dense_layers == 0 or self.dense_d_ff is not None)
+            and (self.dense_layers == self.num_layers or self.num_experts >= 1)
+        ):
+            no(
+                f"dense_layers={self.dense_layers} of {self.num_layers}, "
+                f"dense_d_ff={self.dense_d_ff}, num_experts={self.num_experts}",
+                "the plain block's FFN is a dense MLP of dense_d_ff on the "
+                "first dense_layers layers and the MoE (num_experts of d_ff) "
+                "on the others",
             )
         if not self.use_rope or self.tie_embeddings or self.norm != "rmsnorm":
             no(
@@ -1420,12 +1449,15 @@ class TransformerLM(nn.Module):
         elif (
             self.moe_held_experts is not None or self.moe_zero_experts
             or not self.moe_renormalize or self.moe_routed_scale != 1.0
-            or self.moe_choice_bias
+            or self.moe_choice_bias or self.moe_n_group != 1
+            or self.moe_topk_group != 1 or self.moe_shared_d_ff
         ):
             raise ValueError(
                 "moe_held_experts, moe_zero_experts, moe_renormalize, "
-                "moe_routed_scale and moe_choice_bias are built in the "
-                "shortcut-MoE layer (latent set); Block's MoE takes none"
+                "moe_routed_scale, moe_choice_bias, moe_n_group, "
+                "moe_topk_group and moe_shared_d_ff are built in the "
+                "shortcut-MoE layer and the plain latent block (latent "
+                "set); Block's MoE takes none"
             )
         if self.layer_types is not None:
             kinds = set(self.layer_types)
@@ -1538,29 +1570,31 @@ class TransformerLM(nn.Module):
         )
         if self.latent is not None:
             from cs744_pytorch_distributed_tutorial_tpu.models.latent import (
+                LatentBlock,
                 ShortcutMoEBlock,
             )
 
+            moe = (
+                ("num_experts", self.num_experts),
+                ("d_ff", self.d_ff),
+                ("top_k", self.moe_top_k),
+                ("dispatch_impl", "dropless"),
+                ("gmm_impl", self.moe_gmm_impl),
+                ("gmm_interpret", self.flash_interpret),
+                ("gated", True),
+                ("use_bias", False),
+                ("held_experts", self.moe_held_experts),
+                ("zero_experts", self.moe_zero_experts),
+                ("renormalize", self.moe_renormalize),
+                ("routed_scale", self.moe_routed_scale),
+                ("choice_bias", self.moe_choice_bias),
+            )
+            plain = self.latent_block == "plain"
             for i in range(self.num_layers):
-                x = ShortcutMoEBlock(
+                layer_kw = dict(
                     num_heads=self.num_heads,
                     dims=self.latent,
                     dense_d_ff=self.dense_d_ff,
-                    moe=(
-                        ("num_experts", self.num_experts),
-                        ("d_ff", self.d_ff),
-                        ("top_k", self.moe_top_k),
-                        ("dispatch_impl", "dropless"),
-                        ("gmm_impl", self.moe_gmm_impl),
-                        ("gmm_interpret", self.flash_interpret),
-                        ("gated", True),
-                        ("use_bias", False),
-                        ("held_experts", self.moe_held_experts),
-                        ("zero_experts", self.moe_zero_experts),
-                        ("renormalize", self.moe_renormalize),
-                        ("routed_scale", self.moe_routed_scale),
-                        ("choice_bias", self.moe_choice_bias),
-                    ),
                     dtype=self.dtype,
                     rope_base=self.rope_base,
                     norm_eps=self.norm_eps,
@@ -1569,17 +1603,29 @@ class TransformerLM(nn.Module):
                     paged_attention_impl=self.paged_attention_impl,
                     flash_interpret=self.flash_interpret,
                     name=f"block_{i}",
-                )(
+                )
+                if not plain:
+                    layer = ShortcutMoEBlock(moe=moe, **layer_kw)
+                elif i < self.dense_layers:
+                    layer = LatentBlock(**layer_kw)
+                else:
+                    layer = LatentBlock(moe=moe + (
+                        ("n_group", self.moe_n_group),
+                        ("topk_group", self.moe_topk_group),
+                        ("shared_d_ff", self.moe_shared_d_ff),
+                    ), **layer_kw)
+                x = layer(
                     x, deterministic, mode=mode, decode_pos=decode_pos,
                     page_table=page_table,
                 )
             if mode == "paged_decode" and not self.is_initializing():
-                # Latent rows each slot's step attended, over the two
-                # sublayers of every layer (the engine's counters; a
+                # Latent rows each slot's step attended, over the
+                # attention sublayers of every layer (two a shortcut-MoE
+                # layer, one a plain block; the engine's counters; a
                 # no-op unless "serve_stats" is asked for).
                 self.sow(
                     "serve_stats", "latent_tokens_read",
-                    2 * self.num_layers * (decode_pos + 1),
+                    (1 if plain else 2) * self.num_layers * (decode_pos + 1),
                 )
         elif self.scan_layers:
             if self.num_experts > 0:

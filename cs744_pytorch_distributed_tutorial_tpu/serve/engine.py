@@ -131,6 +131,7 @@ _LATENT_COUNTERS = ("latent_tokens_read",)
 _SHARE_COUNTERS = (
     "held_expert_pairs", "zero_expert_pairs", "absent_expert_pairs",
 )
+_GROUP_COUNTERS = ("held_group_tokens",)
 
 
 def _step_counters(stats: Any, active: jnp.ndarray, num_experts: int):
@@ -144,8 +145,9 @@ def _step_counters(stats: Any, active: jnp.ndarray, num_experts: int):
     attended on its full and on its window layers; from a model with
     latent attention, the latent rows attended; from an expert layer
     that holds a share or has zero-compute experts, its (token, expert)
-    pairs by where the expert is. (None, ()) where the model sowed
-    nothing."""
+    pairs by where the expert is; from a share under group-limited
+    choice, the tokens whose kept groups hold a held expert. (None, ())
+    where the model sowed nothing."""
     selected = _named_leaves(stats, "selected_tokens")
     scored = _named_leaves(stats, "scored_tokens")
     routed = _named_leaves(stats, "expert_idx")
@@ -184,6 +186,9 @@ def _step_counters(stats: Any, active: jnp.ndarray, num_experts: int):
             over_active(_named_leaves(stats, name)) for name in _SHARE_COUNTERS
         ]
         names += _SHARE_COUNTERS
+    if _named_leaves(stats, _GROUP_COUNTERS[0]):
+        counters.append(over_active(_named_leaves(stats, _GROUP_COUNTERS[0])))
+        names += _GROUP_COUNTERS
     return jnp.stack(counters).astype(jnp.int32), names
 
 
@@ -513,7 +518,7 @@ class ServingEngine:
         self._counter_names: tuple[str, ...] = ()  # set when the step traces
         self._counts = dict.fromkeys(
             _ROUTING_COUNTERS + _WINDOW_COUNTERS + _LATENT_COUNTERS
-            + _SHARE_COUNTERS, 0,
+            + _SHARE_COUNTERS + _GROUP_COUNTERS, 0,
         )
         if cfg.prefill_chunk is not None and cfg.prefill_chunk < 1:
             raise ValueError(
@@ -1886,6 +1891,9 @@ class ServingEngine:
             "held_expert_pairs": self._counts["held_expert_pairs"],
             "zero_expert_pairs": self._counts["zero_expert_pairs"],
             "absent_expert_pairs": self._counts["absent_expert_pairs"],
+            # a share under group-limited choice: (token, layer) pairs
+            # whose kept groups hold a held expert. 0 otherwise
+            "held_group_tokens": self._counts["held_group_tokens"],
         }
 
 

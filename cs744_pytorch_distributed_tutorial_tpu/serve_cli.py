@@ -66,14 +66,18 @@ def build_parser() -> argparse.ArgumentParser:
                         "longcat_flash = latent attention (MLA) over one "
                         "paged latent pool a sublayer, the two-attention "
                         "shortcut-MoE layer, zero-compute experts, a chip's "
-                        "share of the routed ones) in "
+                        "share of the routed ones; deepseek_v2 = latent "
+                        "attention with YaRN, one pool a layer, a leading "
+                        "dense layer, group-limited routing and shared "
+                        "experts) in "
                         "place of the size flags above; --max-seq-len still "
                         "caps a request. Random params, as ever; no "
                         "--parity-check (the dense-cache generator has no "
                         "indexer, no window and no latent); window and "
                         "latent layers need --prefill-chunk")
     p.add_argument("--held-experts", type=int, default=None, metavar="N",
-                   help="with --model-config longcat_flash: serve one "
+                   help="with --model-config longcat_flash or "
+                        "deepseek_v2: serve one "
                         "chip's share of the experts, routed experts "
                         "0..N-1 of the config's n_routed_experts; the "
                         "router keeps its width, the other routed "
