@@ -145,13 +145,19 @@ class LatentAttention(nn.Module):
     """Latent attention in modes ``train`` (keys and values built a
     head), ``paged_prefill`` (a chunk written into the slot's latent
     pages, then attended, absorbed, over the slot's view) and
-    ``paged_decode`` (absorbed, over the latent pool:
-    ``paged_attention_impl`` "gather" or "kernel"). What is not built
+    ``paged_decode`` (absorbed, over the latent pool), each by
+    ``paged_attention_impl`` "gather" or "kernel". What is not built
     raises in ``TransformerLM``. The chunk runs absorbed as decode does:
-    building every view row's keys and values a head instead measured
-    5% faster over a quarter-full view, equal at a half and 23% slower
-    at a full one, equal over the chat cell's mix of prompts (PERF.md,
-    PR 34), so the one form serves both programs."""
+    building every view row's keys and values a head spends ~1.9x
+    fewer FLOPs at any head count but writes them all out, and measured
+    even over the chat cell's mix of prompts (PERF.md, section 6). Under
+    "kernel" the chunk is ONE Pallas call a layer
+    (``ops/paged_attention.py::paged_chunk_attention``, named
+    ``attn_latent_chunk``) that walks the slot's live pages straight out
+    of the pool with an online softmax: no float32 scores in HBM, no key
+    past the chunk's last real position. Under "gather" (the reference)
+    it attends over the gathered view, as wide as its last position
+    needs (four static widths, one program)."""
 
     num_heads: int
     dims: LatentDims
@@ -164,7 +170,13 @@ class LatentAttention(nn.Module):
     flash_interpret: bool | None = None
 
     @nn.compact
-    def __call__(self, x, *, mode="train", decode_pos=None, page_table=None):
+    def __call__(
+        self, x, *, mode="train", decode_pos=None, page_table=None,
+        last_idx=None,
+    ):
+        """``last_idx`` ([B], ``paged_prefill``): the index in the chunk
+        of each row's last real token; the tokens after it are padding.
+        None: every token is real."""
         if mode not in ("train", "paged_prefill", "paged_decode"):
             raise ValueError(
                 f"mode={mode!r} keeps a dense cache of keys and values a "
@@ -276,6 +288,26 @@ class LatentAttention(nn.Module):
             pool.value = pool.value.at[
                 rows_page, positions % self.page_size
             ].set(row)
+            if self.paged_attention_impl == "kernel":
+                from cs744_pytorch_distributed_tutorial_tpu.ops.paged_attention import (
+                    paged_chunk_attention,
+                )
+
+                # the slot's live pages walked out of the pool by the
+                # page table: one call a layer, named for the trace
+                length = (
+                    jnp.full((b,), t, jnp.int32) if last_idx is None
+                    else last_idx + 1
+                )
+                with jax.named_scope("attn_latent_chunk"):
+                    o_latent = paged_chunk_attention(
+                        absorbed_query(), pool.value, page_table, decode_pos,
+                        length, value_lanes=r, scale=scale,
+                        interpret=self.flash_interpret,
+                    )
+                return out_proj(
+                    values_of(o_latent).reshape(b, t, h * dv).astype(self.dtype)
+                )
             pages_cap = page_table.shape[1]
             widths = sorted(
                 {max(1, -(-pages_cap * i // 4)) for i in (1, 2, 3, 4)}
@@ -354,7 +386,7 @@ class ShortcutMoEBlock(nn.Module):
     @nn.compact
     def __call__(
         self, x, deterministic: bool = True, *, mode="train",
-        decode_pos=None, page_table=None,
+        decode_pos=None, page_table=None, last_idx=None,
     ):
         from cs744_pytorch_distributed_tutorial_tpu.models.moe import MoEFFN
 
@@ -369,7 +401,10 @@ class ShortcutMoEBlock(nn.Module):
                 page_size=self.page_size, num_pages=self.num_pages,
                 paged_attention_impl=self.paged_attention_impl,
                 flash_interpret=self.flash_interpret, name=f"attn_{i}",
-            )(h, mode=mode, decode_pos=decode_pos, page_table=page_table)
+            )(
+                h, mode=mode, decode_pos=decode_pos, page_table=page_table,
+                last_idx=last_idx,
+            )
 
         def mlp(i, h):
             gate = dense(self.dense_d_ff, name=f"mlp_{i}_gate")(h)
@@ -404,7 +439,7 @@ class LatentBlock(nn.Module):
     @nn.compact
     def __call__(
         self, x, deterministic: bool = True, *, mode="train",
-        decode_pos=None, page_table=None,
+        decode_pos=None, page_table=None, last_idx=None,
     ):
         del deterministic  # no dropout in this layer
         norm = partial(nn.RMSNorm, epsilon=self.norm_eps, dtype=self.dtype)
@@ -417,7 +452,7 @@ class LatentBlock(nn.Module):
             flash_interpret=self.flash_interpret, name="attn",
         )(
             norm(name="ln_attn")(x), mode=mode, decode_pos=decode_pos,
-            page_table=page_table,
+            page_table=page_table, last_idx=last_idx,
         )
         h = norm(name="ln_ffn")(x)
         if self.moe is not None:

@@ -1616,7 +1616,7 @@ class TransformerLM(nn.Module):
                     ), **layer_kw)
                 x = layer(
                     x, deterministic, mode=mode, decode_pos=decode_pos,
-                    page_table=page_table,
+                    page_table=page_table, last_idx=logits_at,
                 )
             if mode == "paged_decode" and not self.is_initializing():
                 # Latent rows each slot's step attended, over the

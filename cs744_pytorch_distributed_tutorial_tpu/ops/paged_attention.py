@@ -109,6 +109,18 @@ new is ONE pool serving as keys (the whole row) and values (its first
 ``value_lanes`` lanes): a block is copied once, and the accumulator
 ``[Hq, value_lanes]`` is written as it lies (no diagonal to pick).
 
+**The chunk walk** (``paged_chunk_attention``; a prefill chunk over a
+latent pool). The same walk with a query axis: the chunk's ``[C, H,
+lanes]`` absorbed queries flattened to ``C*H`` rows, a grid step a block
+of ~``_CHUNK_ROWS`` of them, row ``i`` at position ``offset + t0 + i //
+H`` (the mask by position takes a ``[rows, 1]`` column). A block walks
+key blocks of ``_CHUNK_BLOCK_TOKENS`` up to the page that holds its last
+real position, the next query block's first key block copied under
+this one's last; key blocks wholly before the block's first position
+skip the mask. The online softmax's state lives in VMEM scratch, so no
+``[heads, C, view]`` float32 scores ever reach HBM, and no key past the
+chunk's last real position is read.
+
 ``pages_per_slot`` statically narrows the page table to its first N
 columns (the walk's capacity, the other path's grid).
 
@@ -129,13 +141,25 @@ _NEG = -1e30
 _BLOCK_TOKENS = 256
 # Both buffers of K and of V together stay under this much VMEM.
 _VMEM_BUDGET = 8 * 1024 * 1024
+# The chunk walk (``paged_chunk_attention``): about this many query rows
+# (tokens x heads) a grid step and keys a block of its walk (on the v5e
+# at 128 heads over 16k keys: 15.4 ms a call where 1,024 x 256 takes
+# 18.3, at 80% of the compute bound; PERF.md, the chunk walk), and the
+# scoped VMEM its blocks ask for (their scores, probabilities and
+# accumulator in float32 are ~30 MB, over Mosaic's default 16).
+_CHUNK_ROWS = 2048
+_CHUNK_BLOCK_TOKENS = 512
+_CHUNK_VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def _pages_per_block(capacity: int, page_size: int, row_bytes: int) -> int:
-    """Pages a block of the walk holds: ``_BLOCK_TOKENS`` tokens, never
+def _pages_per_block(
+    capacity: int, page_size: int, row_bytes: int,
+    block_tokens: int = _BLOCK_TOKENS,
+) -> int:
+    """Pages a block of the walk holds: ``block_tokens`` tokens, never
     more than a slot can hold, halved until the double-buffered K and V
     blocks fit the budget."""
-    n = max(1, min(capacity, _BLOCK_TOKENS // page_size))
+    n = max(1, min(capacity, block_tokens // page_size))
     while n > 1 and 4 * n * page_size * row_bytes > _VMEM_BUDGET:
         n //= 2
     return n
@@ -148,13 +172,16 @@ def _attend(q, k, v, first, pos, scale, carry, oldest=None):
     rows starting at position ``first``. The accumulator is ``[Hq,
     Hkv*D]``: row ``h``'s own head sits in its diagonal block, the other
     lanes hold products with other heads' values and are never read.
+    ``pos`` is the query's position, or a ``[rows, 1]`` column of them
+    (the chunk walk); None where every row sees the whole block.
     ``oldest`` (a window layer) is the first position the query sees."""
     m_prev, l_prev, acc = carry
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # [Hq, tokens] f32
-    k_pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(k_pos <= pos, s, _NEG)
+    if pos is not None:
+        k_pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(k_pos <= pos, s, _NEG)
     if oldest is not None:
         s = jnp.where(k_pos >= oldest, s, _NEG)
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -605,3 +632,245 @@ def _latent_walk(
         interpret=interpret,
     )(pos.astype(jnp.int32), pt.astype(jnp.int32), q[:, 0], latent_pages)
     return out[:, None]
+
+
+def _chunk_kernel(
+    page_size: int,
+    block_pages: int,
+    capacity: int,
+    scale: float,
+    value_lanes: int,
+    heads: int,
+    q_tokens: int,
+    off_ref,
+    len_ref,
+    pt_ref,
+    q_ref,
+    pool_hbm,
+    o_ref,
+    buf,
+    sem,
+    m_ref,
+    l_ref,
+    acc_ref,
+    first_buf,
+):
+    """A block of ``q_tokens`` query tokens (x ``heads`` rows each) of a
+    slot's chunk walks the slot's latent pages from position 0 to the
+    page that holds its last real position (``paged_chunk_attention``)."""
+    rows, lanes = q_ref.shape[1:]
+    tokens = block_pages * page_size
+    slots = len_ref.shape[0]
+    b, qi = pl.program_id(0), pl.program_id(1)
+
+    def is_real(slot, blk):  # a query block holds a row of the chunk
+        return blk * q_tokens < len_ref[slot]
+
+    def live_pages(slot, blk):
+        last = off_ref[slot] + jnp.minimum(
+            (blk + 1) * q_tokens, len_ref[slot]
+        ) - 1
+        return jnp.minimum(last // page_size + 1, capacity)
+
+    def live_in_block(slot, blk, kb):
+        return jnp.clip(
+            live_pages(slot, blk) - kb * block_pages, 0, block_pages
+        )
+
+    def copy(slot, kb, bi, j):
+        page = pt_ref[slot, kb * block_pages + j]
+        return pltpu.make_async_copy(
+            pool_hbm.at[page], buf.at[bi, j], sem.at[bi]
+        )
+
+    def start(slot, blk, kb, bi):
+        n = live_in_block(slot, blk, kb)
+
+        @pl.loop(0, n)
+        def _copy(j):
+            copy(slot, kb, bi, j).start()
+
+        # Rows of pages not copied are masked by position; p = 0 times
+        # a stale NaN would still be NaN, so they go 0.
+        @pl.loop(n, block_pages)
+        def _zero(j):
+            buf[bi, j] = jnp.zeros(buf.shape[2:], buf.dtype)
+
+    def wait(slot, blk, kb, bi):
+        @pl.loop(0, live_in_block(slot, blk, kb))
+        def _wait(j):
+            copy(slot, kb, bi, j).wait()
+
+    @pl.when(jnp.logical_and(b == 0, qi == 0))
+    def _first_block():
+        first_buf[0] = 0
+
+        @pl.when(is_real(0, 0))
+        def _start():
+            start(0, 0, 0, 0)
+
+    # The next query block that walks: this slot's next, else the next
+    # slot's first; its first key block rides under this one's last.
+    same = jnp.logical_and(qi + 1 < pl.num_programs(1), is_real(b, qi + 1))
+    nb = jnp.where(same, b, jnp.minimum(b + 1, slots - 1))
+    nqi = jnp.where(same, qi + 1, 0)
+    next_walks = jnp.logical_or(
+        same, jnp.logical_and(b + 1 < slots, is_real(nb, 0))
+    )
+    real = is_real(b, qi)
+
+    @pl.when(real)
+    def _walk():
+        p0 = off_ref[b] + qi * q_tokens
+        last = off_ref[b] + len_ref[b] - 1
+        row_pos = p0 + jax.lax.div(
+            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), heads
+        )
+        # a padding row sees what the last real row sees: no key past
+        # it, so nothing stale enters its softmax before it goes 0
+        pos = jnp.minimum(row_pos, last)
+        num_blocks = (live_pages(b, qi) + block_pages - 1) // block_pages
+        # Key blocks wholly at or before the block's first position are
+        # seen by every row: no mask there, only in the last one or two.
+        n_full = jnp.minimum((p0 + 1) // tokens, num_blocks)
+        m_ref[...], l_ref[...], acc_ref[...] = _init_carry(rows, value_lanes)
+        base = first_buf[0]
+
+        def body(masked):
+            def step(kb, carry):
+                bi = (base + kb) % 2
+                more = kb + 1 < num_blocks
+
+                @pl.when(more)
+                def _next_block():
+                    start(b, qi, kb + 1, 1 - bi)
+
+                @pl.when(jnp.logical_and(~more, next_walks))
+                def _next_query_block():
+                    start(nb, nqi, 0, 1 - bi)
+
+                wait(b, qi, kb, bi)
+                k = buf[bi].reshape(tokens, lanes)
+                m_ref[...], l_ref[...], acc_ref[...] = _attend(
+                    q_ref[0], k, k[:, :value_lanes], kb * tokens,
+                    pos if masked else None, scale,
+                    (m_ref[...], l_ref[...], acc_ref[...]),
+                )
+                return carry
+
+            return step
+
+        jax.lax.fori_loop(0, n_full, body(False), 0)
+        jax.lax.fori_loop(n_full, num_blocks, body(True), 0)
+        first_buf[0] = (base + num_blocks) % 2
+        o_ref[0] = jnp.where(
+            row_pos <= last, acc_ref[...] / l_ref[...], 0.0
+        ).astype(o_ref.dtype)
+
+    @pl.when(~real)
+    def _padding():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+
+def paged_chunk_attention(
+    q: jax.Array,
+    latent_pages: jax.Array,
+    page_table: jax.Array,
+    offset: jax.Array,
+    length: jax.Array,
+    *,
+    value_lanes: int,
+    scale: float,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """A prefill chunk's causal attention over one latent pool, read
+    straight out of the pool (models/latent.py, ``paged_prefill``).
+
+    ``q [B, C, H, lanes]`` holds each head's absorbed query of the
+    chunk's tokens at positions ``offset[b] + t``; rows ``t >=
+    length[b]`` (``length >= 1``) are padding and come back 0.
+    ``latent_pages [num_pages, page_size, lanes]`` is the pool, already
+    holding the chunk's own rows, ``page_table [B, P]`` the slot's pages
+    in position order. Scores are ``q . row * scale`` over the whole row,
+    values the row's first ``value_lanes`` lanes, as ``paged_attention``
+    over a latent pool; returns ``[B, C, H, value_lanes]``.
+
+    The decode walk with a query axis: ``q`` flattened to ``[C*H,
+    lanes]`` rows, a grid step a block of about ``_CHUNK_ROWS`` of them
+    (``C*H / rows`` grid steps a slot), row ``i`` of a block at position
+    ``offset + t0 + i // H``. A block walks key blocks of
+    ``_CHUNK_BLOCK_TOKENS`` up to the page that holds its last real
+    position, copied a page at a time by the page table into
+    double-buffered VMEM while the block before is computed, the next
+    query block's first under this one's last; the mask by position
+    bites only in the last one or two. Pages past that page, the trash
+    page 0 among them, are never read. Online softmax as the decode
+    walk: scores, max and sum in float32, ``p`` in the pool's dtype for
+    ``p . V``, float32 accumulation."""
+    b, c, h, lanes = q.shape
+    if (
+        latent_pages.ndim != 3 or latent_pages.shape[-1] != lanes
+        or lanes % 128 or value_lanes > lanes
+    ):
+        raise ValueError(
+            "a latent pool is [num_pages, page_size, lanes] with lanes whole "
+            "128-lane tiles, q [B, C, H, lanes], value_lanes <= lanes; got "
+            f"pool {latent_pages.shape}, q {q.shape}, value_lanes "
+            f"{value_lanes}"
+        )
+    if interpret is None:
+        from cs744_pytorch_distributed_tutorial_tpu.ops._backend import (
+            default_interpret,
+        )
+
+        interpret = default_interpret()
+    page_size = latent_pages.shape[1]
+    capacity = page_table.shape[1]
+    q_tokens = min(c, max(1, _CHUNK_ROWS // h))
+    n_q = -(-c // q_tokens)
+    if n_q * q_tokens > c:
+        q = jnp.pad(q, ((0, 0), (0, n_q * q_tokens - c), (0, 0), (0, 0)))
+    rows = q_tokens * h
+    block_pages = _pages_per_block(
+        capacity, page_size, lanes * latent_pages.dtype.itemsize,
+        _CHUNK_BLOCK_TOKENS,
+    )
+    out = pl.pallas_call(
+        partial(
+            _chunk_kernel, page_size, block_pages, capacity, float(scale),
+            value_lanes, h, q_tokens,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, n_q),
+            in_specs=[
+                pl.BlockSpec((1, rows, lanes), lambda bi, qi, *_: (bi, qi, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, rows, value_lanes), lambda bi, qi, *_: (bi, qi, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM(
+                    (2, block_pages, page_size, lanes), latent_pages.dtype
+                ),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, value_lanes), jnp.float32),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (b, n_q * rows, value_lanes), latent_pages.dtype
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_CHUNK_VMEM_LIMIT
+        ),
+        interpret=interpret,
+    )(
+        offset.astype(jnp.int32), length.astype(jnp.int32),
+        page_table.astype(jnp.int32), q.reshape(b, n_q * rows, lanes),
+        latent_pages,
+    )
+    return out.reshape(b, n_q * q_tokens, h, value_lanes)[:, :c]

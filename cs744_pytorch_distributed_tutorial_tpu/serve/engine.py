@@ -467,6 +467,11 @@ class ServingEngine:
         )
         self.max_seq_len = model.max_seq_len
         self._scanned = bool(getattr(model, "scan_layers", False))
+        # a latent chunk attends by the Pallas walk over the slot's pages
+        # (models/latent.py): ``chunk_attn_pairs`` counts what it scores
+        self._chunk_walks = impl == "kernel" and (
+            getattr(model, "latent", None) is not None
+        )
 
         self._queue: deque[Request] = deque()
         self._slots: list[_Slot | None] = [None] * cfg.num_slots
@@ -507,6 +512,8 @@ class ServingEngine:
         self._max_admits_in_step = 0
         self._pages_grown = 0  # pages the grow loop allocated
         self._prefill_chunks = 0  # chunk programs run (prefill_chunk set)
+        # causal (query, key) pairs a layer the latent chunk walk scored
+        self._chunk_attn_pairs = 0
         # pages the one-shot commits wrote, trash pages included
         self._commit_pages = 0
         self._decode_puts = 0  # host-to-device puts made in decode_prep
@@ -1265,6 +1272,12 @@ class ServingEngine:
                     for ci in range(n_chunks):
                         off = ci * chunk
                         n = min(chunk, plen - off)
+                        if self._chunk_walks:
+                            # each real row sees the slot's rows before
+                            # the chunk and the chunk's up to its own
+                            self._chunk_attn_pairs += (
+                                n * off + n * (n + 1) // 2
+                            )
                         if self.window_pool is not None:
                             # The chunk's queries see back to
                             # off - window + 1: what lies behind goes
@@ -1853,6 +1866,10 @@ class ServingEngine:
             "max_admits_in_step": self._max_admits_in_step,
             "pages_grown": self._pages_grown,
             "prefill_chunks": self._prefill_chunks,
+            # causal (query, key) pairs a layer the latent chunk walk
+            # scored: len * offset + len * (len + 1) / 2 a chunk, over its
+            # real rows (0 without the walk: "gather", or no latent)
+            "chunk_attn_pairs": self._chunk_attn_pairs,
             # commit_pages / admissions: pages a one-shot commit writes,
             # ceil(bucket / page_size) (0 with prefill_chunk set)
             "commit_pages": self._commit_pages,

@@ -330,7 +330,7 @@ def run_serve_with_recovery(
         engine._active_slot_steps = 0
         engine._trash_rows = 0
         engine._admissions = engine._admit_steps = engine._commit_pages = 0
-        engine._admit_fetches = 0
+        engine._admit_fetches = engine._chunk_attn_pairs = 0
         engine._decode_puts = engine._pool_audits = 0
         engine._max_admits_in_step = engine._pages_grown = 0
         engine._decode_walls.clear()

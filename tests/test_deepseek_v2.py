@@ -349,11 +349,21 @@ def test_chunks_then_absorbed_decode_serve_the_reference_s_tokens(tiny, served):
 
 
 def test_the_page_walk_serves_the_same_tokens(tiny, served):
+    """The chunk's and decode's Pallas walks (interpret mode) serve the
+    gather reference's tokens; the chunk walk's causal pairs a layer are
+    counted at dispatch, and none under "gather"."""
     cfg, _, params, flat = tiny
     model, _, _ = build(cfg, flash_interpret=True)
-    _, reqs = _serve(model, params, paged_attention_impl="kernel")
+    engine, reqs = _serve(model, params, paged_attention_impl="kernel")
     assert [list(r.generated) for r in reqs] == [list(r.generated) for r in served[1]]
     assert _served_gap(flat, cfg, reqs[2]) < 1e-4
+    assert engine.stats()["preemptions"] == 0
+    chunk = SERVE["prefill_chunk"]
+    assert engine.stats()["chunk_attn_pairs"] == sum(
+        n * off + n * (n + 1) // 2
+        for p, _ in LENGTHS for off in range(0, p, chunk) for n in [min(chunk, p - off)]
+    )
+    assert served[0].stats()["chunk_attn_pairs"] == 0
 
 
 def test_one_latent_pool_a_layer(served):

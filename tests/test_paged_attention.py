@@ -25,6 +25,9 @@ rows are whole lane tiles; a page a grid step, for the rest):
    the walk executes is counted through a debug callback: two a live
    page (K and V), the same at any page-table capacity, where the gather
    reference's compiled bytes grow with the capacity.
+6. **The chunk walk** (``paged_chunk_attention``, a prefill chunk over one
+   latent pool) against the view gathered and attended by position, with
+   every page past the chunk's last position poisoned.
 """
 
 from __future__ import annotations
@@ -41,6 +44,7 @@ from cs744_pytorch_distributed_tutorial_tpu.ops import (
 )
 from cs744_pytorch_distributed_tutorial_tpu.ops.paged_attention import (
     paged_attention,
+    paged_chunk_attention,
 )
 from cs744_pytorch_distributed_tutorial_tpu.ops.quant import (
     paged_decode_attention_quant,
@@ -506,6 +510,68 @@ def test_kernel_copies_flat_where_gather_grows(page_copies):
     g8, g32 = gather_bytes(8), gather_bytes(32)
     assert g32 > 1.5 * g8, (g8, g32)
     assert kernel_copies(8) == kernel_copies(32) == 2 * B * 2
+
+
+# ------------------------------------------------- the chunk walk, latent pool
+
+
+@pytest.mark.parametrize(
+    "heads,value_lanes,offset,length,chunk,width,small",
+    [
+        (4, 32, 0, 16, 16, 6, True),  # at offset 0, a block's rows a page
+        (8, 64, 13, 20, 24, 6, True),  # mid-page, the last rows padding
+        (4, 64, 43, 24, 24, 12, True),  # across several key blocks
+        (8, 32, 40, 24, 32, 8, True),  # the last rows past the table
+        (8, 64, 100, 40, 40, 20, False),  # the blocks the chip runs
+    ],
+    ids=["offset-0", "mid-page", "key-blocks", "past-table", "full-blocks"],
+)
+def test_chunk_walk_matches_the_gathered_view(
+    heads, value_lanes, offset, length, chunk, width, small, monkeypatch
+):
+    """A chunk of ``length`` real rows at ``offset`` (padded to
+    ``chunk``) over one latent pool: the walk (interpret mode) against
+    ``attend_by_position`` over the gathered view. The table lists the
+    pages that hold positions up to the chunk's last real one, then 0
+    (the trash page) as the engine leaves it; every page past that
+    position, page 0 among them, holds NaN, so a finite answer shows
+    they are never read. Padding rows come back 0. ``small`` shrinks
+    the query and key blocks so that several of each are walked."""
+    from cs744_pytorch_distributed_tutorial_tpu.models.latent import (
+        attend_by_position,
+    )
+    from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
+        gather_pages,
+    )
+
+    if small:
+        monkeypatch.setattr(kernel_module, "_CHUNK_ROWS", 32)
+        monkeypatch.setattr(kernel_module, "_CHUNK_BLOCK_TOKENS", 16)
+    page, lanes, scale = 8, 128, 0.09
+    live = (offset + length - 1) // page + 1
+    rng = np.random.default_rng(offset)
+    pages = 1 + rng.permutation(width + 4)[:live]
+    table = np.zeros((1, width), np.int32)
+    table[0, :live] = pages
+    pool = np.full((width + 5, page, lanes), np.nan, np.float32)
+    pool[pages] = np.asarray(
+        jax.random.normal(jax.random.key(offset), (live, page, lanes))
+    )
+    q = jax.random.normal(jax.random.key(1), (1, chunk, heads, lanes))
+    got = np.asarray(paged_chunk_attention(
+        q, jnp.asarray(pool), jnp.asarray(table), jnp.asarray([offset]),
+        jnp.asarray([length]), value_lanes=value_lanes, scale=scale,
+        interpret=True,
+    ))
+    assert got.shape == (1, chunk, heads, value_lanes)
+    assert np.isfinite(got).all()
+    assert (got[:, length:] == 0).all()
+    view = gather_pages(jnp.nan_to_num(jnp.asarray(pool)), jnp.asarray(table))
+    want = attend_by_position(
+        q[:, :length], view[:, :, None, :], view[:, :, None, :value_lanes],
+        offset + jnp.arange(length)[None], scale,
+    )
+    np.testing.assert_allclose(got[:, :length], want, rtol=2e-5, atol=2e-5)
 
 
 def test_validation():

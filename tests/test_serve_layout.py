@@ -256,8 +256,9 @@ def latent_compiled(one_chip):
 def test_no_program_copies_a_latent_pool(latent_compiled, program):
     """One pool a sublayer, ``[num_pages, page_size, 640]``: no
     pool-sized copy in either program, the pools row-major as they
-    enter, and the decode step's walk one ``attn_latent`` call a
-    sublayer over that one pool (the chunk program has none)."""
+    enter, and each program's walk one call a sublayer over that one
+    pool: ``attn_latent`` in the decode step, ``attn_latent_chunk`` in
+    the chunk program."""
     engine, programs = latent_compiled
     pools = jax.tree_util.tree_leaves_with_path(engine._pages)
     assert {path[-1].key for path, _ in pools} == {"latent_pages"}
@@ -266,16 +267,16 @@ def test_no_program_copies_a_latent_pool(latent_compiled, program):
     assert len(got.entry_layouts) == 2 and got.row_major, got.entry_layouts
     assert got.pool_copies == []
     assert got.pool_bytes == 513 * 16 * 640 * 2
-    # (b): the chunk's temporaries are its scores over the view, which
-    # grow with the slot's capacity and not with the pool
+    # (b): the chunk walks the pool: no scores over the view
     assert got.temp_bytes < got.pool_bytes, got
     text = programs[program].as_text()
+    scope = "/attn_latent/" if program == "decode" else "/attn_latent_chunk/"
     walks = {
         line.split(" = ")[0].strip() for line in text.splitlines()
-        if "tpu_custom_call" in line and "/attn_latent/" in line
+        if "tpu_custom_call" in line and scope in line
     }
-    assert len(walks) == (2 if program == "decode" else 0), walks
+    assert len(walks) == 2, walks
     for line in text.splitlines():
-        if "tpu_custom_call" in line and "/attn_latent/" in line:
-            # pos, table, q, ONE pool
+        if "tpu_custom_call" in line and scope in line:
+            # the scalars, table, q, ONE pool
             assert line.count("bf16[513,16,640]") == 1, line

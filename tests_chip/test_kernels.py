@@ -353,6 +353,52 @@ def test_paged_attention_chat_cell_shape():
     assert_close(got, want, 3e-2)
 
 
+@pytest.mark.parametrize("offset,length", [(0, 512), (8_000, 500), (16_896, 512)])
+def test_paged_chunk_attention_doc_cell_shape(offset, length):
+    """The doc cell's chunk walk: 512 tokens of 128 heads' absorbed
+    queries on a 640-lane row over a slot of 1,088 pages of 16, bf16;
+    at offset 0, mid-page with padding rows, and the slot's last
+    chunk. The reference attends over the gathered view in float32."""
+    from cs744_pytorch_distributed_tutorial_tpu.models.latent import (
+        attend_by_position,
+    )
+    from cs744_pytorch_distributed_tutorial_tpu.ops.paged_attention import (
+        paged_chunk_attention,
+    )
+    from cs744_pytorch_distributed_tutorial_tpu.parallel.ring_attention import (
+        gather_pages,
+    )
+
+    capacity, num_pages, lanes, r, chunk = 1088, 4097, 640, 512, 512
+    scale = 192 ** -0.5
+    q = _normal(0, (1, chunk, 128, lanes), jnp.bfloat16).at[..., 576:].set(0)
+    pool = _normal(1, (num_pages, PAGE, lanes), jnp.bfloat16).at[..., 576:].set(0)
+    rng = np.random.default_rng(offset)
+    table = jnp.asarray(
+        (1 + rng.permutation(num_pages - 1)[:capacity])[None], jnp.int32
+    )
+    off, ln = jnp.asarray([offset]), jnp.asarray([length])
+    got = jax.jit(
+        lambda q, pool, table, off, ln: paged_chunk_attention(
+            q, pool, table, off, ln, value_lanes=r, scale=scale,
+            interpret=False,
+        )
+    )(q, pool, table, off, ln)
+
+    def reference(q, pool, table):
+        view = gather_pages(pool, table).astype(jnp.float32)
+        return attend_by_position(
+            q[:, :length].astype(jnp.float32), view[:, :, None, :],
+            view[:, :, None, :r], offset + jnp.arange(length)[None], scale,
+        )
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(reference)(q, pool, table)
+    assert got.shape == (1, chunk, 128, r) and got.dtype == jnp.bfloat16
+    assert_close(got[:, :length], want, 3e-2)
+    assert not np.asarray(got[:, length:], np.float32).any()
+
+
 @pytest.fixture(scope="module")
 def serve_cell_programs():
     from cs744_pytorch_distributed_tutorial_tpu.models import TransformerLM
