@@ -42,6 +42,7 @@ from cs744_pytorch_distributed_tutorial_tpu.models.hf_interop import (
     llama_model_config,
     longcat_flash_model_config,
     mellum_model_config,
+    minicpm_sala_model_config,
     model_config_from_hf,
     lm_params_from_hf_gpt2,
     lm_params_from_hf_llama,
@@ -140,6 +141,7 @@ __all__ = [
     "keye_model_config",
     "longcat_flash_model_config",
     "mellum_model_config",
+    "minicpm_sala_model_config",
     "model_config_from_hf",
     "llama_model_config",
     "lm_params_from_hf_gpt2",

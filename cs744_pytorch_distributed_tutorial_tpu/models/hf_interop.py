@@ -598,12 +598,140 @@ def deepseek_v2_model_config(
     )
 
 
+def lightning_rates(num_heads: int, layer: int, num_layers: int) -> tuple[float, ...]:
+    """The decay exponent of each head of a lightning layer, ``lam_h =
+    exp(-rate_h)``, as MiniMax-01's lightning attention builds it
+    (``_build_slope_tensor``): the slope ``s_h = 2^(-8 (h + 1) / H)`` (H
+    a power of two) times ``1 - layer / (num_layers - 1) + 1e-5``, for
+    the layer's index ``layer`` among ``num_layers``."""
+    if num_heads & (num_heads - 1):
+        raise ValueError(
+            f"lightning heads {num_heads} is no power of two: the slopes of "
+            "other head counts are not built"
+        )
+    depth = 1.0 - layer / (num_layers - 1) + 1e-5
+    return tuple(2.0 ** (-8.0 * (h + 1) / num_heads) * depth for h in range(num_heads))
+
+
+# MiniCPM's mixer names -> TransformerLM's layer_types
+_SALA_MIXERS = {
+    "lightning-attn": "lightning_attention",
+    "minicpm4": "block_sparse_attention",
+}
+
+
+def minicpm_sala_model_config(
+    hf_config: Mapping[str, Any], max_seq_len: int | None = None,
+    layer_ids: Sequence[int] | None = None,
+) -> dict:
+    """``TransformerLM`` kwargs for a ``minicpm_sala`` ``config.json``
+    (openbmb/MiniCPM-SALA): each layer of ``mixer_types`` is lightning
+    attention (``lightning-attn``: ``lightning_nh`` heads of
+    ``lightning_head_dim``, RoPE and q/k RMSNorm, the output RMSNorm over
+    every head and the sigmoid gate, a head's decay as
+    ``lightning_rates`` gives it for the layer's index) or block-sparse
+    attention over compressed keys (``minicpm4``: GQA with no position
+    encoding, q/k RMSNorm, the sigmoid output gate, the selection of
+    ``sparse_config`` as MiniCPM4 names its keys, MiniCPM4-8B's values
+    where the file gives none), each beside a dense SwiGLU of
+    ``intermediate_size``; MiniCPM's muP: the embedding times
+    ``scale_emb``, each residual times ``scale_depth /
+    sqrt(mup_denominator)``, the final norm's output times
+    ``dim_model_base / hidden_size``; untied head. Read from the
+    published keys alone.
+
+    ``layer_ids`` are the layers of the file that one chip holds (a
+    stage of a pipeline, the ``model-configs`` guide's section 4), in
+    order; each keeps its own kind and its decay by its index in the
+    file. None holds them all. ``max_seq_len`` defaults to
+    ``max_position_embeddings``."""
+    from cs744_pytorch_distributed_tutorial_tpu.ops.block_sparse import BlockSparse
+
+    c = hf_config
+    if c.get("attention_bias"):
+        raise ValueError("attention_bias is not supported")
+    if c.get("hidden_act", "silu") != "silu":
+        raise ValueError("hidden_act other than silu is not supported")
+    if c.get("tie_word_embeddings"):
+        raise ValueError("tied embeddings are not supported: the head is untied")
+    if c.get("attn_use_rope"):
+        raise ValueError(
+            "attn_use_rope true is not supported: the block-sparse layers "
+            "use no position encoding"
+        )
+    if not c.get("lightning_use_rope", True):
+        raise ValueError("lightning_use_rope false is not supported: the lightning layers rotate")
+    for key in ("qk_norm", "use_output_gate", "use_output_norm", "attn_use_output_gate"):
+        if not c.get(key, True):
+            raise ValueError(f"{key} false is not supported: the layers are built with it")
+    heads, head_dim = c["num_attention_heads"], c["head_dim"]
+    if (
+        c.get("lightning_nh", heads) != heads or c.get("lightning_nkv", heads) != heads
+        or c.get("lightning_head_dim", head_dim) != head_dim
+    ):
+        raise ValueError(
+            "lightning heads other than num_attention_heads of head_dim (or "
+            "shared among queries: lightning_nkv < lightning_nh) are not supported"
+        )
+    if c.get("lightning_scale", "1/sqrt(d)") != "1/sqrt(d)":
+        raise ValueError(f"lightning_scale {c['lightning_scale']!r} is not supported")
+    mixers = list(c["mixer_types"])
+    if len(mixers) != c["num_hidden_layers"] or not set(mixers) <= set(_SALA_MIXERS):
+        raise ValueError(
+            f"mixer_types must name {c['num_hidden_layers']} layers, each one "
+            f"of {sorted(_SALA_MIXERS)}; got {sorted(set(mixers))}"
+        )
+    ids = list(range(len(mixers))) if layer_ids is None else [int(i) for i in layer_ids]
+    if not ids or any(not 0 <= i < len(mixers) for i in ids):
+        raise ValueError(f"layer_ids {ids} are not layers of {len(mixers)}")
+    kinds = tuple(_SALA_MIXERS[mixers[i]] for i in ids)
+    sc = c.get("sparse_config") or {}
+    default = BlockSparse()
+    sparse = BlockSparse(
+        kernel_size=int(sc.get("kernel_size", default.kernel_size)),
+        kernel_stride=int(sc.get("kernel_stride", default.kernel_stride)),
+        block_size=int(sc.get("block_size", default.block_size)),
+        window=int(sc.get("window_size", default.window)),
+        topk=int(sc.get("topk", default.topk)),
+        init_blocks=int(sc.get("init_blocks", default.init_blocks)),
+        dense_len=int(sc.get("dense_len", default.dense_len)),
+    )
+    sparse.check()
+    return dict(
+        vocab_size=c["vocab_size"],
+        num_layers=len(ids),
+        num_heads=heads,
+        num_kv_heads=c["num_key_value_heads"],
+        head_dim=head_dim,
+        d_model=c["hidden_size"],
+        d_ff=c["intermediate_size"],
+        max_seq_len=max_seq_len or c["max_position_embeddings"],
+        use_rope=True,
+        rope_base=float(c["rope_theta"]),
+        tie_embeddings=False,
+        norm="rmsnorm",
+        norm_eps=c["rms_norm_eps"],
+        mlp="swiglu",
+        layer_types=kinds,
+        lightning_rates=tuple(
+            lightning_rates(heads, i, len(mixers)) if k == "lightning_attention" else None
+            for i, k in zip(ids, kinds)
+        ),
+        block_sparse=sparse,
+        embed_scale=float(c["scale_emb"]),
+        residual_scale=float(c["scale_depth"]) / float(c["mup_denominator"]) ** 0.5,
+        logit_scale=float(c["dim_model_base"]) / float(c["hidden_size"]),
+        attention_impl="dense",
+    )
+
+
 # ``model_type`` of a published config.json -> the builder of its kwargs
 CONFIG_BUILDERS = {
     "KeyeVL2": keye_model_config,
     "mellum": mellum_model_config,
     "longcat_flash": longcat_flash_model_config,
     "deepseek_v2": deepseek_v2_model_config,
+    "minicpm_sala": minicpm_sala_model_config,
 }
 
 
@@ -613,8 +741,8 @@ def model_config_from_hf(
     """``TransformerLM`` kwargs from a published ``config.json``, by its
     ``model_type``. ``how_deployed`` goes to the builder: what a
     deployment decides and no published file says (``held_experts`` of
-    ``longcat_flash`` and ``deepseek_v2``); a builder that has no such
-    argument raises."""
+    ``longcat_flash`` and ``deepseek_v2``, ``layer_ids`` of
+    ``minicpm_sala``); a builder that has no such argument raises."""
     kind = hf_config.get("model_type")
     if kind not in CONFIG_BUILDERS:
         raise ValueError(

@@ -47,6 +47,9 @@ REMAT_POLICIES = ("none", "dots")
 
 NORM_IMPLS = ("layernorm", "rmsnorm")
 MLP_IMPLS = ("gelu", "swiglu")
+# layer_types of a model whose mixers are lightning and block-sparse
+# attention (models/hybrid.py)
+HYBRID_KINDS = ("lightning_attention", "block_sparse_attention")
 
 
 def _norm_cls(norm: str, eps: float = 1e-6):
@@ -1343,10 +1346,90 @@ class TransformerLM(nn.Module):
     moe_n_group: int = 1
     moe_topk_group: int = 1
     moe_shared_d_ff: int = 0
+    # Mixers that differ by layer between lightning attention
+    # (models/lightning.py) and block-sparse attention over compressed
+    # keys (models/block_sparse.py): ``layer_types`` names each layer
+    # "lightning_attention" or "block_sparse_attention" (HYBRID_KINDS),
+    # every layer the pre-norm block of models/hybrid.py. A lightning
+    # layer's heads decay by ``exp(-rate)``, its rates the layer's entry
+    # of ``lightning_rates`` (one a layer, None on the others); the
+    # block-sparse layers select by ``block_sparse``
+    # (ops/block_sparse.py::BlockSparse). Served, a lightning layer keeps
+    # one state row a slot (``slot_state_layers``) and a block-sparse
+    # layer its K and V pools and a compressed key a page. MiniCPM's muP
+    # scalars: the embedding times ``embed_scale``, each residual times
+    # ``residual_scale``, the final norm's output times ``logit_scale``;
+    # at 1.0 none is applied.
+    lightning_rates: tuple | None = None
+    block_sparse: Any = None
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
 
     def window_layers(self) -> int:
         """How many layers are sliding-window layers."""
         return sum(k == "sliding_attention" for k in self.layer_types or ())
+
+    def slot_state_layers(self) -> int:
+        """How many layers keep a recurrent state a slot (lightning)."""
+        return sum(k == "lightning_attention" for k in self.layer_types or ())
+
+    def _hybrid(self) -> bool:
+        return bool(set(self.layer_types or ()) & set(HYBRID_KINDS))
+
+    def _check_hybrid(self, mode: str) -> None:
+        """A model with lightning or block-sparse layers: raise, with its
+        reason, for each combination that is not built."""
+
+        def no(what: str, why: str):
+            raise ValueError(
+                f"lightning and block-sparse layers with {what} are not "
+                f"built: {why}"
+            )
+
+        kinds = set(self.layer_types)
+        if len(self.layer_types) != self.num_layers or not kinds <= set(HYBRID_KINDS):
+            no(
+                f"layer_types {self.layer_types!r}",
+                f"name {self.num_layers} layers, each one of {HYBRID_KINDS}",
+            )
+        if mode in ("prefill", "decode"):
+            no(
+                f"mode={mode!r}",
+                "the dense cache holds keys and values of every position, "
+                "where a lightning layer keeps a state and a block-sparse "
+                "layer compressed keys; serve through the paged pools and "
+                "the slots' state rows (ServeConfig.prefill_chunk)",
+            )
+        if self.quant_kv_cache or self.quant_dense:
+            no("int8 (quant_kv_cache, quant_dense)", "pools, state and kernels are float")
+        if (self.tensor_axis is not None and self.tensor_axis_size > 1) or (
+            self.seq_axis is not None and self.seq_axis_size > 1
+        ):
+            no("a tensor or sequence axis", "the state rows and the selection run on one device")
+        if self.scan_layers:
+            no("scan_layers", "the layers differ by kind and are built unrolled")
+        if self.num_experts or self.latent is not None or self.window is not None or self.indexer_heads:
+            no(
+                "experts, latent attention, a window or an indexer",
+                "the layer is a lightning or block-sparse mixer and a dense SwiGLU",
+            )
+        if "lightning_attention" in kinds and (
+            self.lightning_rates is None or len(self.lightning_rates) != self.num_layers
+            or any(
+                r is None for r, k in zip(self.lightning_rates, self.layer_types)
+                if k == "lightning_attention"
+            )
+        ):
+            no("no lightning_rates", "each lightning layer needs its heads' decay")
+        if "block_sparse_attention" in kinds and self.block_sparse is None:
+            no("no block_sparse", "the block-sparse layers need the selection's parameters")
+        if not self.use_rope or self.norm != "rmsnorm" or self.mlp != "swiglu":
+            no(
+                "learned positions, LayerNorm or a GELU MLP",
+                "use_rope=True (the lightning layers rotate, the block-sparse "
+                "ones use no position), norm='rmsnorm', mlp='swiglu'",
+            )
 
     def _check_latent(self, mode: str) -> None:
         """A model with latent attention: raise, with its reason, for
@@ -1438,12 +1521,29 @@ class TransformerLM(nn.Module):
         logits_at: jnp.ndarray | None = None,
         window_page_table: jnp.ndarray | None = None,
         window_first_pos: jnp.ndarray | None = None,
+        slot_rows: jnp.ndarray | None = None,
+        slot_live: jnp.ndarray | None = None,
     ) -> jnp.ndarray:
         """``logits_at`` ([B] indices into this call's tokens) asks for
         the logits of one position a row only, ``[B, 1, vocab]``: the
         final norm and the head then run on that row alone (a prefill
-        chunk needs one token's logits, not a chunk's)."""
+        chunk needs one token's logits, not a chunk's). A model with
+        lightning layers (``slot_state_layers``) is told, by a prefill
+        chunk, the state row of each batch row (``slot_rows`` [B]) and, by
+        a decode step, which slots advance (``slot_live`` [B])."""
         b, t_local = tokens.shape
+        hybrid = self._hybrid()
+        if hybrid:
+            self._check_hybrid(mode)
+        elif (
+            self.lightning_rates is not None or self.block_sparse is not None
+            or self.residual_scale != 1.0
+        ):
+            raise ValueError(
+                "lightning_rates, block_sparse and residual_scale belong to "
+                "the lightning and block-sparse layers (layer_types of "
+                f"{HYBRID_KINDS})"
+            )
         if self.latent is not None:
             self._check_latent(mode)
         elif (
@@ -1459,7 +1559,7 @@ class TransformerLM(nn.Module):
                 "shortcut-MoE layer and the plain latent block (latent "
                 "set); Block's MoE takes none"
             )
-        if self.layer_types is not None:
+        if self.layer_types is not None and not hybrid:
             kinds = set(self.layer_types)
             if len(self.layer_types) != self.num_layers or not kinds <= {
                 "full_attention", "sliding_attention"
@@ -1490,6 +1590,8 @@ class TransformerLM(nn.Module):
             self.vocab_size, self.d_model, dtype=self.dtype, name="tok_embed"
         )
         x = tok_embed(tokens)
+        if self.embed_scale != 1.0:
+            x = x * jnp.asarray(self.embed_scale, x.dtype)
         # Global positions: a sequence-sharded block starts at the
         # device's offset along the seq axis, not at 0; a cached decode
         # step sits at its decode position.
@@ -1627,6 +1729,40 @@ class TransformerLM(nn.Module):
                     "serve_stats", "latent_tokens_read",
                     (1 if plain else 2) * self.num_layers * (decode_pos + 1),
                 )
+        elif hybrid:
+            from cs744_pytorch_distributed_tutorial_tpu.models.hybrid import (
+                HybridBlock,
+            )
+
+            head_dim = self.head_dim or self.d_model // self.num_heads
+            for i, kind in enumerate(self.layer_types):
+                if kind == "lightning_attention":
+                    mixer = (
+                        ("num_heads", self.num_heads), ("head_dim", head_dim),
+                        ("rate", tuple(self.lightning_rates[i])),
+                        ("rope_base", self.rope_base),
+                    )
+                else:
+                    mixer = (
+                        ("num_heads", self.num_heads),
+                        ("num_kv_heads", self.num_kv_heads or self.num_heads),
+                        ("head_dim", head_dim), ("sparse", self.block_sparse),
+                        ("page_size", self.page_size), ("num_pages", self.num_pages),
+                    )
+                mixer += (
+                    ("norm_eps", self.norm_eps),
+                    ("paged_attention_impl", self.paged_attention_impl),
+                    ("flash_interpret", self.flash_interpret),
+                )
+                x = HybridBlock(
+                    kind=kind, mixer=mixer, d_ff=self.d_ff, dtype=self.dtype,
+                    norm_eps=self.norm_eps, residual_scale=self.residual_scale,
+                    name=f"block_{i}",
+                )(
+                    x, deterministic, mode=mode, decode_pos=decode_pos,
+                    page_table=page_table, slot_rows=slot_rows,
+                    slot_live=slot_live, last_idx=logits_at,
+                )
         elif self.scan_layers:
             if self.num_experts > 0:
                 raise ValueError(
@@ -1714,6 +1850,8 @@ class TransformerLM(nn.Module):
         if logits_at is not None:
             x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
         x = _norm_cls(self.norm, self.norm_eps)(dtype=self.dtype, name="ln_f")(x)
+        if self.logit_scale != 1.0:
+            x = x * jnp.asarray(self.logit_scale, x.dtype)
         if self.tie_embeddings:
             # The attend path reuses the (unquantized) embedding table —
             # quant_dense deliberately leaves it float.

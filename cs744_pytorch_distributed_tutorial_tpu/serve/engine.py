@@ -50,6 +50,19 @@ sublayer, ``latent_pages [num_pages, page_size, lanes]``, where another
 declares ``key_pages`` and ``value_pages``; the engine builds, donates,
 leases and frees it under the same page table (docs/serving.md).
 
+A second kind of cache is addressed by slot and not by page table: a
+model with lightning layers (``TransformerLM.slot_state_layers``,
+models/lightning.py) declares ``lightning_state [num_slots, H, d, d]``
+in the same collection, one float32 state row a slot. It is built,
+donated and carried with the pools; the chunk program is told its
+slot's row (one more scalar of its packed argument, for such a model
+only) and starts it from zero at position 0, and the decode step tells
+the model which slots are active, so only their rows advance. A
+preemption gives a row up as it gives up pages: the request's
+re-admission recomputes it from position 0. A block-sparse layer
+(models/block_sparse.py) adds ``compressed_key_pages``, one row a page,
+under the page table (docs/serving.md).
+
 Decode attention has two implementations (``paged_attention_impl``):
 the "gather" reference is BITWISE-identical to the dense-cache path (the
 gathered page view reproduces the cache layout exactly and runs the same
@@ -132,6 +145,10 @@ _SHARE_COUNTERS = (
     "held_expert_pairs", "zero_expert_pairs", "absent_expert_pairs",
 )
 _GROUP_COUNTERS = ("held_group_tokens",)
+_SLOT_STATE_COUNTERS = ("lightning_state_updates",)
+_BLOCK_SPARSE_COUNTERS = (
+    "sparse_selected_tokens", "sparse_live_tokens", "sparse_scored_kernels",
+)
 
 
 def _step_counters(stats: Any, active: jnp.ndarray, num_experts: int):
@@ -146,15 +163,23 @@ def _step_counters(stats: Any, active: jnp.ndarray, num_experts: int):
     latent attention, the latent rows attended; from an expert layer
     that holds a share or has zero-compute experts, its (token, expert)
     pairs by where the expert is; from a share under group-limited
-    choice, the tokens whose kept groups hold a held expert. (None, ())
-    where the model sowed nothing."""
+    choice, the tokens whose kept groups hold a held expert; from a model
+    with lightning layers, the (slot, layer) state updates; from one with
+    block-sparse layers, the positions its queries attended and the
+    positions live, and the compressed keys scored, over the KV groups.
+    (None, ()) where the model sowed nothing."""
     selected = _named_leaves(stats, "selected_tokens")
     scored = _named_leaves(stats, "scored_tokens")
     routed = _named_leaves(stats, "expert_idx")
     full_read = _named_leaves(stats, "full_tokens_read")
     window_read = _named_leaves(stats, "window_tokens_read")
     latent_read = _named_leaves(stats, "latent_tokens_read")
-    if not (selected or routed or full_read or latent_read):
+    state_updates = _named_leaves(stats, _SLOT_STATE_COUNTERS[0])
+    sparse_read = _named_leaves(stats, _BLOCK_SPARSE_COUNTERS[0])
+    if not (
+        selected or routed or full_read or latent_read or state_updates
+        or sparse_read
+    ):
         return None, ()
     zero = jnp.int32(0)
 
@@ -189,6 +214,15 @@ def _step_counters(stats: Any, active: jnp.ndarray, num_experts: int):
     if _named_leaves(stats, _GROUP_COUNTERS[0]):
         counters.append(over_active(_named_leaves(stats, _GROUP_COUNTERS[0])))
         names += _GROUP_COUNTERS
+    if state_updates:
+        counters.append(over_active(state_updates))
+        names += _SLOT_STATE_COUNTERS
+    if sparse_read:
+        counters += [
+            over_active(_named_leaves(stats, name))
+            for name in _BLOCK_SPARSE_COUNTERS
+        ]
+        names += _BLOCK_SPARSE_COUNTERS
     return jnp.stack(counters).astype(jnp.int32), names
 
 
@@ -467,6 +501,10 @@ class ServingEngine:
         )
         self.max_seq_len = model.max_seq_len
         self._scanned = bool(getattr(model, "scan_layers", False))
+        # A model with a state row a slot (lightning layers): the chunk
+        # program is told its slot's row, the decode step which slots
+        # advance (``_slot_kw``). A model without one has neither.
+        self._slot_state = bool(getattr(model, "slot_state_layers", lambda: 0)())
         # a latent chunk attends by the Pallas walk over the slot's pages
         # (models/latent.py): ``chunk_attn_pairs`` counts what it scores
         self._chunk_walks = impl == "kernel" and (
@@ -525,7 +563,8 @@ class ServingEngine:
         self._counter_names: tuple[str, ...] = ()  # set when the step traces
         self._counts = dict.fromkeys(
             _ROUTING_COUNTERS + _WINDOW_COUNTERS + _LATENT_COUNTERS
-            + _SHARE_COUNTERS + _GROUP_COUNTERS, 0,
+            + _SHARE_COUNTERS + _GROUP_COUNTERS + _SLOT_STATE_COUNTERS
+            + _BLOCK_SPARSE_COUNTERS, 0,
         )
         if cfg.prefill_chunk is not None and cfg.prefill_chunk < 1:
             raise ValueError(
@@ -545,6 +584,9 @@ class ServingEngine:
 
         self._pages = self._init_pages()
         self._decode_step = self._build_decode_step()
+        # Set by the steps of ``keep_logits``' program alone.
+        self.last_logits: Any = None
+        self.last_logit_rows: dict[int, int] = {}
 
     # ---------------------------------------------------------- build
 
@@ -560,9 +602,23 @@ class ServingEngine:
                 return jnp.ones(s.shape, s.dtype)
             return jnp.zeros(s.shape, s.dtype)
 
-        pages = jax.tree_util.tree_map_with_path(
-            materialize, self._pages_shape_tree()
-        )
+        shapes = self._pages_shape_tree()
+        if not self.cfg.prefill_chunk:
+            # the one-shot commit fills a pool from the dense cache's
+            # rows of the same name; any other pool only chunks fill
+            names = {
+                path[-1].key
+                for path, _ in jax.tree_util.tree_leaves_with_path(shapes)
+            }
+            if not names <= set(_CACHE_TO_PAGES.values()):
+                raise ValueError(
+                    "a model that keeps "
+                    f"{sorted(names - set(_CACHE_TO_PAGES.values()))} "
+                    "beside its pools is served by chunks: the one-shot "
+                    "prefill fills only what a dense cache holds. Set "
+                    "ServeConfig.prefill_chunk"
+                )
+        pages = jax.tree_util.tree_map_with_path(materialize, shapes)
         if self.mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -617,6 +673,18 @@ class ServingEngine:
 
         return jax.eval_shape(init_fn)
 
+    def _slot_kw(self, **kw: Any) -> dict[str, Any]:
+        """The model's keywords for its state rows (a chunk's
+        ``slot_rows``, a decode step's ``slot_live``); none for a model
+        without them."""
+        return kw if self._slot_state else {}
+
+    def _chunk_scalars(self) -> int:
+        """Scalars of the chunk program's packed argument: offset, last
+        index, (the slot, with state rows), and the sampling stream's
+        two."""
+        return 5 if self._slot_state else 4
+
     def _window_kw(self, *window: Any) -> dict[str, Any]:
         """The model's keywords for the window group's table and its
         first row's positions; none for a model without window layers
@@ -626,9 +694,10 @@ class ServingEngine:
         table, first = window
         return {"window_page_table": table, "window_first_pos": first}
 
-    def _jit_pages_program(self, fn, n_replicated: int):
+    def _jit_pages_program(self, fn, n_replicated: int, n_out: int = 1):
         """``jax.jit`` of one of the engine's programs, ``fn(params,
-        pages, *replicated) -> (pages, tokens)``, the pools donated (XLA
+        pages, *replicated) -> (pages, tokens, ...)`` with ``n_out``
+        replicated outputs behind the pools, the pools donated (XLA
         aliases them in place; no program allocates a pool); under a mesh
         wrapped in ``shard_map`` with the params' and the pools' specs."""
         if self.mesh is None:
@@ -642,20 +711,29 @@ class ServingEngine:
                 mesh=self.mesh,
                 in_specs=(self.param_specs, page_specs)
                 + (P(),) * n_replicated,
-                out_specs=(page_specs, P()),
+                out_specs=(page_specs,) + (P(),) * n_out,
                 check_vma=False,
             ),
             donate_argnums=(1,),
         )
 
-    def _build_decode_step(self):
+    def keep_logits(self) -> None:
+        """Decode from here on by a second compiled step that also
+        returns the float32 logits it sampled from, kept on the device as
+        ``last_logits`` [num_slots, vocab], with ``last_logit_rows``
+        ({request id: row}) of the step that made them: what the served
+        cache gives, for checking it against a reference. The logits are
+        not fetched; the step is otherwise the one it replaces."""
+        self._decode_step = self._build_decode_step(keep_logits=True)
+
+    def _build_decode_step(self, keep_logits: bool = False):
         """ONE jitted fixed-shape step for the engine's lifetime: what
         varies from step to step arrives in one traced vector of static
         shape (``_unpack_decode_arg``), so slot churn (retire / refill /
         preempt — different page tables, lengths, actives, request ids,
         token indices) re-runs the SAME executable. Pages are donated:
         XLA aliases the pool buffers in place, the step allocates no new
-        pool."""
+        pool. With ``keep_logits`` it returns the logits too."""
         cfg = self.cfg
         model = self.model
 
@@ -671,6 +749,7 @@ class ServingEngine:
                 page_table=page_table,
                 mutable=["pages", "serve_stats"],
                 **self._window_kw(*window),
+                **self._slot_kw(slot_live=active),
             )
             # Per-slot sampling keys from the (request, token-index)
             # stream — see _sample_root. ``key`` is the constant stream
@@ -701,9 +780,11 @@ class ServingEngine:
             self._counter_names = names
             if counters is not None:
                 tok = jnp.concatenate([tok, counters])
+            if keep_logits:
+                return mutated["pages"], tok, logits[:, 0].astype(jnp.float32)
             return mutated["pages"], tok
 
-        return self._jit_pages_program(step, 2)
+        return self._jit_pages_program(step, 2, 2 if keep_logits else 1)
 
     # The decode step takes ONE int32 vector beside the pools and the
     # constant stream root, as the prefill programs do, so a step is one
@@ -850,8 +931,10 @@ class ServingEngine:
         model = self.model
 
         def prefill_chunk(params, pages, packed, key):
-            tokens, (offset, last_idx), page_row, key, window = (
-                self._unpack_program_arg(packed, key, cfg.prefill_chunk, 4)
+            tokens, (offset, last_idx, *slot), page_row, key, window = (
+                self._unpack_program_arg(
+                    packed, key, cfg.prefill_chunk, self._chunk_scalars()
+                )
             )
             logits, mutated = model.apply(
                 {"params": params, "pages": pages},
@@ -862,6 +945,7 @@ class ServingEngine:
                 logits_at=last_idx[None],
                 mutable=["pages"],
                 **self._window_kw(*(w[None] for w in window)),
+                **self._slot_kw(slot_rows=slot[0][None] if slot else None),
             )
             tok = sample_tokens(
                 logits[:, 0].astype(jnp.float32),
@@ -881,9 +965,10 @@ class ServingEngine:
     #    window row | window first pos]
     # (the last two of a model with window layers only). The bucket
     # program's scalars are (true_len, req_id, token index), the chunk
-    # program's (offset, last_idx, req_id, token index): the last two
-    # are the sampling stream's, and the programs fold the key from
-    # them as the decode step does.
+    # program's (offset, last_idx, req_id, token index), with the slot
+    # after last_idx for a model with state rows: the last two are the
+    # sampling stream's, and the programs fold the key from them as the
+    # decode step does.
 
     def _program_arg_len(self, width: int, n_scalars: int) -> int:
         w = self.window_table_width
@@ -1302,7 +1387,9 @@ class ServingEngine:
                                 self.params, self._pages,
                                 self._pack_program_arg(
                                     chunk, req.prompt[off:off + n],
-                                    (off, n - 1, req.req_id, tok_idx), row,
+                                    (off, n - 1)
+                                    + ((slot_idx,) if self._slot_state else ())
+                                    + (req.req_id, tok_idx), row,
                                     window_pages, window_first,
                                 ),
                                 self._sample_root,
@@ -1581,9 +1668,16 @@ class ServingEngine:
             self._decode_puts += 1
             prep_span.set_metadata(puts=1)
         with profiling.annotate("serve/decode", step=step, active=n_active):
-            self._pages, toks = self._decode_step(
+            out = self._decode_step(
                 self.params, self._pages, packed, self._sample_root
             )
+            self._pages, toks = out[0], out[1]
+            if len(out) > 2:
+                self.last_logits = out[2]
+                self.last_logit_rows = {
+                    s.req.req_id: i for i, s in enumerate(self._slots)
+                    if s is not None
+                }
             toks = np.asarray(toks)  # graftlint: disable=GL001 -- the scheduler NEEDS this sync: retire/refill decisions read the sampled tokens; one fetch per engine step, outside any jit
             toks, counters = toks[: cfg.num_slots], toks[cfg.num_slots:]
         with profiling.annotate("serve/retire", step=step) as retire_span:
@@ -1911,6 +2005,15 @@ class ServingEngine:
             # a share under group-limited choice: (token, layer) pairs
             # whose kept groups hold a held expert. 0 otherwise
             "held_group_tokens": self._counts["held_group_tokens"],
+            # a model with lightning layers: (active slot, layer) state
+            # rows the decode steps advanced; with block-sparse layers:
+            # positions the decode steps' queries attended and positions
+            # live, and compressed keys scored (past dense_len), each
+            # summed over active slots, layers and KV groups. 0 otherwise
+            "lightning_state_updates": self._counts["lightning_state_updates"],
+            "sparse_selected_tokens": self._counts["sparse_selected_tokens"],
+            "sparse_live_tokens": self._counts["sparse_live_tokens"],
+            "sparse_scored_kernels": self._counts["sparse_scored_kernels"],
         }
 
 

@@ -110,7 +110,9 @@ def compile_programs(
     if cfg.prefill_chunk:
         prefill = engine._chunk_fn().lower(
             params, pages,
-            s((engine._program_arg_len(cfg.prefill_chunk, 4),), i32), key,
+            s((engine._program_arg_len(
+                cfg.prefill_chunk, engine._chunk_scalars()
+            ),), i32), key,
         ).compile()
     else:
         prefill = engine._prefill_fn(bucket).lower(

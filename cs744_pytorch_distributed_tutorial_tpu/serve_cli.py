@@ -69,12 +69,15 @@ def build_parser() -> argparse.ArgumentParser:
                         "share of the routed ones; deepseek_v2 = latent "
                         "attention with YaRN, one pool a layer, a leading "
                         "dense layer, group-limited routing and shared "
-                        "experts) in "
+                        "experts; minicpm_sala = lightning-attention layers "
+                        "with a state row a slot beside block-sparse layers "
+                        "over paged K and V and compressed keys) in "
                         "place of the size flags above; --max-seq-len still "
                         "caps a request. Random params, as ever; no "
                         "--parity-check (the dense-cache generator has no "
-                        "indexer, no window and no latent); window and "
-                        "latent layers need --prefill-chunk")
+                        "indexer, no window and no latent); window, latent, "
+                        "lightning and block-sparse layers need "
+                        "--prefill-chunk")
     p.add_argument("--held-experts", type=int, default=None, metavar="N",
                    help="with --model-config longcat_flash or "
                         "deepseek_v2: serve one "

@@ -22,7 +22,7 @@ import re
 import shutil
 import tempfile
 import time
-from typing import Any, Callable, Iterator
+from typing import Any, Callable, Iterator, Sequence
 
 import jax
 
@@ -151,9 +151,15 @@ def _bytes(shape: str) -> int:
     return total
 
 
-def phase_map(hlo_text: str) -> tuple[str, dict[str, str]]:
+def phase_map(
+    hlo_text: str,
+    classify: Callable[[str, str], str] = phase_of,
+    order: Sequence[str] = PHASES,
+) -> tuple[str, dict[str, str]]:
     """(module name, {instruction name: phase}) of a compiled module's
-    text (``Compiled.as_text()``): every instruction of every
+    text (``Compiled.as_text()``), each phase ``classify(op_name,
+    opcode)`` (``phase_of``, of the labels ``order``; a serving
+    program's scopes use their own): every instruction of every
     computation that runs as ops of its own (the entry, while bodies and
     conditions, branches, async computations); the bodies of fusions and
     of reductions' ``to_apply`` run inside their caller and are left
@@ -205,7 +211,7 @@ def phase_map(hlo_text: str) -> tuple[str, dict[str, str]]:
         if not named:
             return None
         top = max(r[3] for r in named)
-        best = min((r for r in named if r[3] == top), key=lambda r: PHASES.index(phase_of(r[1], r[0])))
+        best = min((r for r in named if r[3] == top), key=lambda r: order.index(classify(r[1], r[0])))
         return best[0], best[1]
 
     phases: dict[str, str] = {}
@@ -222,7 +228,7 @@ def phase_map(hlo_text: str) -> tuple[str, dict[str, str]]:
                 members = [body[n] for n in root[4] if n in body]
                 pick = dominant(matmuls) or (dominant(members) if root[0] == "tuple" else None)
                 op_name = pick[1] if pick else (op_name or root[1])
-            phases[name] = phase_of(op_name or root[1], opcode)
+            phases[name] = classify(op_name or root[1], opcode)
     return module, phases
 
 

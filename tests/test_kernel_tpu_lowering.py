@@ -29,6 +29,8 @@ from cs744_pytorch_distributed_tutorial_tpu.ops.gmm import (
 from cs744_pytorch_distributed_tutorial_tpu.ops.paged_attention import (
     paged_attention,
 )
+from cs744_pytorch_distributed_tutorial_tpu.ops import block_sparse as sparse
+from cs744_pytorch_distributed_tutorial_tpu.ops import lightning
 from cs744_pytorch_distributed_tutorial_tpu.ops.quant import int8_matmul
 
 bf16, f32, i8, i32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
@@ -129,6 +131,26 @@ CASES = {
     "conv3x3_wgrad_s2": (
         lambda x, g: conv3x3_wgrad(x, g, stride=2, interpret=False),
         [_s((4096, 32, 32, 64), bf16), _s((4096, 16, 16, 128), bf16)],
+    ),
+    # the long-context SALA cell's geometry: 32 slots, 32 heads of 128,
+    # chunks of 512, 4,352 pages a slot over 2 KV heads
+    "lightning_decode_sala_cell": (
+        lambda *a: lightning.lightning_decode(*a, interpret=False),
+        [_s((32, 32, 128), bf16)] * 3
+        + [_s((32, 32, 128, 128), f32), _s((32,), f32), _s((32,), i32)],
+    ),
+    "lightning_chunk_sala_cell": (
+        lambda *a: lightning.lightning_chunk(*a, interpret=False),
+        [_s((512, 32, 128), bf16)] * 3
+        + [_s((32, 32, 128, 128), f32), _s((32,), f32)] + [_s((), i32)] * 3,
+    ),
+    "block_sparse_decode_sala_cell": (
+        lambda *a: sparse.block_sparse_decode(
+            *a, sparse.BlockSparse(), 128 ** -0.5, interpret=False
+        ),
+        [_s((32, 32, 128), bf16)] + [_s((139265, 16, 256), bf16)] * 2
+        + [_s((32, 2, 128), i32), _s((32, 2, 512), i32), _s((32, 2), i32),
+           _s((32,), i32)],
     ),
 }
 

@@ -680,6 +680,38 @@ def _packed_step_engine(kind, tiny_lm):
 
 
 @pytest.mark.parametrize("kind", ["plain-greedy", "plain-sampled", "window", "latent", "sparse"])
+def test_kept_logits_are_what_each_step_sampled_from(tiny_lm, kind):
+    """``keep_logits``: the same tokens as the engine that keeps none,
+    and each step's kept rows are the logits its tokens came from (the
+    greedy argmax; under sampling, a token its row makes possible)."""
+    plain, plain_reqs = _packed_step_engine(kind, tiny_lm)
+    plain.run()
+    eng, reqs = _packed_step_engine(kind, tiny_lm)
+    eng.keep_logits()
+    assert eng.last_logits is None
+    steps = 0
+    while eng.busy:
+        before = {r.req_id: r.output_tokens for r in reqs}
+        eng.step()
+        for r in reqs:
+            row = eng.last_logit_rows.get(r.req_id)
+            # one token this step, by the decode (an admission adds its
+            # first token besides)
+            if row is None or r.output_tokens != before[r.req_id] + 1:
+                continue
+            logits = np.asarray(eng.last_logits[row])
+            tok = r.generated[-1]
+            assert logits.shape == (eng.model.vocab_size,) and np.isfinite(logits).all()
+            if kind == "plain-sampled":
+                assert logits[tok] > -np.inf
+            else:
+                assert tok == int(np.argmax(logits))
+            steps += 1
+    assert steps > 0
+    assert [r.generated for r in reqs] == [r.generated for r in plain_reqs]
+
+
+@pytest.mark.parametrize("kind", ["plain-greedy", "plain-sampled", "window", "latent", "sparse"])
 def test_a_decode_step_is_one_packed_put(tiny_lm, kind, monkeypatch):
     """The decode program takes (params, pages, ONE int32 vector, key),
     whatever the model keeps beside the page table (a window group's

@@ -140,6 +140,43 @@ def check_map(text, expected):
     return module, phases
 
 
+def test_phase_map_takes_another_classifier():
+    """A serving program's named scopes through the same rules: a fusion
+    takes its largest matmul's label, else its root's."""
+    text = """HloModule jit_step, is_scheduled=true
+
+%fused (p: f32[8,8], q: f32[8]) -> f32[8] {
+  %p = f32[8,8]{1,0} parameter(0)
+  %q = f32[8]{0} parameter(1)
+  %d = f32[8]{0} dot(%p, %q), metadata={op_name="jit(step)/TransformerLM/attn/attn_sparse_select/dot_general"}
+  ROOT %a = f32[8]{0} add(%d, %q), metadata={op_name="jit(step)/TransformerLM/attn/add"}
+}
+
+%fused.1 (r: f32[8]) -> f32[8] {
+  %r = f32[8]{0} parameter(0)
+  ROOT %e = f32[8]{0} exponential(%r), metadata={op_name="jit(step)/TransformerLM/attn/attn_sparse/exp"}
+}
+
+ENTRY %main (w: f32[8,8], x: f32[8]) -> f32[8] {
+  %w = f32[8,8]{1,0} parameter(0)
+  %x = f32[8]{0} parameter(1)
+  %fusion = f32[8]{0} fusion(%w, %x), kind=kOutput, calls=%fused
+  %fusion.1 = f32[8]{0} fusion(%fusion), kind=kLoop, calls=%fused.1
+  ROOT %sort = f32[8]{0} sort(%fusion.1), metadata={op_name="jit(step)/TransformerLM/mlp/sort"}
+}
+"""
+    scopes = ("attn_sparse_select", "attn_sparse")
+
+    def scope_of(op_name, opcode):
+        return next((s for s in scopes if f"/{s}/" in op_name), "")
+
+    module, got = profiling.phase_map(text, scope_of, scopes + ("",))
+    assert module == "jit_step"
+    assert {k: got[k] for k in ("fusion", "fusion.1", "sort")} == {
+        "fusion": "attn_sparse_select", "fusion.1": "attn_sparse", "sort": "",
+    }
+
+
 def test_cifar_step_phase_map(cifar_text):
     module, phases = check_map(cifar_text, {"augment", "fwd", "bwd", "optimizer", "telemetry"})
     assert module == "jit_local_train_step"
