@@ -19,11 +19,13 @@ page_size, G*d]``), and one more pool beside them,
 ``i`` in the row of the slot's page ``i`` (the kernel stride is the
 page size; a kernel spans two pages, so row ``i`` is written when page
 ``i + 1`` fills: by the chunk that fills it, or by the decode step that
-writes its last row). A chunk selects for each of its queries and
-attends in XLA tile by tile; a decode step selects over the slot's
-compressed rows (XLA, named ``attn_sparse_select``), then reads only the
-chosen blocks' pages (the Pallas walk under "kernel", named
-``attn_sparse``; the gathered rows under "gather").
+writes its last row). A chunk selects for each of its queries (XLA,
+``attn_sparse_select``), then attends under ``attn_sparse_chunk``: by the
+Pallas walk over the slot's live pages under "kernel", in XLA tile by
+tile under "gather". A decode step selects over the slot's compressed
+rows (XLA, named ``attn_sparse_select``), then reads only the chosen
+blocks' pages (the Pallas walk under "kernel", named ``attn_sparse``;
+the gathered rows under "gather").
 """
 
 from __future__ import annotations
@@ -168,10 +170,18 @@ class BlockSparseAttention(nn.Module):
                 )
             nblk = -(-cap * ps // sp.block_size)
             with jax.named_scope("attn_sparse_chunk"):
-                out = B.chunk_attention(
-                    q[0], kp.value, vp.value, page_table[0], positions[0],
-                    B.allowed_blocks(ids[0], nblk), sp, scale,
-                )
+                allowed = B.allowed_blocks(ids[0], nblk)
+                if self.paged_attention_impl == "kernel":
+                    length = t if last_idx is None else last_idx[0] + 1
+                    out = B.block_sparse_chunk_attention(
+                        q[0], kp.value, vp.value, page_table[0], positions[0, 0],
+                        length, allowed, sp, scale, interpret=self.flash_interpret,
+                    )
+                else:
+                    out = B.chunk_attention(
+                        q[0], kp.value, vp.value, page_table[0], positions[0],
+                        allowed, sp, scale,
+                    )
             return gated_out(out[None])
 
         if t != 1:

@@ -31,9 +31,16 @@ group, the group's ``block_size / page_size`` pages a block copied by
 their indices (scalar-prefetched) into double-buffered VMEM, the group's
 lanes only, with the next grid step's first pages in flight under each
 step's last; an online softmax in float32, ``p`` in the pool's type for
-``p . V``. A chunk attends in XLA (``chunk_attention``): the slot's keys
-a tile at a time, each query masked to its selection, an online softmax
-across tiles, so no ``[C, H, view]`` scores are ever held.
+``p . V``. A chunk attends by ``block_sparse_chunk_attention`` under the
+engine's "kernel": a Pallas walk over the slot's live pages, a grid step
+a tile of queries, key blocks copied a page at a time into
+double-buffered VMEM up to the page of the tile's last real position,
+each query's chosen blocks and the causal mask applied in VMEM, the
+online softmax in VMEM scratch. Under "gather" it attends in XLA
+(``chunk_attention``, the kernel's reference): the slot's keys a tile at
+a time, each query masked to its selection, an online softmax across
+tiles, so no ``[C, H, view]`` scores are ever held, though each tile's
+float32 scores go through HBM.
 
 ``interpret=True`` runs the kernel on any backend for tests.
 """
@@ -55,9 +62,19 @@ NO_BLOCK = 1 << 20
 # Blocks a step of the decode walk copies (4 blocks of 64 positions: 16
 # pages of 16, 256 keys).
 _WALK_BLOCKS = 4
-# Positions a tile of the chunk's attention scores (its float32 scores at
-# C = 512 and 32 heads are 134 MB).
+# Positions a tile of the chunk's XLA attention scores (its float32
+# scores at C = 512 and 32 heads are 134 MB).
 _CHUNK_TILE = 2048
+# The chunk walk (``block_sparse_chunk_attention``): (query, head) rows a
+# KV group of a query tile, selection blocks a key block of its walk (a
+# bit each of an int32 word; 1,024 positions at 64 a block), and the
+# scoped VMEM it asks for (a group's float32 scores over a key block are
+# 16 MB). On the v5e at MiniCPM-SALA's shapes, 20k keys back: 1.55 ms a
+# layer, 57% of the bf16 peak, where 2,048 rows x 512 keys take 2.22 ms
+# and 4,096 x 2,048 or 8,192 x 1,024 take 1.65-1.85 (PERF.md).
+_CHUNK_ROWS = 4096
+_CHUNK_KEY_BLOCKS = 16
+_CHUNK_VMEM_LIMIT = 64 * 1024 * 1024
 
 
 class BlockSparse(NamedTuple):
@@ -481,3 +498,239 @@ def block_sparse_decode(
         q.reshape(b, g, hg, d).astype(key_pages.dtype), key_pages, value_pages,
     )
     return out.reshape(b, h, d)
+
+
+def _chunk_kernel(
+    page_size: int, key_pages_a_block: int, key_blocks: int, capacity: int,
+    block_size: int, scale: float,
+    meta_ref, pt_ref, q_ref, bits_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
+    m_ref, l_ref, acc_ref, parity,
+):
+    """One query tile of a chunk, every KV group, walks the key blocks up
+    to its last real position (``block_sparse_chunk_attention``)."""
+    groups, hg, tq, d = q_ref.shape
+    ppk = key_pages_a_block
+    keys = ppk * page_size
+    folded = kbuf.shape[-1]
+    qi, nq = pl.program_id(0), pl.num_programs(0)
+    offset, length = meta_ref[0], meta_ref[1]
+
+    def last(ti):  # tile ti's last real position
+        return offset + jnp.minimum((ti + 1) * tq, length) - 1
+
+    def steps(ti):  # key blocks tile ti walks (none for a padding tile)
+        return jnp.where(
+            ti * tq < length, jnp.minimum(last(ti) // keys + 1, key_blocks), 0
+        )
+
+    def live(ti, kb):  # pages of key block kb at or before tile ti's last
+        return jnp.clip(
+            jnp.minimum(last(ti) // page_size + 1, capacity) - kb * ppk, 0, ppk
+        )
+
+    def copies(kb, buf, j):
+        page = pt_ref[kb * ppk + j]
+        return [
+            pltpu.make_async_copy(src.at[page], dst.at[buf, j], sem.at[buf])
+            for src, dst in ((k_hbm, kbuf), (v_hbm, vbuf))
+        ]
+
+    def start(ti, kb, buf):
+        @pl.loop(0, live(ti, kb))
+        def _copy(j):
+            for cp in copies(kb, buf, j):
+                cp.start()
+
+        # pages not copied keep what the buffer held: their scores are
+        # masked, and p = 0 times a stale NaN is NaN, so their V rows go 0
+        @pl.loop(live(ti, kb), ppk)
+        def _zero(j):
+            vbuf[buf, j] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
+
+    def wait(ti, kb, buf):
+        @pl.loop(0, live(ti, kb))
+        def _wait(j):
+            for cp in copies(0, buf, j):
+                cp.wait()
+
+    @pl.when(qi == 0)
+    def _first_tile():
+        parity[0] = 0
+
+        @pl.when(steps(0) > 0)
+        def _start():
+            start(0, 0, 0)
+
+    base = parity[0]
+    n = steps(qi)
+    nxt = jnp.minimum(qi + 1, nq - 1)
+    next_walks = jnp.logical_and(qi + 1 < nq, steps(nxt) > 0)
+    tok_pos = offset + qi * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tq, keys), 1)
+    lane_tile = jax.lax.broadcasted_iota(jnp.int32, (tq, 128), 1)
+    m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def body(kb, carry):
+        buf = (base + kb) % 2
+        more = kb + 1 < n
+
+        @pl.when(more)
+        def _next_block():
+            start(qi, kb + 1, 1 - buf)
+
+        @pl.when(jnp.logical_and(~more, next_walks))
+        def _next_tile():
+            start(nxt, 0, 1 - buf)
+
+        wait(qi, kb, buf)
+        k = kbuf[buf].reshape(keys, folded)
+        v = vbuf[buf].reshape(keys, folded)
+        before = kb * keys + lane <= tok_pos  # [tq, keys], causal
+        for g in range(groups):
+            # each query's chosen blocks of kb, a bit each: its lane of the
+            # 128-lane tile that holds kb
+            word = jnp.sum(
+                jnp.where(lane_tile == kb % 128, bits_ref[g, kb // 128], 0),
+                axis=1, keepdims=True,
+            )
+            chosen = jax.lax.shift_right_logical(
+                jnp.broadcast_to(word, (tq, keys)), lane // block_size
+            ) & 1
+            ok = jnp.logical_and(chosen == 1, before)
+            s = jax.lax.dot_general(
+                q_ref[g].reshape(hg * tq, d), k[:, g * d:(g + 1) * d],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            ) * scale
+            s = jnp.where(ok[None], s.reshape(hg, tq, keys), _NEG).reshape(
+                hg * tq, keys
+            )
+            m_prev = m_ref[g]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[g] = corr * l_ref[g] + p.sum(axis=-1, keepdims=True)
+            acc_ref[g] = acc_ref[g] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v[:, g * d:(g + 1) * d],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            )
+            m_ref[g] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n, body, 0)
+    parity[0] = (base + n) % 2
+    real = (tok_pos < offset + length)[None]
+    for g in range(groups):
+        o_ref[g] = jnp.where(
+            real, (acc_ref[g] / l_ref[g]).reshape(hg, tq, d), 0.0
+        ).astype(o_ref.dtype)
+
+
+def block_sparse_chunk_attention(
+    q: jax.Array,
+    key_pages: jax.Array,
+    value_pages: jax.Array,
+    page_row: jax.Array,
+    offset: jax.Array,
+    length: jax.Array,
+    allowed: jax.Array,
+    sp: BlockSparse,
+    scale: float,
+    *,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """A prefill chunk's attention read straight out of the pools
+    (models/block_sparse.py, ``paged_prefill``, kernel named
+    ``attn_sparse_chunk``): ``chunk_attention``'s result without its
+    float32 scores over the view in HBM.
+
+    ``q [C, H, d]`` holds the chunk's queries at positions ``offset +
+    t``; rows ``t >= length`` (``length >= 1``) are padding and come
+    back 0. ``key_pages``/``value_pages [num_pages, page_size, G*d]``
+    already hold the chunk's own rows, ``page_row [P]`` is the slot's
+    pages in position order, ``allowed [C, G, nblk]`` each query's
+    chosen blocks (``allowed_blocks``), which must hold a key at or
+    before every real query (``select`` always chooses the query's own
+    block). Returns ``[C, H, d]`` in the pools' type.
+
+    A grid step is a tile of ``_CHUNK_ROWS / (H / G)`` queries (256 at
+    MiniCPM-SALA's 16 heads a group: 4,096 (query, head) rows a group),
+    every KV group. It walks key blocks of ``_CHUNK_KEY_BLOCKS``
+    selection blocks (1,024 positions at 64 a block) in order, whole
+    pages of both groups copied a page at a time by the scalar-prefetched
+    page row into double-buffered VMEM while the block before is
+    computed, the next tile's first under this tile's last; in each, the
+    query's chosen blocks (a bit a block, from a word a query and key
+    block) and the causal mask apply before an online softmax in VMEM
+    scratch: scores, max and sum in float32, ``p`` in the pools' type
+    for ``p . V``, float32 accumulation (``chunk_attention``'s
+    numerics). The walk reads only up to the page that holds the tile's
+    last real position, so pages past it, the trash page 0 among them,
+    are never read."""
+    c, h, d = q.shape
+    _, ps, folded = key_pages.shape
+    g = folded // d
+    hg = h // g
+    cap = page_row.shape[0]
+    bs = sp.block_size
+    keys = _CHUNK_KEY_BLOCKS * bs
+    if interpret is None:
+        from cs744_pytorch_distributed_tutorial_tpu.ops._backend import (
+            default_interpret,
+        )
+
+        interpret = default_interpret()
+    if folded % d or h % g or bs % ps or (d % 128 and not interpret):
+        raise ValueError(
+            f"pools [num_pages, page_size, G*d] with d a multiple of 128 "
+            f"lanes, G dividing the {h} heads and block_size a multiple of "
+            f"the page; got pool {key_pages.shape}, d {d}, block {bs}"
+        )
+    ppk = keys // ps
+    nkb = -(-cap // ppk)
+    tq = min(c, max(8, _CHUNK_ROWS // hg))
+    n_q = -(-c // tq)
+    rows = n_q * tq
+    lanes = -(-nkb // 128) * 128
+    # each (query, group, key block): a bit a chosen block
+    a = jnp.pad(
+        allowed.astype(jnp.int32),
+        ((0, rows - c), (0, 0), (0, nkb * _CHUNK_KEY_BLOCKS - allowed.shape[-1])),
+    ).reshape(rows, g, nkb, _CHUNK_KEY_BLOCKS)
+    bits = jnp.sum(a << jnp.arange(_CHUNK_KEY_BLOCKS), -1)
+    bits = jnp.pad(bits, ((0, 0), (0, 0), (0, lanes - nkb))).reshape(
+        rows, g, lanes // 128, 128
+    ).transpose(1, 2, 0, 3)  # [G, lane tiles, rows, 128]
+    qg = jnp.pad(q.reshape(c, g, hg, d), ((0, rows - c), (0, 0), (0, 0), (0, 0)))
+    tile = pl.BlockSpec((g, hg, tq, d), lambda qi, *_: (0, 0, qi, 0))
+    buffers = pltpu.VMEM((2, ppk, ps, folded), key_pages.dtype)
+    out = pl.pallas_call(
+        partial(_chunk_kernel, ps, ppk, nkb, cap, bs, float(scale)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_q,),
+            in_specs=[
+                tile,
+                pl.BlockSpec((g, lanes // 128, tq, 128), lambda qi, *_: (0, 0, qi, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=tile,
+            scratch_shapes=[
+                buffers, buffers, pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((g, hg * tq, 1), jnp.float32),
+                pltpu.VMEM((g, hg * tq, 1), jnp.float32),
+                pltpu.VMEM((g, hg * tq, d), jnp.float32),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((g, hg, rows, d), key_pages.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_CHUNK_VMEM_LIMIT),
+        interpret=interpret,
+        name="attn_sparse_chunk",
+    )(
+        jnp.stack([offset, length]).astype(jnp.int32), page_row.astype(jnp.int32),
+        qg.transpose(1, 2, 0, 3).astype(key_pages.dtype), bits, key_pages, value_pages,
+    )
+    return out.transpose(2, 0, 1, 3)[:c].reshape(c, h, d)

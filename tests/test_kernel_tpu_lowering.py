@@ -152,6 +152,14 @@ CASES = {
         + [_s((32, 2, 128), i32), _s((32, 2, 512), i32), _s((32, 2), i32),
            _s((32,), i32)],
     ),
+    "block_sparse_chunk_sala_cell": (
+        lambda *a: sparse.block_sparse_chunk_attention(
+            *a, sparse.BlockSparse(), 128 ** -0.5, interpret=False
+        ),
+        [_s((512, 32, 128), bf16)] + [_s((139265, 16, 256), bf16)] * 2
+        + [_s((4352,), i32), _s((), i32), _s((), i32),
+           _s((512, 2, 1088), jnp.bool_)],
+    ),
 }
 
 

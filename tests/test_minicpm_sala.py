@@ -275,6 +275,57 @@ def test_the_reference_selects_as_the_brute_force():
             assert list(np.nonzero(chosen[t, gi])[0]) == want[gi], (t, gi)
 
 
+# (offset, real length, planted) of a chunk of 32 over a slot of 160 pages
+# of 4 (five key blocks of 128 positions): at position 0, below dense_len,
+# past it, a short last chunk, and a selection planted so that the first
+# tile chose nothing in key block 2 and no tile anything in key block 1
+WALKS = {
+    "offset_0": (0, 32, False), "dense": (8, 32, False), "sparse": (400, 32, False),
+    "short_last": (448, 13, False), "planted_gaps": (420, 32, True),
+}
+
+
+@pytest.mark.parametrize("case", WALKS)
+def test_the_chunk_walk_matches_the_xla_form(case, monkeypatch):
+    """``block_sparse_chunk_attention`` (interpreted) against
+    ``chunk_attention`` over the same selection, in tiles of 8 queries
+    and key blocks of 128 positions. The kernel's pools are NaN wherever
+    it must not read: the trash page 0, every page past the chunk's last
+    real position and every page off the slot's row; its padding rows
+    come back 0."""
+    offset, length, planted = WALKS[case]
+    c, h, g, d, ps, cap, tile = 32, 4, 2, 32, 4, 160, 8
+    monkeypatch.setattr(B, "_CHUNK_ROWS", tile * h // g)
+    per = B._CHUNK_KEY_BLOCKS
+    rng = np.random.default_rng(7)
+    num_pages = cap + 9
+    table = 1 + rng.permutation(num_pages - 1)[:cap]
+    kp, vp = (rng.normal(size=(num_pages, ps, g * d)).astype(np.float32) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(c, h, d)), jnp.float32)
+    pos = offset + np.arange(c)
+    ckeys = jnp.asarray(rng.normal(size=(1, cap, g, d)), jnp.float32)
+    ids, _, _ = B.select(q[None], ckeys, jnp.asarray(pos)[None], SP, d ** -0.5)
+    nblk = cap * ps // SP.block_size
+    allowed = np.array(B.allowed_blocks(ids[0], nblk))
+    if planted:
+        allowed[:tile, :, 2 * per:3 * per] = False
+        allowed[:, :, per:2 * per] = False
+    want = B.chunk_attention(q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(table),
+                             jnp.asarray(pos), jnp.asarray(allowed), SP, d ** -0.5)
+    last_page = (offset + length - 1) // ps
+    unread = [0] + [int(table[i]) for i in range(last_page + 1, cap)]
+    unread += sorted(set(range(num_pages)) - set(table.tolist()))
+    kp, vp = kp.copy(), vp.copy()  # the XLA form may still read the arrays it was given
+    kp[unread], vp[unread] = np.nan, np.nan
+    got = np.asarray(B.block_sparse_chunk_attention(
+        q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(table), jnp.int32(offset),
+        jnp.int32(length), jnp.asarray(allowed), SP, d ** -0.5, interpret=True,
+    ))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[:length], np.asarray(want)[:length], atol=2e-6, rtol=1e-5)
+    assert (got[length:] == 0).all()
+
+
 # ---- served by chunks and decode ---------------------------------------------------
 
 LENGTHS = ((70, 12), (23, 9), (130, 20), (9, 5), (64, 8))
@@ -318,8 +369,8 @@ def test_chunks_then_decode_serve_the_reference_s_tokens(tiny, served):
 
 
 def test_the_kernels_serve_the_same_tokens(tiny, served):
-    """The lightning chunk and decode kernels and the block-sparse walk
-    (interpret mode) serve the gather path's tokens."""
+    """The lightning chunk and decode kernels and the block-sparse walks,
+    decode and chunk (interpret mode), serve the gather path's tokens."""
     cfg, _, params, flat = tiny
     model, _, _ = build(cfg, flash_interpret=True)
     engine, reqs = _serve(model, params, paged_attention_impl="kernel")

@@ -6,12 +6,14 @@ their plain-XLA forms (the engine's "gather" path).
 The lightning update is float32 throughout, so it is held to float32
 round-off; the chunk's ``Q K^T`` and its product with ``V`` run in
 bfloat16 on the MXU against the form's own bfloat16 einsums, and the
-block-sparse walk's ``p . V`` in bfloat16 against the gathered rows'.
+block-sparse walks' ``p . V`` in bfloat16 against the gathered rows' (the
+decode step's) and the tiled XLA form's (the chunk's).
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from parity import assert_close
 
 from cs744_pytorch_distributed_tutorial_tpu.ops import block_sparse as B
@@ -71,3 +73,31 @@ def test_block_sparse_decode_cell_shape():
     )(q, kp, vp, first, pages, count[:, 0], pos)
     want = B.decode_reference(q, kp, vp, first, pages, pos, sp, D ** -0.5)
     assert_close(got, want, 2e-2)
+
+
+@pytest.mark.parametrize("offset", [0, 8192, 40960])
+@pytest.mark.parametrize("length", [512, 300])
+def test_block_sparse_chunk_cell_shape(offset, length):
+    """The chunk walk at the cell's shapes (a chunk of 512 queries, 32
+    heads over 2 KV heads of 128, a slot of 4,352 pages of 16): at
+    position 0, at dense_len and deep past it, whole and with padding
+    rows, against the XLA form over the same selection."""
+    c, g, ps, cap = 512, 2, 16, 4352
+    sp = B.BlockSparse()
+    num_pages = cap + 1
+    kp, vp = (_normal(i, (num_pages, ps, g * D), jnp.bfloat16) for i in (0, 1))
+    rng = np.random.default_rng(offset + length)
+    table = jnp.asarray(1 + rng.permutation(num_pages - 1), jnp.int32)
+    q = _normal(2, (c, H, D), jnp.bfloat16)
+    pos = offset + jnp.arange(c)
+    ckeys = _normal(3, (1, cap, g, D))
+    ids, _, _ = B.select(q[None], ckeys, pos[None], sp, D ** -0.5)
+    allowed = B.allowed_blocks(ids[0], cap * ps // sp.block_size)
+    got = jax.jit(
+        lambda *a: B.block_sparse_chunk_attention(*a, sp, D ** -0.5, interpret=False)
+    )(q, kp, vp, table, jnp.int32(offset), jnp.int32(length), allowed)
+    want = jax.jit(
+        lambda *a: B.chunk_attention(*a, sp, D ** -0.5)
+    )(q, kp, vp, table, pos, allowed)
+    assert_close(got[:length], want[:length], 2e-2)
+    assert not np.asarray(got[length:], np.float32).any()
